@@ -55,6 +55,7 @@ def main(argv=None) -> int:
     cli.add_cache_args(ap)
     cli.add_json_args(ap, what="bench summary")
     args = ap.parse_args(argv)
+    cli.enable_compile_cache()
 
     session = cli.session_from_args(args)
 

@@ -58,7 +58,7 @@ def _level_for(ws: int, chip: hwinfo.ChipSpec) -> str:
 def model_map(chip: Optional[hwinfo.ChipSpec] = None,
               sizes: Optional[List[int]] = None) -> List[BandwidthPoint]:
     """Static datasheet map: predicted bandwidth per working-set size."""
-    chip = chip or hwinfo.DEFAULT_CHIP
+    chip = chip or hwinfo.device_chip()
     sizes = sizes or [2**k for k in range(12, 34, 2)]
     # VMEM bandwidth is not a public datasheet number; model it as the rate
     # needed to keep the MXUs fed (flops / arithmetic-intensity-of-1), a
@@ -77,7 +77,7 @@ def measure_map(sizes: Optional[List[int]] = None, *, repeats: int = 5,
                 dtype=jnp.float32,
                 chip: Optional[hwinfo.ChipSpec] = None) -> List[BandwidthPoint]:
     """Measured STREAM-triad bandwidth over a working-set sweep (wall-clock)."""
-    chip = chip or hwinfo.lookup_chip(jax.devices()[0].device_kind)
+    chip = chip or hwinfo.device_chip()
     dtype_bytes = jnp.dtype(dtype).itemsize
     sizes = sizes or [2**k for k in range(14, 27, 2)]
     out = []

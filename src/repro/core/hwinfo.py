@@ -15,12 +15,13 @@ All bandwidth numbers are bytes/second, all compute numbers FLOP/s.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict
 
 __all__ = [
     "ChipSpec",
     "CHIP_REGISTRY",
     "lookup_chip",
+    "device_chip",
     "DEFAULT_CHIP",
 ]
 
@@ -52,6 +53,9 @@ class ChipSpec:
     # --- layout quanta ---
     lane_count: int = 128          # minor-most tile dim (VPU lanes)
     sublane_count: int = 8         # second-minor tile dim for f32
+    # the compiler's default scoped-VMEM limit: what one Pallas kernel may
+    # use without raising ``vmem_limit_bytes`` (far below ``vmem_bytes``)
+    scoped_vmem_bytes: int = 16 * 2**20
 
     @property
     def ici_bisection_bw(self) -> float:
@@ -160,28 +164,29 @@ CHIP_REGISTRY: Dict[str, ChipSpec] = {
     spec.name: spec for spec in (_V5E, _V4, _V5P, _CPU)
 }
 
-#: The production target chip for this repo's dry-run + roofline numbers.
+#: The chip the CPU dry run models (``launch/dryrun.py``): a v5e pod.
+#: Code that runs on a device takes its datasheet from the device instead
+#: (:func:`device_chip`).
 DEFAULT_CHIP: ChipSpec = _V5E
 
 
-def lookup_chip(device_kind: Optional[str] = None) -> ChipSpec:
+def lookup_chip(device_kind: str) -> ChipSpec:
     """Map a ``jax.Device.device_kind`` string onto a datasheet.
 
-    Unknown kinds fall back to the production target (v5e) — the dry-run in
-    this container runs on forced-host CPU devices but models the v5e pod, so
-    the *default* is the modeled chip, not the host.  Pass ``device_kind="cpu"``
-    explicitly to get host numbers.
+    Raises ``ValueError`` for a kind with no datasheet: peaks taken from
+    another chip would make every roofline number silently wrong.
     """
-    if device_kind is None:
-        return DEFAULT_CHIP
     kind_lower = device_kind.lower()
     for spec in CHIP_REGISTRY.values():
         for k in spec.device_kinds:
             if k.lower() == kind_lower:
                 return spec
-    # Substring match ("TPU v5 lite" variants etc.)
-    for spec in CHIP_REGISTRY.values():
-        for k in spec.device_kinds:
-            if k.lower() in kind_lower or kind_lower in k.lower():
-                return spec
-    return DEFAULT_CHIP
+    known = sorted(k for s in CHIP_REGISTRY.values() for k in s.device_kinds)
+    raise ValueError(f"no datasheet for device_kind {device_kind!r}; "
+                     f"known kinds: {known}")
+
+
+def device_chip() -> ChipSpec:
+    """The datasheet of the device JAX runs on (its first device)."""
+    import jax
+    return lookup_chip(jax.devices()[0].device_kind)

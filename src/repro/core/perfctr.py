@@ -82,7 +82,7 @@ def measure_compiled(compiled, *, region: str = "program",
                      chip: Optional[hwinfo.ChipSpec] = None,
                      num_devices: int = 1) -> Measurement:
     """Wrapper mode on an already-compiled executable (dry-run path)."""
-    chip = chip or hwinfo.DEFAULT_CHIP
+    chip = chip or hwinfo.device_chip()
     ev = extract_events(compiled, num_devices=num_devices)
     return Measurement(region=region, events=ev, chip=chip,
                        num_devices=num_devices)
@@ -141,7 +141,7 @@ class PerfCtr:
     def __init__(self, chip: Optional[hwinfo.ChipSpec] = None,
                  groups: Sequence[str] = ("ROOFLINE",), mesh=None,
                  session=None):
-        self.chip = chip or hwinfo.DEFAULT_CHIP
+        self.chip = chip or hwinfo.device_chip()
         self.group_names = list(groups)
         self.mesh = mesh
         self.session = session       # optional ProfileSession (compile cache)

@@ -46,6 +46,7 @@ __all__ = [
     "get_strategy",
     "STRATEGIES",
     "PinResult",
+    "auto_mesh",
 ]
 
 
@@ -221,3 +222,16 @@ def get_strategy(name: str) -> PinStrategy:
     raise ValueError(
         f"unknown pin strategy {name!r}; expected one of {sorted(STRATEGIES)} "
         f"or an explicit list like '0-63,128-191'")
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None):
+    """``jax.make_mesh`` over a (pinned) device order with every axis
+    ``Auto``: GSPMD propagates the shardings and
+    ``with_sharding_constraint`` may name any axis (the installed JAX makes
+    axes ``Explicit`` by default, which refuses both)."""
+    import jax
+    kw = {} if devices is None else {"devices": list(devices)}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kw)

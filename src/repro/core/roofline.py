@@ -111,7 +111,7 @@ def analyze(ev: EventCounts, *, cell: str,
     program); ``model_flops_total`` is the whole-job estimate and is divided
     by ``num_devices`` here.
     """
-    chip = chip or hwinfo.DEFAULT_CHIP
+    chip = chip or hwinfo.device_chip()
     links = ici_links_used if ici_links_used is not None else chip.ici_links
     links = max(links, 1)
     return RooflineTerms(

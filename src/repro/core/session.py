@@ -172,7 +172,7 @@ class ProfileSession:
                  cache: Optional[ArtifactCache] = None,
                  enabled: bool = True):
         self.cache = cache or ArtifactCache(cache_dir, enabled=enabled)
-        self.chip = chip or hwinfo.DEFAULT_CHIP
+        self.chip = chip or hwinfo.device_chip()
         self.lowerings = 0           # real lower+compile ops this session
         self._lock = threading.Lock()
         self._key_locks: Dict[str, threading.Lock] = {}

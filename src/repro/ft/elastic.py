@@ -25,7 +25,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
-from repro.core.pin import PinStrategy, apply_skip, get_strategy
+from repro.core.pin import PinStrategy, apply_skip, auto_mesh, get_strategy
 from repro.core.topology import NodeTopology
 
 __all__ = ["RemeshPlan", "RemeshGovernor", "plan_remesh",
@@ -140,7 +140,7 @@ def build_mesh_from_plan(plan: RemeshPlan,
         devices = jax.devices()
     by_id = {d.id: d for d in devices}
     ordered = [by_id[i] for i in plan.device_ids]
-    return jax.make_mesh(plan.axis_sizes, plan.axis_names, devices=ordered)
+    return auto_mesh(plan.axis_sizes, plan.axis_names, devices=ordered)
 
 
 def reshard_tree(tree: Any, pspecs: Any, mesh: Mesh) -> Any:

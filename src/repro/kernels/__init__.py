@@ -15,7 +15,8 @@ sampling.py               greedy/top-k/top-p token sampling (blockwise
 ========================  ===================================================
 
 ops.py holds the jit'd layout adapters; ref.py the pure-jnp oracles every
-kernel is allclose-tested against (interpret=True on this CPU container).
+kernel is allclose-tested against (interpret mode on a CPU backend;
+tests/test_chip_compile.py compiles them for a described TPU v5e).
 
 registry.py is the ONE entry point over all of them: every implementation
 is a declarative ``KernelSpec`` registered into a family (``attention``,

@@ -67,7 +67,10 @@ def _wavefront_kernel(x_ref, o_ref, *, omega: float, sweeps: int, bx: int):
 
 
 def _run(x: jnp.ndarray, sweeps: int, omega: float, block_x: int,
-         interpret: bool) -> jnp.ndarray:
+         interpret: bool | None) -> jnp.ndarray:
+    if interpret is None:
+        from repro.kernels.registry import default_interpret
+        interpret = default_interpret()
     T = sweeps
     X, Y, Z = x.shape
     ox, oy, oz = X - 2 * T, Y - 2 * T, Z - 2 * T
@@ -92,7 +95,7 @@ def _run(x: jnp.ndarray, sweeps: int, omega: float, block_x: int,
 
 @functools.partial(jax.jit, static_argnames=("omega", "block_x", "interpret"))
 def jacobi7_naive(x: jnp.ndarray, *, omega: float = 1.0 / 6.0,
-                  block_x: int = 8, interpret: bool = True) -> jnp.ndarray:
+                  block_x: int = 8, interpret: bool | None = None) -> jnp.ndarray:
     """One valid sweep: [X,Y,Z] -> [X-2,Y-2,Z-2] (call T times for T steps)."""
     return _run(x, 1, omega, block_x, interpret)
 
@@ -101,7 +104,7 @@ def jacobi7_naive(x: jnp.ndarray, *, omega: float = 1.0 / 6.0,
                    static_argnames=("sweeps", "omega", "block_x", "interpret"))
 def jacobi7_wavefront(x: jnp.ndarray, *, sweeps: int = 4,
                       omega: float = 1.0 / 6.0, block_x: int = 8,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool | None = None) -> jnp.ndarray:
     """T valid sweeps in one VMEM residency: [X,Y,Z]->[X-2T,Y-2T,Z-2T]."""
     return _run(x, sweeps, omega, block_x, interpret)
 

@@ -9,13 +9,13 @@ scan call sites; these factories build them:
   kernels/ssd_scan.py, returning (y, (C,n)) exactly like
   models.linear_scan.chunked_linear_attention.
 
-``interpret=True`` everywhere in this container (CPU validation); on real
-TPU the same wrappers run compiled (interpret=False via REPRO_KERNEL_COMPILE).
+``interpret=None`` resolves from the backend
+(:func:`repro.kernels.registry.default_interpret`): compiled on TPU,
+interpreted everywhere else.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Callable, Tuple
 
 import jax.numpy as jnp
@@ -25,15 +25,9 @@ from repro.kernels.jacobi7 import jacobi7_naive, jacobi7_wavefront
 from repro.kernels.ssd_scan import ssd_scan_flat
 from repro.kernels.stream_triad import stream_triad
 
-__all__ = ["INTERPRET", "flash_attention", "ssd_scan",
+__all__ = ["flash_attention", "ssd_scan",
            "make_flash_attention_fn", "make_ssd_scan_fn",
            "stream_triad", "jacobi7_naive", "jacobi7_wavefront"]
-
-#: interpret-mode default: CPU container -> True; flip on real TPU.
-#: (kept for back-compat; the flash path now resolves through
-#: dispatch.default_interpret, which also detects the backend)
-INTERPRET = os.environ.get("REPRO_KERNEL_COMPILE", "0") != "1"
-
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, q_offset=0, kv_valid=None,
@@ -61,14 +55,13 @@ def ssd_scan(q, k, v, log_f, log_i, *, chunk: int = 128,
     Returns (y [B,S,H,dv], (C [B,H,dk,dv], n [B,H,dk])) — the
     chunked_linear_attention contract.
     """
-    itp = INTERPRET if interpret is None else interpret
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     flat = lambda a: a.transpose(0, 2, 1, *range(3, a.ndim)).reshape(
         b * h, s, *a.shape[3:])
     y, (c_st, n_st) = ssd_scan_flat(
         flat(q), flat(k), flat(v), flat(log_f), flat(log_i),
-        chunk=chunk, normalize=normalize, interpret=itp)
+        chunk=chunk, normalize=normalize, interpret=interpret)
     y = y.reshape(b, h, s, dv).transpose(0, 2, 1, 3)
     return y, (c_st.reshape(b, h, dk, dv), n_st.reshape(b, h, dk))
 

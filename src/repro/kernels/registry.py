@@ -69,7 +69,7 @@ __all__ = [
     "use_impl", "parse_impl_spec", "override_for", "select", "run",
     "autotune", "best", "record", "clear_tune_table", "tune_table",
     "dump_tune_table", "default_interpret", "LEGACY_ATTN_MAP",
-    "use_mesh_facts", "mesh_facts", "mesh_key_tag",
+    "use_mesh_facts", "mesh_facts", "mesh_key_tag", "on_mesh",
 ]
 
 
@@ -78,15 +78,9 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 def default_interpret(backend: Optional[str] = None) -> bool:
-    """Pallas interpret mode from backend detection (not a hardcoded True).
-
-    ``REPRO_KERNEL_COMPILE=1`` forces compiled, ``=0`` forces interpret;
-    otherwise TPU compiles and everything else interprets.
-    """
-    env = os.environ.get("REPRO_KERNEL_COMPILE")
-    if env is not None:
-        return env != "1"
-    return (backend or jax.default_backend()) != "tpu"
+    """Pallas interpret mode from the backend alone: a TPU compiles every
+    kernel, every other backend interprets it."""
+    return _backend(backend) != "tpu"
 
 
 def _pow2_up(n: int) -> int:
@@ -302,22 +296,26 @@ MESH_FACTS = ("mesh_shape", "mesh_axis", "per_device_heads")
 
 
 @contextlib.contextmanager
-def use_mesh_facts(**facts):
+def use_mesh_facts(mesh=None, **facts):
     """Ambient sharding facts for everything traced inside the block.
 
     A mesh-aware engine enters this around its jitted programs so that
     dispatch-time :func:`best` lookups (which see only the GLOBAL array
     shapes under GSPMD) key their tune records per sharding:
-    ``use_mesh_facts(mesh_shape=(1, 2), mesh_axis="model",
-    per_device_heads=2)``.  Thread-local, nested contexts merge with
-    inner-wins; ``None`` values are dropped so callers can thread
-    optional config straight through.
+    ``use_mesh_facts(mesh=m, mesh_shape=(1, 2), mesh_axis="model",
+    per_device_heads=2)``.  ``mesh`` is the serving mesh itself: Pallas
+    runners traced under it run their kernel in ``shard_map``
+    (:func:`on_mesh`), since GSPMD cannot partition a Pallas custom call.
+    Thread-local, nested contexts merge with inner-wins; ``None`` values
+    are dropped so callers can thread optional config straight through.
     """
     wanted = {k: v for k, v in facts.items() if v is not None}
     unknown = set(wanted) - set(MESH_FACTS)
     if unknown:
         raise ValueError(f"unknown mesh facts {sorted(unknown)}; "
                          f"expected a subset of {MESH_FACTS}")
+    if mesh is not None:
+        wanted["mesh"] = mesh
     prev = getattr(_TLS, "mesh_facts", None)
     _TLS.mesh_facts = {**(prev or {}), **wanted}
     try:
@@ -328,7 +326,33 @@ def use_mesh_facts(**facts):
 
 def mesh_facts() -> Dict[str, Any]:
     """The ambient sharding facts (empty dict when unsharded)."""
-    return dict(getattr(_TLS, "mesh_facts", None) or {})
+    facts = getattr(_TLS, "mesh_facts", None) or {}
+    return {k: v for k, v in facts.items() if k != "mesh"}
+
+
+#: kernel operand layouts under a mesh: ``_HEADS`` splits dim -2 of
+#: [B,S,H,Dh] / [P,ps,KVH,Dh] over the ambient ``mesh_axis`` — the layout
+#: ``Engine(mesh=)`` shards weights and KV pages in — and ``_REPL``
+#: replicates
+_HEADS = "heads"
+_REPL = jax.sharding.PartitionSpec()
+
+
+def on_mesh(fn: Callable, args: Sequence, in_specs: Sequence,
+            out_specs=_HEADS):
+    """``fn(*args)``, or under an ambient mesh (:func:`use_mesh_facts`)
+    the same call inside ``shard_map`` with these operand layouts: each
+    device runs the kernel on its own head slice."""
+    facts = getattr(_TLS, "mesh_facts", None) or {}
+    mesh = facts.get("mesh")
+    if mesh is None:
+        return fn(*args)
+    heads = jax.sharding.PartitionSpec(None, None,
+                                       facts.get("mesh_axis", "model"), None)
+    spec = lambda s: heads if s == _HEADS else s
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(spec(s) for s in in_specs),
+                         out_specs=spec(out_specs), check_vma=False)(*args)
 
 
 def mesh_key_tag(*, mesh_shape=None, mesh_axis=None,
@@ -658,7 +682,7 @@ def autotune(family: str, session, *, impl: Optional[str] = None,
     """
     spec = _tuned_spec(family, impl)
     ts = spec.tune
-    chip = chip or getattr(session, "chip", None) or hwinfo.DEFAULT_CHIP
+    chip = chip or getattr(session, "chip", None) or hwinfo.device_chip()
     backend = _backend(backend)
     if interpret is None:
         interpret = default_interpret(backend)
@@ -697,7 +721,7 @@ def autotune(family: str, session, *, impl: Optional[str] = None,
                 return rec
 
     itemsize = jnp.dtype(facts["dtype"]).itemsize
-    budget = chip.vmem_bytes * vmem_fraction
+    budget = chip.scoped_vmem_bytes * vmem_fraction
     lowerings0 = session.lowerings
     scores: Dict[Tuple, float] = {}
     cand_events: Dict[Tuple, Dict[str, float]] = {}
@@ -783,7 +807,7 @@ def _best_from_neighbors(family: str, ts: TuneSpace,
     recorded under the exact key (``interpolated=True``), so dispatch
     pays the neighbor scan once per process per shape."""
     itemsize = jnp.dtype(facts["dtype"]).itemsize
-    budget = hwinfo.DEFAULT_CHIP.vmem_bytes * 0.9
+    budget = hwinfo.device_chip().scoped_vmem_bytes * 0.9
     for delta in ts.neighbors(**facts):
         nfacts = {**facts, **delta}
         nkey = keyf(**nfacts)
@@ -1000,10 +1024,18 @@ def _run_pallas_flash(q, k, v, *, q_offset=0, causal: bool = True,
     bq, bk = blocks or best("attention", b=b, h=h, kvh=k.shape[2], sq=sq,
                             sk=k.shape[1], dh=dh, dtype=q.dtype,
                             causal=causal)
-    # ops.flash_attention owns the BSHD<->BHSD layout contract
-    return ops.flash_attention(q, k, v, causal=causal, q_offset=q_offset,
-                               kv_valid=kv_len, bq=bq, bk=bk,
-                               interpret=interpret)
+    kv_len = (jnp.full((b,), k.shape[1], jnp.int32) if kv_len is None
+              else jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,)))
+
+    def kernel(q, k, v, q_offset, kv_len):
+        # ops.flash_attention owns the BSHD<->BHSD layout contract
+        return ops.flash_attention(q, k, v, causal=causal,
+                                   q_offset=q_offset, kv_valid=kv_len,
+                                   bq=bq, bk=bk, interpret=interpret)
+
+    return on_mesh(kernel, (q, k, v, jnp.asarray(q_offset, jnp.int32),
+                            kv_len),
+                   (_HEADS, _HEADS, _HEADS, _REPL, _REPL))
 
 
 @register_impl("attention", "jnp_flash", layout=_ATTENTION_LAYOUT,
@@ -1088,17 +1120,50 @@ def paged_sweep_key(*, b: int, kvh: int, g: int, dh: int, ctx: int, dtype,
                            per_device_heads=per_device_heads))
 
 
-def paged_vmem(ps: int, ppb: int, g: int, dh: int, itemsize: int = 4) -> int:
-    """VMEM bytes for one grid step: q + ppb double-buffered k/v page
-    tiles + out, plus the f32 [g, ps] score tile and m/l/acc scratch."""
-    io = 2 * (g * dh + 2 * ppb * ps * dh + 2 * dh + g * dh) * itemsize
-    compute = (g * ps + g * dh + 2 * g) * 4
-    return io + compute
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a [rows, cols] tile: cols pad to the 128 lanes, rows
+    to the sublane tile (8 rows of 32-bit, 16 of 16-bit, 32 of 8-bit)."""
+    sub = 8 * max(4 // itemsize, 1)
+    return -(-rows // sub) * sub * (-(-cols // 128) * 128) * itemsize
 
 
-def _paged_vmem(cand, itemsize, *, g, dh, **facts) -> int:
+def _paged_kernel_vmem(ps: int, ppb: int, g: int, dh: int, kvh: int,
+                       itemsize: int, page_itemsize: int,
+                       scales: bool) -> int:
+    """VMEM bytes for one grid step of ``paged_decode._paged_call``.
+
+    Double-buffered blocks: the block-diagonal queries [KVH, G, KVH*Dh],
+    ppb k and v page tiles [ps, KVH*Dh] (+ [ps, 1] f32 scale columns for
+    int8 pages), the new token's k/v rows [1, KVH*Dh] and the output
+    [G, KVH*Dh].  Scratch once: m/l [KVH, G, 1] and the f32 accumulator
+    [KVH, G, KVH*Dh].  f32 temporaries: the widened k/v tiles, one widened
+    query row, the [G, ps] score and probability tiles and the P.V
+    product."""
+    w = kvh * dh
+    blocks = (kvh * _tile_bytes(g, w, itemsize)
+              + 2 * ppb * _tile_bytes(ps, w, page_itemsize)
+              + (2 * ppb * _tile_bytes(ps, 1, 4) if scales else 0)
+              + 2 * _tile_bytes(1, w, itemsize)
+              + _tile_bytes(g, w, itemsize))
+    scratch = kvh * (2 * _tile_bytes(g, 1, 4) + _tile_bytes(g, w, 4))
+    temps = (2 * _tile_bytes(ps, w, 4) + 2 * _tile_bytes(g, w, 4)
+             + 2 * _tile_bytes(g, ps, 4))
+    return 2 * blocks + scratch + temps
+
+
+def paged_vmem(ps: int, ppb: int, g: int, dh: int, itemsize: int = 4,
+               kvh: int = 1) -> int:
+    """VMEM bytes for one grid step of the fp paged decode kernel with
+    ``kvh`` kv heads per device (see :func:`_paged_kernel_vmem`)."""
+    return _paged_kernel_vmem(ps, ppb, g, dh, kvh, itemsize, itemsize,
+                              scales=False)
+
+
+def _paged_vmem(cand, itemsize, *, g, dh, kvh=1, per_device_heads=None,
+                **facts) -> int:
     ps, ppb = cand
-    return paged_vmem(ps, ppb, g, dh, itemsize)
+    # under a mesh each device's kernel holds only its own kv heads
+    return paged_vmem(ps, ppb, g, dh, itemsize, kvh=per_device_heads or kvh)
 
 
 def _paged_probe_fn(q4, kp, vp, pt, lens, kn, vn, *, ppb: int,
@@ -1207,13 +1272,11 @@ def _paged_q8_record_keys(scores, **facts) -> Dict[str, Tuple[Tuple, float]]:
     return _paged_record_keys(scores, quantized=True, **facts)
 
 
-def _paged_q8_vmem(cand, itemsize, *, g, dh, **facts) -> int:
+def _paged_q8_vmem(cand, itemsize, *, g, dh, kvh=1, per_device_heads=None,
+                   **facts) -> int:
     ps, ppb = cand
-    io = 2 * ((2 * g * dh + 2 * dh) * itemsize     # q, out, k/v_new
-              + 2 * ppb * ps * dh                  # int8 k/v page tiles
-              + 2 * ppb * ps * 4)                  # f32 scale tiles
-    compute = (2 * ppb * ps * dh + g * ps + g * dh + 2 * g) * 4
-    return io + compute
+    return _paged_kernel_vmem(ps, ppb, g, dh, per_device_heads or kvh,
+                              itemsize, 1, scales=True)
 
 
 def _paged_q8_probe_fn(q4, kp, vp, ksc, vsc, pt, lens, kn, vn, *, ppb: int,
@@ -1286,9 +1349,16 @@ def _run_pallas_paged(q, k_pages, v_pages, page_table, length, k_new, v_new,
         g=q.shape[2] // k_pages.shape[2], dh=q.shape[-1],
         page_size=k_pages.shape[1], ctx=_paged_ctx_fact(page_table, k_pages),
         dtype=q.dtype)[1]
-    return paged_decode_attention(q, k_pages, v_pages, page_table, length,
-                                  k_new, v_new, pages_per_block=ppb,
-                                  interpret=interpret)
+
+    def kernel(q, k_pages, v_pages, page_table, length, k_new, v_new):
+        return paged_decode_attention(q, k_pages, v_pages, page_table,
+                                      length, k_new, v_new,
+                                      pages_per_block=ppb,
+                                      interpret=interpret)
+
+    return on_mesh(kernel,
+                   (q, k_pages, v_pages, page_table, length, k_new, v_new),
+                   (_HEADS, _HEADS, _HEADS, _REPL, _REPL, _HEADS, _HEADS))
 
 
 @register_impl("paged_decode", "jnp_paged", layout=_PAGED_LAYOUT,
@@ -1320,10 +1390,20 @@ def _run_pallas_paged_q8(q, k_pages, v_pages, page_table, length, k_new,
         g=q.shape[2] // k_pages.shape[2], dh=q.shape[-1],
         page_size=k_pages.shape[1], ctx=_paged_ctx_fact(page_table, k_pages),
         dtype=q.dtype)[1]
-    return paged_decode_attention_q8(q, k_pages, v_pages, page_table,
-                                     length, k_new, v_new, k_scale=k_scale,
-                                     v_scale=v_scale, pages_per_block=ppb,
-                                     interpret=interpret)
+
+    def kernel(q, k_pages, v_pages, page_table, length, k_new, v_new,
+               k_scale, v_scale):
+        return paged_decode_attention_q8(q, k_pages, v_pages, page_table,
+                                         length, k_new, v_new,
+                                         k_scale=k_scale, v_scale=v_scale,
+                                         pages_per_block=ppb,
+                                         interpret=interpret)
+
+    return on_mesh(kernel,
+                   (q, k_pages, v_pages, page_table, length, k_new, v_new,
+                    k_scale, v_scale),
+                   (_HEADS, _HEADS, _HEADS, _REPL, _REPL, _HEADS, _HEADS,
+                    _REPL, _REPL))
 
 
 @register_impl("paged_decode", "jnp_paged_q8", layout=_PAGED_Q8_LAYOUT,
@@ -1537,13 +1617,16 @@ def _ssd_candidates(*, s: int, **facts) -> Tuple[Tuple[int], ...]:
     return cands or ((s,),)
 
 
-def _ssd_vmem(cand, itemsize, *, dk, dv, **facts) -> int:
+def _ssd_vmem(cand, itemsize, *, s, dk, dv, **facts) -> int:
     (c,) = cand
-    # q/k [c,dk] + v/y [c,dv] double-buffered; [c,c] score tile + C/n
-    # state live once in f32 scratch
-    io = 2 * (2 * c * dk + 2 * c * dv + 2 * c) * itemsize
+    c = min(c, s)
+    # q/k [c,dk] + v/y [c,dv] double-buffered; both gates as whole-row
+    # [chunks, c] f32 blocks, double-buffered (they grow with s); [c,c]
+    # score tile + C/n state live once in f32 scratch
+    io = 2 * (2 * c * dk + 2 * c * dv) * itemsize
+    gates = 2 * 2 * _tile_bytes(-(-s // c), c, 4)
     compute = (c * c + dk * dv + dk) * 4
-    return io + compute
+    return io + gates + compute
 
 
 def _ssd_probe_fn(q, k, v, lf, li, *, chunk: int, normalize: bool,

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import registry
 from repro.kernels.registry import (TuneSpace, _backend, _dtype_name,
                                     _pow2_up, best, default_interpret,
                                     register_family, register_impl)
@@ -121,7 +122,9 @@ def _argmax_kernel(x_ref, val_ref, idx_ref, *, block_vocab: int):
         val_ref[...] = jnp.full_like(val_ref, -jnp.inf)
         idx_ref[...] = jnp.zeros_like(idx_ref)
 
-    x = x_ref[...]                                      # [br, bv]
+    # compare in f32: widening is exact, and Mosaic cannot lay out the
+    # bf16 compare mask that the lowest-index select needs
+    x = x_ref[...].astype(jnp.float32)                  # [br, bv]
     ids = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     loc_val = jnp.max(x, axis=1)                        # [br]
     # lowest column index attaining the block max (jnp.argmax semantics)
@@ -153,7 +156,7 @@ def block_argmax(x: jnp.ndarray, *, block_rows: int = 8,
                                lambda i, j: (i, j))],
         out_specs=[pl.BlockSpec((block_rows, LANES), lambda i, j: (i, 0)),
                    pl.BlockSpec((block_rows, LANES), lambda i, j: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((rows, LANES), x.dtype),
+        out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
                    jax.ShapeDtypeStruct((rows, LANES), jnp.int32)],
         interpret=interpret,
     )(x)
@@ -167,8 +170,13 @@ def _resolved_argmax(x, *, method: str, block, interpret) -> jnp.ndarray:
         b, v = x.shape
         block = best("sampling", b=b, v=v, method=method, dtype=x.dtype)
     br, bv = (int(c) for c in block)
-    return block_argmax(x, block_rows=br, block_vocab=bv,
-                        interpret=interpret)
+    # under a serving mesh the vocab axis may be sharded: every device
+    # reduces the whole (gathered) row, as a Mosaic kernel cannot be
+    # partitioned
+    return registry.on_mesh(
+        functools.partial(block_argmax, block_rows=br, block_vocab=bv,
+                          interpret=interpret),
+        (x,), (jax.sharding.PartitionSpec(),), jax.sharding.PartitionSpec())
 
 
 # ---------------------------------------------------------------------------
@@ -326,6 +334,5 @@ def sample(logits, key=None, *, method: str = "greedy",
            temperature: float = 1.0, k: int = 0, p: float = 1.0,
            impl: Optional[str] = None) -> jnp.ndarray:
     """Dispatch one sampling step through the registry ladder."""
-    from repro.kernels import registry
     return registry.run("sampling", logits, key, impl=impl, method=method,
                         temperature=temperature, k=k, p=p)

@@ -46,32 +46,45 @@ def _ssd_kernel(q_ref, k_ref, v_ref, lf_ref, li_ref, y_ref, c_out_ref,
     q = q_ref[...].astype(jnp.float32)              # [c, dk]
     k = k_ref[...].astype(jnp.float32)              # [c, dk]
     v = v_ref[...].astype(jnp.float32)              # [c, dv]
-    lf = lf_ref[...].astype(jnp.float32)[0]         # [c]
-    li = li_ref[...].astype(jnp.float32)[0]         # [c]
+    # the gate blocks hold the row's whole [chunks, c] gate matrix; take
+    # this chunk's row
+    lf = lf_ref[pl.ds(j, 1), :].astype(jnp.float32)      # [1, c]
+    li = li_ref[pl.ds(j, 1), :].astype(jnp.float32)      # [1, c]
 
-    Bc = jnp.cumsum(lf)                             # [c]
-    total = Bc[-1]
+    # Mosaic has no cumsum: the inclusive prefix sum Bc[t] = sum_{s<=t}
+    # lf[s] is a matmul with the lower-triangular ones matrix, taken in
+    # both orientations (column for the query side, row for the key
+    # side), and li is turned into a column by the identity.  HIGHEST
+    # keeps the f32 gates exact through the MXU.
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    tri = col <= row
+    nt = (((1,), (1,)), ((), ()))                   # A @ B^T
+    hi = jax.lax.Precision.HIGHEST
+    lower = tri.astype(jnp.float32)
+    bc_col = jax.lax.dot_general(lower, lf, nt, precision=hi)   # [c, 1]
+    bc_row = jax.lax.dot_general(lf, lower, nt, precision=hi)   # [1, c]
+    li_col = jax.lax.dot_general((row == col).astype(jnp.float32), li, nt,
+                                 precision=hi)                  # [c, 1]
+    total = jnp.sum(lf, axis=1, keepdims=True)      # [1, 1]
 
     # inter-chunk: contribution of the carried state
-    qd = q * jnp.exp(Bc)[:, None]                   # [c, dk]
+    qd = q * jnp.exp(bc_col)                        # [c, dk]
     y_inter = jax.lax.dot(qd, C_ref[...])           # [c, dv]
-    n_inter = jax.lax.dot(qd, n_ref[...].T)[:, 0]   # [c]
+    n_inter = jax.lax.dot_general(qd, n_ref[...], nt)   # [c, 1]
 
     # intra-chunk: decay-masked attention
-    gap = Bc[:, None] - Bc[None, :] + li[None, :]   # [c, c]
-    tri = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1) <= \
-        jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    gap = bc_col - bc_row + li                      # [c, c]
     A = jnp.where(tri, jnp.exp(gap), 0.0)
-    scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * A
+    scores = jax.lax.dot_general(q, k, nt) * A
     y = y_inter + jax.lax.dot(scores, v)
     if normalize:
-        denom = jnp.abs(n_inter + jnp.sum(scores, axis=1))
-        y = y / jnp.maximum(denom, eps)[:, None]
+        denom = jnp.abs(n_inter + jnp.sum(scores, axis=1, keepdims=True))
+        y = y / jnp.maximum(denom, eps)
     y_ref[...] = y.astype(y_ref.dtype)
 
     # state update
-    wj = jnp.exp(total - Bc + li)                   # [c]
-    kw = k * wj[:, None]                            # [c, dk]
+    kw = k * jnp.exp(total - bc_col + li_col)       # [c, dk]
     C_ref[...] = jnp.exp(total) * C_ref[...] + \
         jax.lax.dot_general(kw, v, (((0,), (0,)), ((), ())))
     n_ref[...] = jnp.exp(total) * n_ref[...] + \
@@ -88,13 +101,16 @@ def _ssd_kernel(q_ref, k_ref, v_ref, lf_ref, li_ref, y_ref, c_out_ref,
 def ssd_scan_flat(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                   log_f: jnp.ndarray, log_i: jnp.ndarray, *,
                   chunk: int = 128, normalize: bool = False,
-                  eps: float = 1e-6, interpret: bool = True
+                  eps: float = 1e-6, interpret: bool | None = None
                   ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Flat layout: q,k [BH,S,dk]; v [BH,S,dv]; log_f/log_i [BH,S].
 
     Returns (y [BH,S,dv], (C [BH,dk,dv], n [BH,1,dk])).
     S is padded to a chunk multiple with log_i = -1e9 (inert writes).
     """
+    if interpret is None:
+        from repro.kernels.registry import default_interpret
+        interpret = default_interpret()
     bh, s, dk = q.shape
     dv = v.shape[-1]
     c = min(chunk, s)
@@ -105,7 +121,14 @@ def ssd_scan_flat(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         log_f = zp(log_f)
         log_i = jnp.pad(log_i, ((0, 0), (0, pad)), constant_values=-1e9)
     nc = q.shape[1] // c
-    # gates as [BH, 1, S]-style blocks: keep 2D block (1, c) on [BH, S]
+    # gates as [BH, chunks, c] with one whole-row block per BH: a (1, c)
+    # block of [BH, S] puts 1 in the sublane dim, which the TPU tiling
+    # refuses; a full [chunks, c] block is always accepted.  f32, because
+    # the kernel selects its chunk's row at a dynamic sublane offset,
+    # which packed bf16 rows cannot serve (the kernel computes in f32
+    # anyway, so the widening changes nothing)
+    log_f = log_f.astype(jnp.float32).reshape(bh, nc, c)
+    log_i = log_i.astype(jnp.float32).reshape(bh, nc, c)
     y, c_out, n_out = pl.pallas_call(
         functools.partial(_ssd_kernel, c=c, normalize=normalize, eps=eps),
         grid=(bh, nc),
@@ -113,8 +136,8 @@ def ssd_scan_flat(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((None, c, dk), lambda b, j: (b, j, 0)),
             pl.BlockSpec((None, c, dk), lambda b, j: (b, j, 0)),
             pl.BlockSpec((None, c, dv), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, c), lambda b, j: (b, j)),
-            pl.BlockSpec((1, c), lambda b, j: (b, j)),
+            pl.BlockSpec((None, nc, c), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((None, nc, c), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, c, dv), lambda b, j: (b, j, 0)),

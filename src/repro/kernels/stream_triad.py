@@ -37,9 +37,12 @@ def stream_triad_kernel(b_ref, c_ref, a_ref, *, s: float):
                    static_argnames=("s", "block_rows", "interpret",
                                     "pipelined"))
 def stream_triad(b: jnp.ndarray, c: jnp.ndarray, *, s: float = 2.5,
-                 block_rows: int = 256, interpret: bool = True,
+                 block_rows: int = 256, interpret: bool | None = None,
                  pipelined: bool = True) -> jnp.ndarray:
     """b, c: flat [N] arrays with N % 128 == 0.  Returns a = b + s*c."""
+    if interpret is None:
+        from repro.kernels.registry import default_interpret
+        interpret = default_interpret()
     assert b.shape == c.shape and b.ndim == 1, (b.shape, c.shape)
     n = b.shape[0]
     assert n % LANES == 0, f"N={n} must be lane-aligned ({LANES})"

@@ -20,6 +20,9 @@ with divergent spellings; these helpers make the surface uniform:
 * :func:`add_cache_args` — ``--cache-dir`` / ``--no-cache`` over the
   compile-artifact cache.
 * :func:`add_json_args` — ``--json PATH`` machine-readable summary.
+* :func:`enable_compile_cache` — JAX's persistent compilation cache,
+  placed from outside (``$JAX_COMPILATION_CACHE_DIR``) or at the
+  checkout's fixed ``.jax_cache``.
 
 Consume with :func:`impl_context` (a ``use_impl`` context covering both
 ``--impl`` and the legacy ``--attn-impl``), :func:`session_from_args`
@@ -31,8 +34,27 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import os
 import warnings
 from typing import Dict, Optional
+
+#: the checkout root (``src/repro/launch/cli.py`` -> three levels up)
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set (no other directory is
+    configured); otherwise the cache lives at ``<checkout>/.jax_cache`` —
+    a fixed path, because the path is part of what a later process must
+    find again.  Entry points call this; importing sets nothing."""
+    import jax
+    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(CHECKOUT, ".jax_cache"))
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def add_impl_args(ap: argparse.ArgumentParser, *, tune: bool = True,
@@ -296,10 +318,14 @@ def impl_context(args: argparse.Namespace):
     return registry.use_impl(**impls) if impls else contextlib.nullcontext()
 
 
-def session_from_args(args: argparse.Namespace):
-    """A ProfileSession honouring ``--cache-dir`` / ``--no-cache``."""
+def session_from_args(args: argparse.Namespace, chip=None):
+    """A ProfileSession honouring ``--cache-dir`` / ``--no-cache``.
+
+    ``chip`` defaults to the device's datasheet; the dry-run tools pass
+    the v5e they model (``hwinfo.DEFAULT_CHIP``)."""
     from repro.core.session import ProfileSession
     return ProfileSession(cache_dir=getattr(args, "cache_dir", None),
+                          chip=chip,
                           enabled=not getattr(args, "no_cache", False))
 
 

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this builds the REAL step function (the same factories the
@@ -18,11 +14,16 @@ writes one JSON record per cell under experiments/dryrun/.
 Per-arch TRAIN POLICY (accum steps, remat, SP, moment dtype) lives in
 ``TRAIN_POLICY`` — the knobs that make the 123B/235B cells fit 16 GiB v5e
 HBM; EXPERIMENTS.md §Dry-run documents each.
+
+The production mesh needs 512 devices; the CPU backend gets them from
+:func:`force_host_devices`, which each dry-run entry point calls before
+JAX initialises its backends.  Importing this module sets nothing.
 """
 
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import Any, Dict, Optional
@@ -45,7 +46,21 @@ from repro.optim import AdamWConfig, ScheduleConfig
 from repro.train.step import (init_train_state, make_train_step,
                               train_state_pspecs)
 
-__all__ = ["run_cell", "main", "TRAIN_POLICY"]
+__all__ = ["run_cell", "main", "TRAIN_POLICY", "force_host_devices"]
+
+_HOST_DEVICE_FLAG = "--xla_force_host_platform_device_count"
+
+
+def force_host_devices() -> None:
+    """Give the CPU backend the 512 devices of the modelled production mesh.
+
+    Appends the flag to ``XLA_FLAGS`` (flags already set are kept, an
+    explicit device count included).  Effective only before JAX
+    initialises its backends, so only a process dedicated to the dry run
+    calls it."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if _HOST_DEVICE_FLAG not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {_HOST_DEVICE_FLAG}=512".strip()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -360,6 +375,7 @@ def _emit(rec: Dict[str, Any], out_dir: Optional[str], verbose: bool):
 
 
 def main(argv=None) -> int:
+    force_host_devices()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES))
@@ -423,7 +439,7 @@ def main(argv=None) -> int:
     if not (args.all or args.arch or args.shape):
         ap.error("pass --all or --arch/--shape")
 
-    session = cli.session_from_args(args)
+    session = cli.session_from_args(args, chip=hwinfo.DEFAULT_CHIP)
     if args.tune:
         cli.run_tune_suite(session)
 

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core import pin as pin_mod
 from repro.core import topology as topo_mod
+from repro.core.pin import auto_mesh
 
 __all__ = ["make_production_mesh", "mesh_axes", "production_topology",
            "ServeMesh", "make_serve_mesh", "axis_ici_map"]
@@ -50,7 +51,7 @@ def make_production_mesh(*, multi_pod: bool = False,
     """
     shape, axes = mesh_axes(multi_pod)
     if pin_strategy is None and not skip:
-        return jax.make_mesh(shape, axes)
+        return auto_mesh(shape, axes)
     topo = production_topology(multi_pod)
     result = pin_mod.get_strategy(pin_strategy or "compact")(topo, skip=skip)
     devices = list(jax.devices())
@@ -63,7 +64,7 @@ def make_production_mesh(*, multi_pod: bool = False,
             f"mesh needs {need} (skip={list(skip)})")
     by_id = {d.id: d for d in devices}
     ordered = [by_id[i] for i in result.device_ids[:need]]
-    return jax.make_mesh(shape, axes, devices=ordered)
+    return auto_mesh(shape, axes, devices=ordered)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,8 +124,7 @@ def make_serve_mesh(shape: Sequence[int],
     used = result.device_ids[:need]
     spares = tuple(result.skipped) + tuple(result.device_ids[need:])
     by_id = {d.id: d for d in devices}
-    mesh = jax.make_mesh(tuple(shape), tuple(axes),
-                         devices=[by_id[i] for i in used])
+    mesh = auto_mesh(shape, axes, devices=[by_id[i] for i in used])
     return ServeMesh(mesh=mesh, topo=topo, axis_names=tuple(axes),
                      axis_sizes=tuple(shape), pin=result, spares=spares)
 

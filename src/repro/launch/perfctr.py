@@ -37,8 +37,9 @@ def main(argv=None) -> int:
         print(list_groups())
         return 0
 
-    # Reuse the dry-run lowering (sets XLA_FLAGS before jax init).
+    # Reuse the dry-run lowering (its mesh needs the forced host devices).
     from repro.launch import dryrun
+    dryrun.force_host_devices()
     import jax
     from repro.configs import SHAPES, get_arch, input_specs
     from repro.core import hwinfo
@@ -46,7 +47,7 @@ def main(argv=None) -> int:
     from repro.core.groups import get_group
     from repro.core.perfctr import Measurement
 
-    session = cli.session_from_args(args)
+    session = cli.session_from_args(args, chip=hwinfo.DEFAULT_CHIP)
     if args.tune:
         cli.run_tune_suite(session)
     with cli.impl_context(args):

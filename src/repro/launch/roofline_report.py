@@ -129,10 +129,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.sweep:
-        # dryrun must be imported before jax init (it sets XLA_FLAGS)
-        from repro.launch import dryrun  # noqa: F401
+        # the dry-run cells need the forced host devices (before jax init)
+        from repro.launch import dryrun
+        dryrun.force_host_devices()
         from repro.configs import SHAPES, list_archs
-        session = cli.session_from_args(args)
+        from repro.core import hwinfo
+        session = cli.session_from_args(args, chip=hwinfo.DEFAULT_CHIP)
         if args.tune:
             cli.run_tune_suite(session)
         archs = (args.archs.split(",") if args.archs

@@ -69,6 +69,7 @@ def main(argv=None) -> int:
                     help="probe serve regions through PerfCtr and report")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args(argv)
+    cli.enable_compile_cache()
 
     import jax
     import numpy as np
@@ -79,24 +80,6 @@ def main(argv=None) -> int:
 
     spec = get_arch(args.arch)
     cfg = spec.smoke if args.smoke_dims else spec.config
-    feats = default_features().with_(remat_policy="none")
-    lm = LM(cfg, feats)
-    params = lm.init(jax.random.PRNGKey(0))
-    if args.ckpt_dir:
-        from repro.checkpoint import restore_checkpoint
-        from repro.optim import AdamWConfig
-        from repro.train import init_train_state
-        state = init_train_state(lm, jax.random.PRNGKey(0), AdamWConfig())
-        state, _ = restore_checkpoint(args.ckpt_dir, target=state)
-        params = state.params
-        print("[serve] restored params from checkpoint")
-
-    from repro.kernels import registry
-    impls = registry.parse_impl_spec(args.impl) if args.impl else None
-    # --attn-impl stays the ServeConfig spelling (the engine validates
-    # and expands it itself); cli.resolve_impls is for the non-serve
-    # tools.  The warning path is the shared one.
-    cli.warn_legacy_attn_impl(args.attn_impl)
     serve_mesh = None
     if args.mesh:
         from repro.launch.mesh import axis_ici_map, make_serve_mesh
@@ -113,6 +96,28 @@ def main(argv=None) -> int:
                    else f"mean {row['mean_hops']:.1f} hops")
             print(f"[serve]   axis {row['axis']:<6} "
                   f"size {row['size']:>3}  {lay}")
+    feats = default_features().with_(remat_policy="none")
+    lm = LM(cfg, feats)
+    # sharded engines get their weights initialised in place, already
+    # split over the mesh (Engine's own device_put is then a no-op)
+    params = lm.init_params(
+        jax.random.PRNGKey(0),
+        mesh=serve_mesh.mesh if serve_mesh is not None else None)
+    if args.ckpt_dir:
+        from repro.checkpoint import restore_checkpoint
+        from repro.optim import AdamWConfig
+        from repro.train import init_train_state
+        state = init_train_state(lm, jax.random.PRNGKey(0), AdamWConfig())
+        state, _ = restore_checkpoint(args.ckpt_dir, target=state)
+        params = state.params
+        print("[serve] restored params from checkpoint")
+
+    from repro.kernels import registry
+    impls = registry.parse_impl_spec(args.impl) if args.impl else None
+    # --attn-impl stays the ServeConfig spelling (the engine validates
+    # and expands it itself); cli.resolve_impls is for the non-serve
+    # tools.  The warning path is the shared one.
+    cli.warn_legacy_attn_impl(args.attn_impl)
     serve_cfg = ServeConfig(
         max_seq=args.max_seq, batch_slots=args.slots,
         temperature=args.temperature,

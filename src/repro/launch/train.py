@@ -41,6 +41,8 @@ def main(argv=None) -> int:
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--remat", default="none")
     args = ap.parse_args(argv)
+    from repro.launch import cli
+    cli.enable_compile_cache()
 
     import jax
     from repro.configs import get_arch

@@ -204,6 +204,24 @@ class LM:
             raise ValueError(f"unknown family {fam!r}")
         return p
 
+    def init_params(self, rng, *, mesh=None, dtype=None) -> Params:
+        """:meth:`init` as one jitted program whose outputs are created
+        where they live: sharded over ``mesh`` by :meth:`param_pspecs`
+        when one is given (no device holds the whole tree first), and
+        cast to ``dtype`` on the way out (e.g. bf16 serving weights)."""
+        def init(key):
+            p = self.init(key)
+            if dtype is None:
+                return p
+            return jax.tree.map(lambda x: x.astype(dtype), p)
+
+        if mesh is None:
+            return jax.jit(init)(rng)
+        from jax.sharding import NamedSharding
+        pspecs = self.param_pspecs(mesh, jax.eval_shape(init, rng))
+        return jax.jit(init, out_shardings=jax.tree.map(
+            lambda s: NamedSharding(mesh, s), pspecs))(rng)
+
     def param_specs(self) -> Specs:
         cfg = self.cfg
         s: Specs = {"embed": {"table": ("vocab", "embed")}}

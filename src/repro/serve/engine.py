@@ -483,7 +483,8 @@ class Engine:
         if self.cfg.impls:
             stack.enter_context(registry.use_impl(**dict(self.cfg.impls)))
         if self.mesh is not None:
-            stack.enter_context(registry.use_mesh_facts(**self.mesh_facts))
+            stack.enter_context(registry.use_mesh_facts(mesh=self.mesh,
+                                                        **self.mesh_facts))
         return stack
 
     @property
@@ -837,8 +838,11 @@ class Engine:
                                                         state)
                     return (logits, state, rng), nxt
 
-                (logits, state, rng), toks = jax.lax.scan(
-                    body, (logits, state, rng), None, length=steps)
+                # the engine's kernel pins hold wherever the segment is
+                # traced (the scheduler calls it outside _impl_ctx)
+                with self._impl_ctx():
+                    (logits, state, rng), toks = jax.lax.scan(
+                        body, (logits, state, rng), None, length=steps)
                 return toks.T, logits, state, rng
 
             fn = self._segments[steps] = jax.jit(seg, donate_argnums=(1, 2))
@@ -962,8 +966,9 @@ class Engine:
         if self._spec_seg is None:
             def seg(params, dparams, state, dstate, logits, rng,
                     spec_mask):
-                return self._spec_round(params, dparams, state, dstate,
-                                        logits, rng, spec_mask)
+                with self._impl_ctx():
+                    return self._spec_round(params, dparams, state, dstate,
+                                            logits, rng, spec_mask)
 
             self._spec_seg = jax.jit(seg, donate_argnums=(2, 3, 4))
         return self._spec_seg
@@ -1230,7 +1235,7 @@ class Engine:
             perfctr.probe(self.lm.prefill, params_s,
                           {"tokens": toks_s}, state_s)
         tok_s = jax.ShapeDtypeStruct((b, 1), jnp.int32)
-        with perfctr.marker(DECODE_REGION):
+        with perfctr.marker(DECODE_REGION), self._impl_ctx():
             perfctr.probe(self.lm.decode_step, params_s, tok_s, state_s)
 
     def restore(self, path: str, **scheduler_kwargs) -> "BatchScheduler":

@@ -68,10 +68,10 @@ def test_extract_events_from_synthetic_text():
 
 def test_collectives_in_scan_counted_dynamically():
     """An all-reduce inside a scanned body must count trip_count times."""
-    mesh = jax.make_mesh((1,), ("d",))
+    from jax.sharding import PartitionSpec as P
 
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.core.pin import auto_mesh
+    mesh = auto_mesh((1,), ("d",))
 
     def step(x):
         def body(c, _):
@@ -80,7 +80,7 @@ def test_collectives_in_scan_counted_dynamically():
         y, _ = jax.lax.scan(body, x, None, length=9)
         return y
 
-    f = shard_map(step, mesh=mesh, in_specs=P("d"), out_specs=P("d"))
+    f = jax.shard_map(step, mesh=mesh, in_specs=P("d"), out_specs=P("d"))
     c = jax.jit(f).lower(jnp.ones((4,), jnp.float32)).compile()
     ev = extract_events(compiled=c, num_devices=1)
     # 9 dynamic executions (single-device group -> zero wire bytes, but the

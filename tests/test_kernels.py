@@ -1,7 +1,8 @@
 """Per-kernel shape/dtype sweeps against the pure-jnp oracles (ref.py).
 
-All kernels run in interpret mode here (CPU container); on TPU the same
-pallas_call compiles (REPRO_KERNEL_COMPILE=1).
+On a CPU backend every kernel runs in interpret mode; on a TPU the same
+pallas_call compiles (``registry.default_interpret`` decides from the
+backend).
 """
 
 import jax

@@ -185,7 +185,7 @@ def test_paged_autotune_vmem_gate(tmp_path):
     sess = ProfileSession(cache_dir=str(tmp_path / "cache"))
     rec = autotune.autotune_paged_decode(
         **PAGED_SHAPE, session=sess, candidates=((16, 1), (64, 4)),
-        vmem_fraction=1e-4)
+        vmem_fraction=0.02)
     assert rec.scores[(64, 4)] == float("inf")   # gated, never lowered
     assert sess.lowerings == 1
     with pytest.raises(ValueError):

@@ -347,7 +347,7 @@ def test_autotune_vmem_gate_and_no_fit():
         # budget sized so (64,) fits and (128,) does not
         rec = registry.autotune("stream_triad", sess, n=TRIAD_N,
                                 candidates=((64,), (128,)),
-                                vmem_fraction=2.5e-3)
+                                vmem_fraction=2e-2)
         assert rec.scores[(128,)] == float("inf")    # gated, never lowered
         assert rec.choice == (64,) and sess.lowerings == 1
         with pytest.raises(ValueError, match="fits VMEM"):
@@ -530,7 +530,7 @@ def test_interpolation_vmem_gates_adopted_choice():
         key4 = registry.attention_tune_key(b=4, **shape, **dt)
         huge = (1 << 15, 1 << 15)
         assert registry.attention_vmem(*huge, shape["dh"]) \
-            > hwinfo.DEFAULT_CHIP.vmem_bytes * 0.9
+            > hwinfo.device_chip().scoped_vmem_bytes * 0.9
         registry.record("attention", key4, huge)
         # b=8 interpolates from the b=4 bucket first, but the choice
         # busts the budget -> skipped -> declared default
@@ -570,3 +570,11 @@ def test_stale_negative_cache_dropped_when_custom_root_registers():
             assert registry.best("stream_triad", n=TRIAD_N) == rec.choice
     finally:
         registry.clear_tune_table()
+
+
+def test_default_interpret_follows_the_backend_alone(monkeypatch):
+    """The backend alone decides: a TPU always compiles its kernels."""
+    assert registry.default_interpret("tpu") is False
+    assert registry.default_interpret("cpu") is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert registry.default_interpret() is False

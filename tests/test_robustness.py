@@ -495,14 +495,24 @@ def test_cli_ft_and_robustness_flags(tmp_path):
         cli.robustness_kwargs(args2)
 
 
-def test_serve_json_includes_robustness(tmp_path):
+def test_serve_json_includes_robustness(tmp_path, monkeypatch):
     """launch/serve.py end-to-end with the new flags (tiny smoke)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
     from repro.launch.serve import main
+    # the launcher turns JAX's persistent compile cache on: keep it in tmp
+    # and off again for the rest of this process
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+    was = jax.config.jax_compilation_cache_dir
     out = str(tmp_path / "serve.json")
-    rc = main(["--arch", "qwen2-0.5b", "--smoke-dims", "--requests", "4",
-               "--prompt-len", "6", "--max-new", "4", "--max-seq", "64",
-               "--max-queue", "2", "--snapshot-dir",
-               str(tmp_path / "snaps"), "--json", out])
+    try:
+        rc = main(["--arch", "qwen2-0.5b", "--smoke-dims", "--requests",
+                   "4", "--prompt-len", "6", "--max-new", "4",
+                   "--max-seq", "64", "--max-queue", "2", "--snapshot-dir",
+                   str(tmp_path / "snaps"), "--json", out])
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()
     assert rc == 0
     d = json.load(open(out))
     assert d["rejections"] == 2 and d["snapshots"] >= 1

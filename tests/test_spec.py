@@ -65,6 +65,15 @@ def spec_engine(models):
     return Engine(lm, tp, SCFG, spec=spec, draft_params=dp)
 
 
+@pytest.fixture(scope="module")
+def exact_spec_engine(models):
+    """Draft == target (same config, same weights): greedy drafts verify,
+    so rounds commit more than one token."""
+    lm, tp, _dp = models
+    spec = SpecConfig(draft_config=TCFG, num_draft_tokens=4)
+    return Engine(lm, tp, SCFG, spec=spec, draft_params=tp)
+
+
 # ---------------------------------------------------------------------------
 # greedy parity: fused / streaming / scheduler
 # ---------------------------------------------------------------------------
@@ -77,21 +86,28 @@ def test_fused_greedy_parity(spec_engine, ref_tokens):
 
 
 def test_streaming_parity_and_callback_reconstruction(spec_engine,
+                                                      exact_spec_engine,
                                                       ref_tokens):
-    events = []
-    out = spec_engine.generate(
-        PROMPTS, max_new_tokens=24,
-        stream_cb=lambda i, toks, done: events.append((i, list(toks), done)))
-    assert out == ref_tokens
-    rebuilt = [[] for _ in PROMPTS]
-    for i, toks, _done in events:
-        rebuilt[i].extend(toks)
-    assert rebuilt == ref_tokens
-    # blockwise: spec rows stream up to K+1 tokens per round, so there
-    # are strictly fewer callback waves than tokens
+    for eng in (spec_engine, exact_spec_engine):
+        events = []
+        out = eng.generate(
+            PROMPTS, max_new_tokens=24,
+            stream_cb=lambda i, toks, done: events.append(
+                (i, list(toks), done)))
+        assert out == ref_tokens
+        rebuilt = [[] for _ in PROMPTS]
+        for i, toks, _done in events:
+            rebuilt[i].extend(toks)
+        assert rebuilt == ref_tokens
+        last = {i: done for i, _t, done in events}
+        assert all(last[i] for i in range(len(PROMPTS)))
+    # blockwise: a round streams its pending token plus every accepted
+    # draft in ONE callback, so once drafts verify there are strictly
+    # fewer callback waves than tokens.  (The independent random draft
+    # above is never accepted under greedy, so it streams one token per
+    # wave — which is why this is checked on the exact draft.)
+    assert exact_spec_engine.spec_stats["accepted"] > 0
     assert len(events) < sum(len(t) for t in ref_tokens)
-    last = {i: done for i, _t, done in events}
-    assert all(last[i] for i in range(len(PROMPTS)))
 
 
 def test_scheduler_mixed_batch_parity(models, base_engine, spec_engine):

@@ -93,5 +93,9 @@ def test_chip_datasheet_lookup():
     assert chip.peak_bf16_flops == 197e12
     assert chip.hbm_bw == 819e9
     assert chip.ici_bw_per_link == 50e9
-    # unknown kinds fall back to the default chip rather than crashing
-    assert hwinfo.lookup_chip("weird-device").name
+    assert hwinfo.lookup_chip("TPU v5 lite") is chip
+    assert hwinfo.lookup_chip("cpu").name == "host-cpu"
+    # a kind with no datasheet is an error, never another chip's peaks
+    for kind in ("weird-device", "TPU v9 unknown"):
+        with pytest.raises(ValueError, match="no datasheet"):
+            hwinfo.lookup_chip(kind)
