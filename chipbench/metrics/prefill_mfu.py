@@ -1,0 +1,18 @@
+"""Model FLOPs of the prefilled tokens (layers, causal attention over
+prefix and suffix, the head at the last position) over the slot-prefill
+programs' device time times the chip's bf16 peak, in the traced
+stretch."""
+
+from chipbench import trace as tr
+from chipbench import work
+from chipbench.reading import prefills, traced
+
+
+def read(rec):
+    if not traced(rec):
+        return None
+    flops = sum(work.prefill_flops(rec.cfg, n, p) for n, p in prefills(rec))
+    secs = tr.program_seconds(rec.trace, "prefill")
+    if not flops or not secs:
+        return None
+    return 100.0 * flops / (secs * work.peaks(rec.device_kind)["bf16_flops"])
