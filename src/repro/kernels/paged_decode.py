@@ -139,11 +139,11 @@ def _paged_call(q4, k_pages, v_pages, scales, page_table, lengths, k_new,
     """Shared pallas_call for the fp and int8 page flavors.
 
     ``scales`` is None (fp pages) or ``(k_scale, v_scale)`` ``[P, ps]``.
-    The pool ``[P, ps, KVH, Dh]`` is viewed as ``[P, ps, KVH*Dh]`` (a free
-    reshape): the block's minor dim is then the whole lane-dense row,
-    which the TPU tiling accepts for any KVH and Dh — a per-head block
-    would put KVH in the sublane dim, refused unless KVH is a multiple
-    of 8.  ``compiler_params`` (``pltpu.CompilerParams``) passes through
+    The pool ``[P, ps, KVH, Dh]`` is viewed as ``[P, ps, KVH*Dh]`` (a
+    reshape, though not a free one on a v5e): the block's minor dim is
+    then the whole lane-dense row, which the TPU tiling accepts for any
+    KVH and Dh — a per-head block would put KVH in the sublane dim,
+    refused unless KVH is a multiple of 8.  ``compiler_params`` (``pltpu.CompilerParams``) passes through
     to the ``pallas_call``, e.g. to hold the kernel to a VMEM limit.
     """
     if interpret is None:
@@ -178,8 +178,13 @@ def _paged_call(q4, k_pages, v_pages, scales, page_table, lengths, k_new,
 
     kv_specs = [pl.BlockSpec((None, ps, w), page_map(i, 0))
                 for i in range(ppb)]
-    operands = [k_pages.reshape(p_total, ps, w)] * ppb \
-        + [v_pages.reshape(p_total, ps, w)] * ppb
+    # on a v5e the [ps, KVH, Dh] and [ps, W] tilings differ, so XLA
+    # copies the whole pool to make this view: pool movement outside the
+    # kernel, named as such
+    with jax.named_scope("kv_cache"):
+        k_rows = k_pages.reshape(p_total, ps, w)
+        v_rows = v_pages.reshape(p_total, ps, w)
+    operands = [k_rows] * ppb + [v_rows] * ppb
     in_pages = kv_specs * 2
     if scales is not None:
         # [P, ps] -> [P, ps, 1]: the in-kernel scale block is a [ps, 1]

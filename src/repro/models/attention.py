@@ -769,7 +769,8 @@ def _suffix_prefill_attend(p: Params, x: jnp.ndarray, cfg: AttnConfig,
     b, s, _ = x.shape
     positions = prefix_len[:, None] + jnp.arange(s)[None, :]
     q, k, v = _project_qkv(p, x, cfg, positions, positions3)
-    k_ctx, v_ctx = _gather_ctx(cache, q.dtype)
+    with jax.named_scope("kv_cache"):
+        k_ctx, v_ctx = _gather_ctx(cache, q.dtype)
     ctx_w = k_ctx.shape[1]
     # joint mask over [ctx | suffix] keys: ctx key j real iff j < prefix;
     # suffix key t visible iff t <= i (causal) and t < suffix length
@@ -822,27 +823,28 @@ def prefill_into_paged_cache(p: Params, x: jnp.ndarray, cfg: AttnConfig,
                                            suffix_len, positions3)
         new_len = prefix_len + suffix_len
         start = prefix_len
-    if cache.quantized:
-        k_codes, k_sc = quantize_kv_rows(k)
-        v_codes, v_sc = quantize_kv_rows(v)
-        newk = _scatter_pages_at(cache.k_pages, cache.page_table, k_codes,
-                                 start, suffix_len)
-        newv = _scatter_pages_at(cache.v_pages, cache.page_table, v_codes,
-                                 start, suffix_len)
-        new_ks = _scatter_scales_at(cache.k_scale, cache.page_table, k_sc,
-                                    start, suffix_len)
-        new_vs = _scatter_scales_at(cache.v_scale, cache.page_table, v_sc,
-                                    start, suffix_len)
-    elif prefix_len is None:
-        newk = _scatter_pages(cache.k_pages, cache.page_table, k)
-        newv = _scatter_pages(cache.v_pages, cache.page_table, v)
-        new_ks, new_vs = cache.k_scale, cache.v_scale
-    else:
-        newk = _scatter_pages_at(cache.k_pages, cache.page_table, k,
-                                 start, suffix_len)
-        newv = _scatter_pages_at(cache.v_pages, cache.page_table, v,
-                                 start, suffix_len)
-        new_ks, new_vs = cache.k_scale, cache.v_scale
+    with jax.named_scope("kv_cache"):
+        if cache.quantized:
+            k_codes, k_sc = quantize_kv_rows(k)
+            v_codes, v_sc = quantize_kv_rows(v)
+            newk = _scatter_pages_at(cache.k_pages, cache.page_table,
+                                     k_codes, start, suffix_len)
+            newv = _scatter_pages_at(cache.v_pages, cache.page_table,
+                                     v_codes, start, suffix_len)
+            new_ks = _scatter_scales_at(cache.k_scale, cache.page_table,
+                                        k_sc, start, suffix_len)
+            new_vs = _scatter_scales_at(cache.v_scale, cache.page_table,
+                                        v_sc, start, suffix_len)
+        elif prefix_len is None:
+            newk = _scatter_pages(cache.k_pages, cache.page_table, k)
+            newv = _scatter_pages(cache.v_pages, cache.page_table, v)
+            new_ks, new_vs = cache.k_scale, cache.v_scale
+        else:
+            newk = _scatter_pages_at(cache.k_pages, cache.page_table, k,
+                                     start, suffix_len)
+            newv = _scatter_pages_at(cache.v_pages, cache.page_table, v,
+                                     start, suffix_len)
+            new_ks, new_vs = cache.k_scale, cache.v_scale
     new_cache = PagedKVCache(k_pages=newk, v_pages=newv,
                              page_table=cache.page_table, length=new_len,
                              k_scale=new_ks, v_scale=new_vs)

@@ -267,13 +267,15 @@ class LM:
         return x
 
     def _head(self, p: Params, x: jnp.ndarray) -> jnp.ndarray:
+        """Final norm and LM head, under the ``head`` scope."""
         norm = rms_norm if self.cfg.norm == "rmsnorm" else layer_norm
-        x = norm(x, p["final_norm"], self.cfg.norm_eps)
-        w = (p["embed"]["table"].T if self.cfg.tie_embeddings
-             else p["lm_head"]["w"])
-        logits = jnp.einsum("bsd,dv->bsv", x, w.astype(self.dtype))
-        return constrain(logits, ("batch", "act_seq", "vocab"),
-                         self.rules, self.mesh)
+        with jax.named_scope("head"):
+            x = norm(x, p["final_norm"], self.cfg.norm_eps)
+            w = (p["embed"]["table"].T if self.cfg.tie_embeddings
+                 else p["lm_head"]["w"])
+            logits = jnp.einsum("bsd,dv->bsv", x, w.astype(self.dtype))
+            return constrain(logits, ("batch", "act_seq", "vocab"),
+                             self.rules, self.mesh)
 
     def _vlm_positions3(self, tokens: jnp.ndarray) -> jnp.ndarray:
         """M-RoPE position streams [3,B,S]: patches get (0,h,w) grid

@@ -117,23 +117,27 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: BlockConfig, *,
 def _block_mlp(p: Params, h: jnp.ndarray, cfg: BlockConfig,
                rules, mesh) -> jnp.ndarray:
     """The post-attention MLP half of a block (aux loss dropped — the
-    decode/prefill paths never train)."""
-    if cfg.mlp == "moe":
-        cst = (lambda a, axes: constrain(a, axes, rules, mesh, soft=True))
-        m, _ = moe_mlp(p["moe"], _norm(h, p["ln2"], cfg), cfg.moe,
-                       constrain_fn=cst)
-        return m
-    mp = p["mlp"]
-    return swiglu(_norm(h, p["ln2"], cfg), mp["w_gate"].astype(h.dtype),
-                  mp["w_up"].astype(h.dtype), mp["w_down"].astype(h.dtype))
+    decode/prefill paths never train), under the ``mlp`` scope."""
+    with jax.named_scope("mlp"):
+        if cfg.mlp == "moe":
+            cst = (lambda a, axes: constrain(a, axes, rules, mesh,
+                                             soft=True))
+            m, _ = moe_mlp(p["moe"], _norm(h, p["ln2"], cfg), cfg.moe,
+                           constrain_fn=cst)
+            return m
+        mp = p["mlp"]
+        return swiglu(_norm(h, p["ln2"], cfg), mp["w_gate"].astype(h.dtype),
+                      mp["w_up"].astype(h.dtype),
+                      mp["w_down"].astype(h.dtype))
 
 
 def apply_block_decode(p: Params, x: jnp.ndarray, cfg: BlockConfig,
                        cache: KVCache, *, rules=DEFAULT_RULES, mesh=None,
                        positions3=None) -> Tuple[jnp.ndarray, KVCache]:
-    a, new_cache = attn_mod.decode_attention(
-        p["attn"], _norm(x, p["ln1"], cfg), cfg.attn, cache,
-        positions3=positions3)
+    with jax.named_scope("attention"):
+        a, new_cache = attn_mod.decode_attention(
+            p["attn"], _norm(x, p["ln1"], cfg), cfg.attn, cache,
+            positions3=positions3)
     h = x + a
     return h + _block_mlp(p, h, cfg, rules, mesh), new_cache
 
@@ -151,14 +155,16 @@ def apply_block_prefill(p: Params, x: jnp.ndarray, cfg: BlockConfig,
     if prefix_len is not None and not paged:
         raise ValueError("prefix_len requires a paged KV cache "
                          "(dense prefill has no resident prefix)")
-    if paged:
-        a, new_cache = attn_mod.prefill_into_paged_cache(
-            p["attn"], _norm(x, p["ln1"], cfg), cfg.attn, cache,
-            positions3=positions3, lengths=lengths, prefix_len=prefix_len)
-    else:
-        a, new_cache = attn_mod.prefill_into_cache(
-            p["attn"], _norm(x, p["ln1"], cfg), cfg.attn, cache,
-            positions3=positions3, lengths=lengths)
+    with jax.named_scope("attention"):
+        if paged:
+            a, new_cache = attn_mod.prefill_into_paged_cache(
+                p["attn"], _norm(x, p["ln1"], cfg), cfg.attn, cache,
+                positions3=positions3, lengths=lengths,
+                prefix_len=prefix_len)
+        else:
+            a, new_cache = attn_mod.prefill_into_cache(
+                p["attn"], _norm(x, p["ln1"], cfg), cfg.attn, cache,
+                positions3=positions3, lengths=lengths)
     h = x + a
     return h + _block_mlp(p, h, cfg, rules, mesh), new_cache
 
@@ -240,7 +246,19 @@ def apply_stack_decode(stacked: Params, x: jnp.ndarray, cfg: BlockConfig,
     A paged cache (:class:`~repro.models.attention.PagedKVCache`) takes its
     own path: per-layer paged decode attention over the page table, plus a
     single-page token write — bytes/token O(length), not O(max_seq).
+
+    The stack runs under the ``layers`` scope: the scan's own slicing and
+    re-stacking of the per-layer caches carries it, and the blocks' parts
+    their ``attention`` / ``mlp`` / ``kv_cache`` scopes inside it.
     """
+    with jax.named_scope("layers"):
+        return _apply_stack_decode(stacked, x, cfg, caches, features,
+                                   rules=rules, mesh=mesh,
+                                   positions3=positions3, block_fn=block_fn)
+
+
+def _apply_stack_decode(stacked, x, cfg, caches, features, *, rules, mesh,
+                        positions3, block_fn):
     if isinstance(caches, attn_mod.PagedKVCache) \
             and block_fn is apply_block_decode:
         return _apply_stack_decode_paged(stacked, x, cfg, caches, features,
@@ -257,16 +275,21 @@ def apply_stack_decode(stacked: Params, x: jnp.ndarray, cfg: BlockConfig,
         def body(carry, scanned):
             h, kst, vst = carry
             i, layer_p = scanned
-            k_l = jax.lax.dynamic_index_in_dim(kst, i, 0, keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(vst, i, 0, keepdims=False)
-            a, k_t, v_t = attn_mod.decode_attention_token(
-                layer_p["attn"], _norm(h, layer_p["ln1"], cfg), cfg.attn,
-                k_l, v_l, length, positions3=positions3)
+            with jax.named_scope("kv_cache"):
+                k_l = jax.lax.dynamic_index_in_dim(kst, i, 0, keepdims=False)
+                v_l = jax.lax.dynamic_index_in_dim(vst, i, 0, keepdims=False)
+            with jax.named_scope("attention"):
+                a, k_t, v_t = attn_mod.decode_attention_token(
+                    layer_p["attn"], _norm(h, layer_p["ln1"], cfg),
+                    cfg.attn, k_l, v_l, length, positions3=positions3)
             h2 = h + a
             y = h2 + _block_mlp(layer_p, h2, cfg, rules, mesh)
             # per-row scatter: row b's token lands at its own length[b]
-            kst = kst.at[i, rows, length].set(k_t[:, 0].astype(kst.dtype))
-            vst = vst.at[i, rows, length].set(v_t[:, 0].astype(vst.dtype))
+            with jax.named_scope("kv_cache"):
+                kst = kst.at[i, rows, length].set(
+                    k_t[:, 0].astype(kst.dtype))
+                vst = vst.at[i, rows, length].set(
+                    v_t[:, 0].astype(vst.dtype))
             return (y, kst, vst), None
 
         (y, kst, vst), _ = jax.lax.scan(
@@ -309,7 +332,8 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
     layer 0's are read once).  The token write touches ONE page per layer:
     row b's token lands in physical page ``pt[b, length[b] // ps]`` at
     offset ``length[b] % ps`` — the pool guarantees that page is
-    allocated before the segment runs.
+    allocated before the segment runs.  The layer's pool slices and the
+    token write run under the ``kv_cache`` scope.
     """
     b = x.shape[0]
     length = attn_mod._row_lengths(
@@ -319,18 +343,28 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
     ps = caches.k_pages.shape[-3]
     np_w = pt.shape[-1]
     rows = jnp.arange(b)
-    page = pt[rows, jnp.minimum(length // ps, np_w - 1)]
-    off = length % ps
+    with jax.named_scope("kv_cache"):
+        page = pt[rows, jnp.minimum(length // ps, np_w - 1)]
+        off = length % ps
     n = jax.tree.leaves(stacked)[0].shape[0]
     quantized = caches.quantized
 
     def attend(h, layer_p, k_l, v_l, ksc_l=None, vsc_l=None):
-        a, k_t, v_t = attn_mod.paged_decode_attention_token(
-            layer_p["attn"], _norm(h, layer_p["ln1"], cfg), cfg.attn,
-            k_l, v_l, pt, length, positions3=positions3,
-            k_scale=ksc_l, v_scale=vsc_l)
+        with jax.named_scope("attention"):
+            a, k_t, v_t = attn_mod.paged_decode_attention_token(
+                layer_p["attn"], _norm(h, layer_p["ln1"], cfg), cfg.attn,
+                k_l, v_l, pt, length, positions3=positions3,
+                k_scale=ksc_l, v_scale=vsc_l)
         h2 = h + a
         return h2 + _block_mlp(layer_p, h2, cfg, rules, mesh), k_t, v_t
+
+    def layer_slice(pool, i):
+        with jax.named_scope("kv_cache"):
+            return jax.lax.dynamic_index_in_dim(pool, i, 0, keepdims=False)
+
+    def write(pool, i, rows_kv):
+        with jax.named_scope("kv_cache"):
+            return pool.at[i, page, off].set(rows_kv.astype(pool.dtype))
 
     if quantized:
         # int8 cache: attend with the layer's scales, then quantize the
@@ -338,17 +372,14 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
         def body(carry, scanned):
             h, kst, vst, ksc, vsc = carry
             i, layer_p = scanned
-            k_l = jax.lax.dynamic_index_in_dim(kst, i, 0, keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(vst, i, 0, keepdims=False)
-            ksc_l = jax.lax.dynamic_index_in_dim(ksc, i, 0, keepdims=False)
-            vsc_l = jax.lax.dynamic_index_in_dim(vsc, i, 0, keepdims=False)
-            y, k_t, v_t = attend(h, layer_p, k_l, v_l, ksc_l, vsc_l)
-            k_c, k_s = attn_mod.quantize_kv_rows(k_t[:, 0])
-            v_c, v_s = attn_mod.quantize_kv_rows(v_t[:, 0])
-            kst = kst.at[i, page, off].set(k_c.astype(kst.dtype))
-            vst = vst.at[i, page, off].set(v_c.astype(vst.dtype))
-            ksc = ksc.at[i, page, off].set(k_s.astype(ksc.dtype))
-            vsc = vsc.at[i, page, off].set(v_s.astype(vsc.dtype))
+            y, k_t, v_t = attend(h, layer_p, layer_slice(kst, i),
+                                 layer_slice(vst, i), layer_slice(ksc, i),
+                                 layer_slice(vsc, i))
+            with jax.named_scope("kv_cache"):
+                k_c, k_s = attn_mod.quantize_kv_rows(k_t[:, 0])
+                v_c, v_s = attn_mod.quantize_kv_rows(v_t[:, 0])
+            kst, vst = write(kst, i, k_c), write(vst, i, v_c)
+            ksc, vsc = write(ksc, i, k_s), write(vsc, i, v_s)
             return (y, kst, vst, ksc, vsc), None
 
         carry0 = (x, caches.k_pages, caches.v_pages,
@@ -357,11 +388,10 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
         def body(carry, scanned):
             h, kst, vst = carry
             i, layer_p = scanned
-            k_l = jax.lax.dynamic_index_in_dim(kst, i, 0, keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(vst, i, 0, keepdims=False)
-            y, k_t, v_t = attend(h, layer_p, k_l, v_l)
-            kst = kst.at[i, page, off].set(k_t[:, 0].astype(kst.dtype))
-            vst = vst.at[i, page, off].set(v_t[:, 0].astype(vst.dtype))
+            y, k_t, v_t = attend(h, layer_p, layer_slice(kst, i),
+                                 layer_slice(vst, i))
+            kst = write(kst, i, k_t[:, 0])
+            vst = write(vst, i, v_t[:, 0])
             return (y, kst, vst), None
 
         carry0 = (x, caches.k_pages, caches.v_pages)

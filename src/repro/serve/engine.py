@@ -29,10 +29,18 @@ prove token-identical output through the kernel).
 Every device->host transfer goes through :meth:`Engine._fetch`, so
 ``engine.host_syncs`` is an auditable counter — tests assert the O(1)
 bound and ``benchmarks/bench_serve.py`` reports it next to tokens/s.
-Instrumentation is LIKWID-style (``Engine.instrument``): event counts for
-the ``serve.decode`` / ``serve.prefill`` regions come from the compiled
-artifact (wrapper mode, zero overhead), wall-clock accumulates into the
-same regions via ``PerfCtr.region_timer``.
+Measurement: every boundary of the scheduler and every engine entry
+point opens one host span (:meth:`Engine.span`, names in
+:data:`SERVE_SPANS`) on the profiler's clock, and the decode and prefill
+programs name their parts with ``jax.named_scope`` (``layers``,
+``attention``, ``kv_cache``, ``mlp``, ``head``).  The first dispatch of
+a program shape the engine has not run before runs under ``serve.build``
+and counts in ``Engine.programs_built`` (``programs_built`` in a
+scheduler's metrics).  ``Engine.instrument`` is LIKWID-style on top:
+event counts for the ``serve.decode`` / ``serve.prefill`` regions come
+from the compiled artifact (wrapper mode, zero overhead), and wall time
+accumulates into ``serve.decode`` around each stretch that ends in the
+host sync.
 
 ``generate()`` is fully deterministic given (seed, prompts).  In the
 scheduler, greedy decoding (temperature 0, the default) is replayable
@@ -54,13 +62,14 @@ from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.models.lm import LM
 
 __all__ = ["ServeConfig", "Engine", "BatchScheduler", "Request",
-           "MASKED_FAMILIES"]
+           "MASKED_FAMILIES", "SERVE_SPANS"]
 
 # families whose decode state is an attention cache: pad keys can be masked
 # per row, so ragged prompts batch exactly.  Recurrent-state families
@@ -71,6 +80,17 @@ MASKED_FAMILIES = ("dense", "moe", "vlm")
 
 PREFILL_REGION = "serve.prefill"
 DECODE_REGION = "serve.decode"
+
+#: the host spans the serving program writes into the profiler's trace
+#: (``Engine.span``): one ``run()``; one admission, its pool bookkeeping,
+#: copy-on-write copy and slot prefill; the page-table upload; a decode
+#: segment's dispatch, its one sync and the retire that follows; the
+#: per-segment hook; the first dispatch of an unseen program shape; one
+#: static-batch ``generate()``
+SERVE_SPANS = ("serve.run", "serve.admit", "serve.pool", "serve.cow_copy",
+               "serve.prefill", "serve.page_table", "serve.segment",
+               "serve.fetch", "serve.retire", "serve.hook", "serve.build",
+               "serve.generate")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -218,6 +238,10 @@ class Engine:
         self.perfctr = perfctr          # optional repro.core.perfctr.PerfCtr
         self.host_syncs = 0             # device->host transfers (audited)
         self.fused_calls = 0            # fused-loop dispatches
+        # (program, shape key) of every slot prefill, decode segment and
+        # COW copy dispatched so far; a new key is a program build
+        self._built: set = set()
+        self.programs_built = 0
         self.paged = cfg.page_size > 0
         if self.paged and lm.cfg.family not in MASKED_FAMILIES:
             raise ValueError(
@@ -429,6 +453,7 @@ class Engine:
         self.params = self._shard_params(self.params)
         self._fused.clear()
         self._segments.clear()
+        self._built.clear()
         self._prefill = jax.jit(self.lm.prefill)
         self._decode = jax.jit(self.lm.decode_step)
         self._slot_prefill = jax.jit(self._slot_prefill_impl)
@@ -446,18 +471,41 @@ class Engine:
         """Swap the (host-managed) page table into a decode state."""
         caches = state["caches"]
         n_layers = caches.length.shape[0]
-        tbl = jnp.broadcast_to(jnp.asarray(table, jnp.int32)[None],
-                               (n_layers,) + tuple(table.shape))
-        return dict(state, caches=caches._replace(page_table=tbl))
+        with self.span("serve.page_table", width=table.shape[-1]):
+            tbl = jnp.broadcast_to(jnp.asarray(table, jnp.int32)[None],
+                                   (n_layers,) + tuple(table.shape))
+            return dict(state, caches=caches._replace(page_table=tbl))
 
     def _fetch(self, tree):
         """THE device->host sync point: every transfer is counted here."""
         self.host_syncs += 1
         return jax.device_get(tree)
 
-    def _region_timer(self, region: str):
-        return (self.perfctr.region_timer(region) if self.perfctr is not None
-                else contextlib.nullcontext())
+    def span(self, name: str, region: Optional[str] = None, **args):
+        """A host span ``name`` (one of :data:`SERVE_SPANS`) on the
+        profiler's clock.  ``args`` are recorded only while the profiler
+        is on.  With a PerfCtr attached (:meth:`instrument`), the span's
+        wall time also accumulates into the marker ``region``."""
+        ann = (TraceAnnotation(name, **args)
+               if args and TraceAnnotation.is_enabled()
+               else TraceAnnotation(name))
+        if region is None or self.perfctr is None:
+            return ann
+        stack = contextlib.ExitStack()
+        stack.enter_context(ann)
+        stack.enter_context(self.perfctr.region_timer(region))
+        return stack
+
+    def _build(self, program: str, key: Tuple):
+        """Wrap one dispatch of ``program`` at shape ``key``: the first at
+        an unseen key (a trace and a compile, or a load from the
+        persistent cache) runs under ``serve.build`` and counts in
+        ``programs_built``."""
+        if (program, key) in self._built:
+            return contextlib.nullcontext()
+        self._built.add((program, key))
+        self.programs_built += 1
+        return self.span("serve.build", program=program, key=str(key))
 
     def _impl_ctx(self):
         """Kernel-registry override while tracing/running engine programs.
@@ -503,9 +551,10 @@ class Engine:
         ``argmax`` / ``jax.random.categorical(rng, logits / T)``."""
         from repro.kernels import sampling
         cfg = self.cfg
-        return sampling.sample(logits, rng, method=self.sampling_method,
-                               temperature=max(cfg.temperature, 1e-6),
-                               k=cfg.top_k, p=cfg.top_p)
+        with jax.named_scope("head"):
+            return sampling.sample(logits, rng, method=self.sampling_method,
+                                   temperature=max(cfg.temperature, 1e-6),
+                                   k=cfg.top_k, p=cfg.top_p)
 
     def _pad_prompts(self, prompts: Sequence[Sequence[int]]
                      ) -> Tuple[np.ndarray, np.ndarray]:
@@ -641,7 +690,7 @@ class Engine:
             fused = self._fused[key] = \
                 self._make_fused(max_new_tokens, paged_dims)
         self.fused_calls += 1
-        with self._region_timer(DECODE_REGION), self._impl_ctx():
+        with self._impl_ctx(), self.span("serve.generate", DECODE_REGION):
             out, n = fused(self.params, jnp.asarray(toks), jnp.asarray(lens),
                            jax.random.key(cfg.seed), extra, *args)
             out_np, n_np = self._fetch((out, n))    # the ONE sync
@@ -780,7 +829,8 @@ class Engine:
             return state
         n = 1 << (len(pairs) - 1).bit_length()
         arr = np.asarray(list(pairs) + [(0, 0)] * (n - len(pairs)), np.int32)
-        with self._region_timer(PREFILL_REGION):
+        with self.span("serve.cow_copy", pairs=len(pairs)), \
+                self._build("cow_copy", (n,)):
             return self._copy_pages(state, jnp.asarray(arr[:, 0]),
                                     jnp.asarray(arr[:, 1]))
 
@@ -795,11 +845,12 @@ class Engine:
         keep the row-twin prefill + donated scatter-merge.
         """
         toks = jnp.asarray([list(prompt)], jnp.int32)
+        key = (len(prompt), prefix_len > 0)
         if self.paged:
             assert table_row is not None, "paged admission needs a table row"
             pl = (jnp.asarray(prefix_len, jnp.int32) if prefix_len > 0
                   else None)
-            with self._region_timer(PREFILL_REGION), self._impl_ctx():
+            with self._build("prefill", key), self._impl_ctx():
                 return self._paged_slot_prefill(
                     self.params, state, logits_buf, toks,
                     jnp.asarray(slot, jnp.int32),
@@ -807,7 +858,7 @@ class Engine:
         if prefix_len:
             raise ValueError("prefix_len needs a paged engine "
                              "(dense caches hold no shared prefix)")
-        with self._region_timer(PREFILL_REGION), self._impl_ctx():
+        with self._build("prefill", key), self._impl_ctx():
             row_logits, row_state = self._slot_prefill(self.params, toks)
         return self._merge(state, logits_buf, row_state, row_logits,
                            jnp.asarray(slot, jnp.int32))
@@ -821,10 +872,12 @@ class Engine:
         masks any overshoot against per-request budgets.  (On a paged
         engine each entry point additionally retraces per page-table
         WIDTH it is fed — the scheduler's live-mix buckets, x4-page
-        quantized, bound that churn.)  ``lax.scan`` over the fused
-        sample->decode body; decode state and the logits buffer are
-        DONATED, so segment-to-segment the cache buffers alias instead of
-        reallocating.  Returns (tokens [B,steps], logits, state, rng).
+        quantized, bound that churn; each (steps, width) is one build.)
+        ``lax.scan`` over the fused sample->decode body; decode state and
+        the logits buffer are DONATED, so segment-to-segment the cache
+        buffers alias instead of reallocating.  Returns (tokens
+        [B,steps], logits, state, rng).  The returned callable's
+        ``lower`` is the jitted program's.
         """
         steps = self.quantize_steps(steps)
         fn = self._segments.get(steps)
@@ -845,7 +898,16 @@ class Engine:
                         body, (logits, state, rng), None, length=steps)
                 return toks.T, logits, state, rng
 
-            fn = self._segments[steps] = jax.jit(seg, donate_argnums=(1, 2))
+            prog = jax.jit(seg, donate_argnums=(1, 2))
+
+            def fn(params, state, logits, rng):
+                width = (state["caches"].page_table.shape[-1] if self.paged
+                         else 0)
+                with self._build("segment", (steps, width)):
+                    return prog(params, state, logits, rng)
+
+            fn.lower = prog.lower
+            self._segments[steps] = fn
         return fn
 
     # ------------------------------------------- speculative decoding (jit)
@@ -904,7 +966,7 @@ class Engine:
                            table_row):
         """Admission hook: land ``prompt``'s draft KV in its pool pages."""
         toks = jnp.asarray([list(prompt)], jnp.int32)
-        with self._region_timer(PREFILL_REGION), self._impl_ctx():
+        with self._impl_ctx():
             return self._draft_slot_prefill(
                 self.draft_params, dstate, toks,
                 jnp.asarray(slot, jnp.int32),
@@ -1095,7 +1157,8 @@ class Engine:
                 fused = self._fused[key] = self._make_spec_fused(
                     max_new_tokens, pd, dd)
             self.fused_calls += 1
-            with self._region_timer(DECODE_REGION), self._impl_ctx():
+            with self._impl_ctx(), self.span("serve.generate",
+                                             DECODE_REGION):
                 out, n, prop, accn = fused(
                     self.params, self.draft_params, jnp.asarray(toks),
                     jnp.asarray(lens), rng, extra, jnp.asarray(table),
@@ -1134,7 +1197,7 @@ class Engine:
                 return logits, state, dstate
 
             prefill = self._fused[pkey] = jax.jit(_prefill)
-        with self._region_timer(PREFILL_REGION), self._impl_ctx():
+        with self._impl_ctx(), self.span("serve.prefill"):
             logits, state, dstate = prefill(
                 self.params, self.draft_params, jnp.asarray(toks),
                 jnp.asarray(lens), extra, jnp.asarray(table),
@@ -1144,7 +1207,7 @@ class Engine:
         outs: List[List[int]] = [[] for _ in range(b)]
         done = np.zeros(b, bool)
         proposed = accepted = 0
-        with self._region_timer(DECODE_REGION), self._impl_ctx():
+        with self._impl_ctx(), self.span("serve.generate", DECODE_REGION):
             for _round in range(max_new_tokens):
                 if done.all():
                     break
@@ -1187,12 +1250,12 @@ class Engine:
         batch = dict(extra, tokens=jnp.asarray(toks))
         if self.lm.cfg.family in MASKED_FAMILIES:
             batch["lengths"] = jnp.asarray(lens)
-        with self._region_timer(PREFILL_REGION), self._impl_ctx():
+        with self._impl_ctx(), self.span("serve.prefill"):
             logits, state = self._prefill(self.params, batch, state)
         rng = jax.random.key(cfg.seed)
         out: List[List[int]] = [list() for _ in range(b)]
         done = np.zeros(b, bool)
-        with self._region_timer(DECODE_REGION), self._impl_ctx():
+        with self._impl_ctx(), self.span("serve.generate", DECODE_REGION):
             for _t in range(max_new_tokens):
                 rng, sub = jax.random.split(rng)
                 nxt = self._sample(logits, sub)
@@ -1219,8 +1282,11 @@ class Engine:
         Event counts for ``serve.prefill`` / ``serve.decode`` are read from
         the compiled artifacts against abstract inputs — the measured
         programs are never executed (the paper's zero-overhead claim by
-        construction).  Wall-clock then accumulates into the same regions
-        on every ``generate()`` / scheduler segment via ``region_timer``.
+        construction).  Wall time then accumulates into ``serve.decode``
+        through :meth:`span` around each stretch that ends in the host
+        sync: a ``generate()`` call, and a scheduler segment's
+        ``serve.fetch`` (dispatches alone are enqueues, so prefills and
+        copies add no wall time of their own).
         """
         self.perfctr = perfctr
         cfg = self.cfg
@@ -1329,6 +1395,9 @@ class BatchScheduler:
             # request-plane robustness telemetry
             "expired": 0, "cancelled": 0, "sheds": 0, "rejections": 0,
             "bypasses": 0, "snapshots": 0, "restores": 0,
+            # programs the engine built while this scheduler ran (a
+            # dispatch at a shape key it had not run before)
+            "programs_built": 0,
         }
         if engine.spec is not None:
             # speculative decoding telemetry (accept_rate =
@@ -1843,11 +1912,144 @@ class BatchScheduler:
             self.queue.push_front(req)
         return len(live)
 
+    def _admit(self, i: int, req: Request, state, logits, dstate,
+               width_restored: bool):
+        """Admit ``req`` into free slot ``i`` under the ``serve.admit``
+        span: pool pages, the copy-on-write fork, the slot prefill of its
+        context (prompt + progress).  Returns (state, logits, draft state,
+        width_restored)."""
+        eng = self.engine
+        nslots = eng.cfg.batch_slots
+        full = list(req.prompt) + list(req.generated)
+        budget = req.max_new_tokens - len(req.generated)
+        with eng.span("serve.admit", rid=req.rid,
+                      prompt=len(full)) as admit_span:
+            table_row = None
+            prefix_len = 0
+            cow_pairs: List[Tuple[int, int]] = []
+            if self.pool is not None:
+                with eng.span("serve.pool"):
+                    # admission allocates exactly ceil(len/page) pages for the
+                    # context (minus full-page prefix hits, which map read-only
+                    # by refcount bump) and RESERVES the request's worst case
+                    # (budget + segment overshoot), so decode growth can never
+                    # exhaust the pool mid-run.  (_pick_admission already
+                    # proved can_reserve for this request.)
+                    worst = len(full) + budget + eng.slot_headroom
+                    admit = self.pool.admit_prefix(i, full)
+                    prefix_len = admit.matched_len
+                    if admit.cow is not None:
+                        cow_pairs.append(admit.cow)
+                    self.pool.reserve(i, worst)
+                    self.pool.alloc(i, len(full))
+                    table_row = self.pool.tables[i]
+                    if eng.spec is not None:
+                        # the draft twin: full context, no sharing
+                        self.pool.reserve(nslots + i, worst)
+                        self.pool.alloc(nslots + i, len(full))
+                    tbl = None if width_restored else self.pool.table()
+                # admission programs key on the FULL table width (prefill only
+                # scatter-writes through the table, and writes its own slot's
+                # row on device; one width-restoring upload per round suffices
+                # — the next segment re-slices to the live mix)
+                if not width_restored:
+                    state = eng.set_page_table(state, tbl[:nslots])
+                    if eng.spec is not None:
+                        dstate = eng.set_page_table(dstate, tbl[nslots:])
+                    width_restored = True
+                # the fork page must hold the shared tokens before the suffix
+                # prefill reads (and partially rewrites) it — the copy is
+                # issued first, device-ordered
+                state = eng.copy_pages(state, cow_pairs)
+                self.metrics["prefix_hits"] += int(prefix_len > 0)
+                self.metrics["pages_shared"] += admit.shared_full
+                self.metrics["cow_copies"] += len(cow_pairs)
+            admit_span.set_metadata(prefix=prefix_len)
+            self.queue.remove(req)
+            # resume path (restore / max_segments re-queue): ``full`` replays
+            # prompt + progress through prefill — resident prefix pages are
+            # attended, not recomputed — and the row decodes only its
+            # remaining budget
+            with eng.span("serve.prefill", rid=req.rid,
+                          tokens=len(full) - prefix_len):
+                state, logits = eng.prefill_slot(
+                    state, logits, full[prefix_len:], i,
+                    table_row=table_row, prefix_len=prefix_len)
+                if eng.spec is not None:
+                    dstate = eng.draft_prefill_slot(
+                        dstate, full, i, self.pool.tables[nslots + i])
+            if self.pool is not None:
+                # index the now-resident context pages so the NEXT admission
+                # can share them
+                with eng.span("serve.pool"):
+                    self.pool.register_prefix(i, full)
+            req.status = "active"
+            self._slots[i] = req
+            self._remaining[i] = budget
+            self._slot_len[i] = len(full)
+            self.metrics["admissions"] += 1
+            self.metrics["prompt_tokens"] += len(full)
+            self.metrics["prefilled_tokens"] += len(full) - prefix_len
+            self.admission_log.append((req.rid, i))
+            return state, logits, dstate, width_restored
+
+    def _retire(self, active: np.ndarray, toks_np: np.ndarray,
+                produced: np.ndarray, now: float) -> int:
+        """Hand each active row this segment's tokens; finished, expired
+        and cancelled rows release their slots immediately.  Returns the
+        number of rows released."""
+        eos = self.engine.cfg.eos_token
+        released = 0
+        for i in np.nonzero(active)[0]:
+            req = self._slots[i]
+            reason = ("cancel" if req.cancel_requested
+                      else self._expiry_reason(req, now))
+            if reason:
+                # the in-progress segment's tokens are DISCARDED: nothing
+                # generated after the flag/deadline was observed is ever
+                # returned
+                self._release_slot(int(i))
+                self._finish_abnormal(req, reason)
+                released += 1
+                continue
+            if not req.generated and not req.first_token_time:
+                req.first_token_time = now
+            # mask overshoot: at most this segment's real tokens (spec
+            # rows: the accepted count), never past budget
+            take = toks_np[i][:min(produced[i], self._remaining[i])]
+            finished = False
+            if eos >= 0:
+                hits = np.nonzero(take == eos)[0]
+                if hits.size:
+                    take = take[:hits[0] + 1]
+                    finished = True
+            req.generated.extend(int(t) for t in take)
+            self._remaining[i] = req.max_new_tokens - len(req.generated)
+            if finished or self._remaining[i] <= 0:
+                req.finished = True
+                req.status = "done"
+                self.completed[req.rid] = req
+                self._release_slot(int(i))
+                self.queue.note_service_time(now - req.submit_time)
+                released += 1
+        return released
+
     def run(self, max_segments: Optional[int] = None) -> Dict[int, Request]:
         """Drive the queue to completion (or for ``max_segments`` decode
         segments — in-flight requests then re-queue with their progress
         kept, and with ``snapshot_dir`` set an exit snapshot is written:
-        the controlled half of the kill-and-restore story)."""
+        the controlled half of the kill-and-restore story).  Runs under
+        the ``serve.run`` span; the engine's program builds meanwhile add
+        to ``metrics["programs_built"]``."""
+        eng = self.engine
+        built = eng.programs_built
+        try:
+            with eng.span("serve.run"):
+                return self._run(max_segments)
+        finally:
+            self.metrics["programs_built"] += eng.programs_built - built
+
+    def _run(self, max_segments: Optional[int]) -> Dict[int, Request]:
         eng, cfg = self.engine, self.engine.cfg
         if not self.queue:
             return self.completed
@@ -1891,79 +2093,12 @@ class BatchScheduler:
                 for i in range(nslots):
                     if slots[i] is not None:
                         continue
-                    req = self._pick_admission()
+                    with eng.span("serve.pool"):
+                        req = self._pick_admission()
                     if req is None:
                         break
-                    full = list(req.prompt) + list(req.generated)
-                    budget = req.max_new_tokens - len(req.generated)
-                    table_row = None
-                    prefix_len = 0
-                    cow_pairs: List[Tuple[int, int]] = []
-                    if self.pool is not None:
-                        # admission allocates exactly ceil(len/page) pages
-                        # for the context (minus full-page prefix hits,
-                        # which map read-only by refcount bump) and
-                        # RESERVES the request's worst case (budget +
-                        # segment overshoot), so decode growth can never
-                        # exhaust the pool mid-run.  (_pick_admission
-                        # already proved can_reserve for this request.)
-                        worst = len(full) + budget + eng.slot_headroom
-                        admit = self.pool.admit_prefix(i, full)
-                        prefix_len = admit.matched_len
-                        if admit.cow is not None:
-                            cow_pairs.append(admit.cow)
-                        self.pool.reserve(i, worst)
-                        self.pool.alloc(i, len(full))
-                        table_row = self.pool.tables[i]
-                        if eng.spec is not None:
-                            # the draft twin: full context, no sharing
-                            self.pool.reserve(nslots + i, worst)
-                            self.pool.alloc(nslots + i, len(full))
-                        # admission programs key on the FULL table width
-                        # (prefill only scatter-writes through the table,
-                        # and writes its own slot's row on device; one
-                        # width-restoring upload per round suffices — the
-                        # next segment re-slices to the live mix)
-                        if not width_restored:
-                            tbl = self.pool.table()
-                            state = eng.set_page_table(state,
-                                                       tbl[:nslots])
-                            if eng.spec is not None:
-                                dstate = eng.set_page_table(dstate,
-                                                            tbl[nslots:])
-                            width_restored = True
-                        # the fork page must hold the shared tokens before
-                        # the suffix prefill reads (and partially rewrites)
-                        # it — the copy is issued first, device-ordered
-                        state = eng.copy_pages(state, cow_pairs)
-                        self.metrics["prefix_hits"] += int(prefix_len > 0)
-                        self.metrics["pages_shared"] += admit.shared_full
-                        self.metrics["cow_copies"] += len(cow_pairs)
-                    self.queue.remove(req)
-                    # resume path (restore / max_segments re-queue):
-                    # ``full`` replays prompt + progress through prefill —
-                    # resident prefix pages are attended, not recomputed —
-                    # and the row decodes only its remaining budget
-                    state, logits = eng.prefill_slot(
-                        state, logits, full[prefix_len:], i,
-                        table_row=table_row, prefix_len=prefix_len)
-                    if eng.spec is not None:
-                        dstate = eng.draft_prefill_slot(
-                            dstate, full, i,
-                            self.pool.tables[nslots + i])
-                    if self.pool is not None:
-                        # index the now-resident context pages so the
-                        # NEXT admission can share them
-                        self.pool.register_prefix(i, full)
-                    req.status = "active"
-                    slots[i] = req
-                    remaining[i] = budget
-                    slot_len[i] = len(full)
-                    self.metrics["admissions"] += 1
-                    self.metrics["prompt_tokens"] += len(full)
-                    self.metrics["prefilled_tokens"] += (len(full)
-                                                         - prefix_len)
-                    self.admission_log.append((req.rid, i))
+                    state, logits, dstate, width_restored = self._admit(
+                        i, req, state, logits, dstate, width_restored)
 
                 active = np.array([s is not None for s in slots])
                 if not active.any():
@@ -1991,28 +2126,34 @@ class BatchScheduler:
                     # length can grow by up to K+1 (exactly `counts[i]`,
                     # fetched below); cover BOTH namespaces first
                     grow = eng.spec.num_draft_tokens + 1
-                    for i in np.nonzero(active)[0]:
-                        self.pool.ensure(int(i), int(slot_len[i]) + grow)
-                        self.pool.ensure(nslots + int(i),
-                                         int(slot_len[i]) + grow)
-                    width = max(max(self.pool.slot_pages(int(i)),
-                                    self.pool.slot_pages(nslots + int(i)))
-                                for i in np.nonzero(active)[0])
-                    bucket = min(-(-max(width, 1) // 4) * 4,
-                                 eng.table_width)
-                    tbl = self.pool.table()
+                    with eng.span("serve.pool"):
+                        for i in np.nonzero(active)[0]:
+                            self.pool.ensure(int(i),
+                                             int(slot_len[i]) + grow)
+                            self.pool.ensure(nslots + int(i),
+                                             int(slot_len[i]) + grow)
+                        width = max(max(self.pool.slot_pages(int(i)),
+                                        self.pool.slot_pages(nslots
+                                                             + int(i)))
+                                    for i in np.nonzero(active)[0])
+                        bucket = min(-(-max(width, 1) // 4) * 4,
+                                     eng.table_width)
+                        tbl = self.pool.table()
                     state = eng.set_page_table(
                         state, tbl[:nslots, :bucket])
                     dstate = eng.set_page_table(
                         dstate, tbl[nslots:, :bucket])
-                    spec_mask = jnp.asarray(
-                        [s is not None and s.spec for s in slots])
+                    seg = int(self.metrics["segments"])
                     seg_t0 = time.perf_counter()
-                    with eng._region_timer(DECODE_REGION):
+                    with eng.span("serve.segment", seg=seg, steps=1,
+                                  rows=int(active.sum()), width=bucket):
+                        spec_mask = jnp.asarray(
+                            [s is not None and s.spec for s in slots])
                         (toks, counts, logits, state, dstate,
                          rng) = eng.spec_segment()(
                             eng.params, eng.draft_params, state, dstate,
                             logits, rng, spec_mask)
+                    with eng.span("serve.fetch", DECODE_REGION, seg=seg):
                         # ONE sync per segment
                         toks_np, counts_np = eng._fetch((toks, counts))
                     produced = counts_np.astype(np.int64)
@@ -2030,6 +2171,7 @@ class BatchScheduler:
                     steps = eng.quantize_steps(
                         min(self.admission_chunk,
                             int(remaining[active].min())))
+                    bucket = 0
                     if self.pool is not None:
                         # cover every page this segment can write, then
                         # hand the device a table sliced to the width the
@@ -2038,81 +2180,57 @@ class BatchScheduler:
                         # model's gather window — tracks actual context,
                         # not max_seq.  A long request widens segments
                         # only while it is resident.
-                        for i in np.nonzero(active)[0]:
-                            self.pool.ensure(int(i),
-                                             int(slot_len[i]) + steps)
-                        width = max(self.pool.slot_pages(int(i))
-                                    for i in np.nonzero(active)[0])
-                        bucket = min(-(-max(width, 1) // 4) * 4,
-                                     eng.table_width)
-                        state = eng.set_page_table(
-                            state, self.pool.table()[:, :bucket])
+                        with eng.span("serve.pool"):
+                            for i in np.nonzero(active)[0]:
+                                self.pool.ensure(int(i),
+                                                 int(slot_len[i]) + steps)
+                            width = max(self.pool.slot_pages(int(i))
+                                        for i in np.nonzero(active)[0])
+                            bucket = min(-(-max(width, 1) // 4) * 4,
+                                         eng.table_width)
+                            tbl = self.pool.table()[:, :bucket]
+                        state = eng.set_page_table(state, tbl)
+                    seg = int(self.metrics["segments"])
                     seg_t0 = time.perf_counter()
-                    with eng._region_timer(DECODE_REGION):
+                    with eng.span("serve.segment", seg=seg, steps=steps,
+                                  rows=int(active.sum()), width=bucket):
                         toks, logits, state, rng = eng.decode_segment(
                             steps)(eng.params, state, logits, rng)
+                    with eng.span("serve.fetch", DECODE_REGION, seg=seg):
                         toks_np = eng._fetch(toks)  # ONE sync per segment
                     produced = np.full(nslots, steps, np.int64)
                     slot_len[active] += steps
                     self.metrics["segments"] += 1
                     self.metrics["decode_steps"] += steps
                 seg_run += 1
-                now = time.perf_counter()
-                # chaos slow/hung-segment injection inflates the OBSERVED
-                # wall (the detector path under test) without sleeping
-                seg_wall = (now - seg_t0) * self._wall_inflate
-                self._wall_inflate = 1.0
-                # the straggler detector watches segment walls on EVERY
-                # engine (hung/slow segments surface single-device too)
-                verdict = self.straggler.record(seg_wall)
-                if verdict.is_straggler:
-                    self.ft_events.append(dict(
-                        type="straggler",
-                        segment=int(self.metrics["segments"]),
-                        wall_s=seg_wall, ema_s=verdict.ema))
-                if self.heartbeats is not None:
-                    state, logits, rng = self._ft_tick(state, logits, rng,
-                                                       seg_wall)
-
-                # ---- retire: finished/expired/cancelled rows release
-                # their slots immediately
-                for i in np.nonzero(active)[0]:
-                    req = slots[i]
-                    reason = ("cancel" if req.cancel_requested
-                              else self._expiry_reason(req, now))
-                    if reason:
-                        # the in-progress segment's tokens are DISCARDED:
-                        # nothing generated after the flag/deadline was
-                        # observed is ever returned
-                        self._release_slot(int(i))
-                        self._finish_abnormal(req, reason)
-                        continue
-                    if not req.generated and not req.first_token_time:
-                        req.first_token_time = now
-                    # mask overshoot: at most this segment's real tokens
-                    # (spec rows: the accepted count), never past budget
-                    take = toks_np[i][:min(produced[i], remaining[i])]
-                    finished = False
-                    if cfg.eos_token >= 0:
-                        hits = np.nonzero(take == cfg.eos_token)[0]
-                        if hits.size:
-                            take = take[:hits[0] + 1]
-                            finished = True
-                    req.generated.extend(int(t) for t in take)
-                    remaining[i] = req.max_new_tokens - len(req.generated)
-                    if finished or remaining[i] <= 0:
-                        req.finished = True
-                        req.status = "done"
-                        self.completed[req.rid] = req
-                        self._release_slot(int(i))
-                        self.queue.note_service_time(now - req.submit_time)
-
-                if (self.snapshot_dir and self.snapshot_every
-                        and int(self.metrics["segments"])
-                        % self.snapshot_every == 0):
-                    self._write_snapshot(state)
+                with eng.span("serve.retire") as retire_span:
+                    now = time.perf_counter()
+                    # chaos slow/hung-segment injection inflates the
+                    # OBSERVED wall (the detector path under test) without
+                    # sleeping
+                    seg_wall = (now - seg_t0) * self._wall_inflate
+                    self._wall_inflate = 1.0
+                    # the straggler detector watches segment walls on
+                    # EVERY engine (hung/slow segments surface
+                    # single-device too)
+                    verdict = self.straggler.record(seg_wall)
+                    if verdict.is_straggler:
+                        self.ft_events.append(dict(
+                            type="straggler",
+                            segment=int(self.metrics["segments"]),
+                            wall_s=seg_wall, ema_s=verdict.ema))
+                    if self.heartbeats is not None:
+                        state, logits, rng = self._ft_tick(
+                            state, logits, rng, seg_wall)
+                    retire_span.set_metadata(finished=self._retire(
+                        active, toks_np, produced, now))
+                    if (self.snapshot_dir and self.snapshot_every
+                            and int(self.metrics["segments"])
+                            % self.snapshot_every == 0):
+                        self._write_snapshot(state)
                 if self.chaos is not None:
-                    self.chaos.tick(self, int(self.metrics["segments"]))
+                    with eng.span("serve.hook"):
+                        self.chaos.tick(self, int(self.metrics["segments"]))
                 if max_segments is not None and seg_run >= max_segments:
                     break
         finally:

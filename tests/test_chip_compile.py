@@ -192,3 +192,37 @@ def test_decode_segment_compiles_at_full_width(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 16 * 2**30, mem
+
+
+def test_decode_segment_pool_copies_carry_kv_cache(one_chip, monkeypatch):
+    """On the v5e the decode segment copies each layer's K/V pool slice,
+    and the paged kernel's lane-dense view of it: every such copy of a
+    whole layer's pool carries the ``kv_cache`` scope, and the kernel
+    keeps its custom-call name."""
+    import re
+
+    from repro.core.features import default_features
+    from repro.models.lm import LM
+    from repro.serve import Engine, ServeConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lm = LM(QWEN, default_features().with_(remat_policy="none"), dtype=BF16)
+    params = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0),
+                                                   dtype=BF16))
+    eng = Engine(lm, params, ServeConfig(page_size=16, batch_slots=8,
+                                         max_seq=512))
+    state = jax.eval_shape(lambda: lm.init_decode_state(
+        8, 512, page_size=16, num_pages=eng.pool_pages, table_width=8))
+    put = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    text = eng.decode_segment(2).lower(
+        put(params), put(state),
+        jax.ShapeDtypeStruct((8, QWEN.vocab), BF16, sharding=one_chip),
+        put(key)).compile().as_text()
+    p = eng.pool_pages
+    layer_pool = re.compile(
+        rf"%(copy|constant_dynamic-slice_fusion)[.\d]* = bf16\[(1,)?{p},16,")
+    moved = [ln for ln in text.splitlines() if layer_pool.search(ln)]
+    assert len(moved) >= 4
+    assert all("/kv_cache/" in ln for ln in moved), moved
+    assert re.search(r"%paged_decode_attention_grouped\.\d+ = ", text)
