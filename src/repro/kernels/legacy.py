@@ -50,7 +50,6 @@ from repro.core import hwinfo
 from repro.kernels import registry
 from repro.kernels.registry import (DEFAULT_BLOCKS, DEFAULT_CANDIDATES,
                                     DEFAULT_PAGED_CANDIDATES,
-                                    DEFAULT_PAGES_PER_BLOCK,
                                     default_interpret)
 
 __all__ = [
@@ -62,7 +61,7 @@ __all__ = [
     # autotune surface
     "DEFAULT_BLOCKS", "DEFAULT_CANDIDATES", "TuneRecord", "vmem_footprint",
     "tune_key", "autotune_flash_blocks", "best_blocks", "record_blocks",
-    "clear_table", "DEFAULT_PAGES_PER_BLOCK", "DEFAULT_PAGED_CANDIDATES",
+    "clear_table", "DEFAULT_PAGED_CANDIDATES",
     "PagedTuneRecord", "paged_tune_key", "paged_vmem_footprint",
     "autotune_paged_decode", "best_paged_block",
 ]
@@ -126,7 +125,6 @@ _STUB_REPLACEMENTS: Dict[str, str] = {
     "best_blocks": 'registry.best("attention", ...)',
     "record_blocks": 'registry.record("attention", key, (bq, bk))',
     "clear_table": "registry.clear_tune_table()",
-    "DEFAULT_PAGES_PER_BLOCK": "registry.DEFAULT_PAGES_PER_BLOCK",
     "DEFAULT_PAGED_CANDIDATES": "registry.DEFAULT_PAGED_CANDIDATES",
     "PagedTuneRecord": "registry.TuneRecord",
     "paged_tune_key": "registry.paged_lookup_key",
@@ -319,7 +317,7 @@ def vmem_footprint(bq: int, bk: int, dh: int, itemsize: int = 4) -> int:
 
 def paged_vmem_footprint(ps: int, ppb: int, g: int, dh: int,
                          itemsize: int = 4) -> int:
-    """VMEM bytes for one paged-decode grid step."""
+    """VMEM bytes of the paged decode kernel."""
     _deprecated("paged_vmem_footprint", "registry.paged_vmem(...)")
     return registry.paged_vmem(ps, ppb, g, dh, itemsize)
 

@@ -1070,14 +1070,31 @@ def _run_full(q, k, v, *, q_offset=0, causal: bool = True, kv_len=None,
 # family: paged_decode (decode attention over the serve/kv_pool pages)
 # ===========================================================================
 
-DEFAULT_PAGES_PER_BLOCK = 1
+#: keys per block of the paged kernel's walk over a row's pages: one
+#: QK^T and one P.V matmul of this depth per block, few enough blocks per
+#: row that a block's fixed cost (its DMA issue and wait, one loop trip)
+#: stays small beside its bytes
+PAGED_BLOCK_KEYS = 256
+
+
+def default_pages_per_block(page_size: int) -> int:
+    """The untuned block: ``PAGED_BLOCK_KEYS`` keys of ``page_size``-token
+    pages (16 pages of 16 tokens), at least one page."""
+    return max(1, PAGED_BLOCK_KEYS // page_size)
+
+
+#: the paged kernel's schedule, part of its tune keys: records swept for
+#: another schedule (the one-page grid that came before the live-page
+#: walk) never match
+PAGED_SCHEDULE = "walk"
 
 #: (page_size, pages_per_block) grid — page_size trades pool
 #: fragmentation against per-page DMA efficiency, pages_per_block is the
-#: kernel's fetch granularity over a row's table
+#: block of the kernel's walk over a row's live pages (16 to 512 keys)
 DEFAULT_PAGED_CANDIDATES: Tuple[Tuple[int, int], ...] = (
-    (16, 1), (16, 2), (16, 4), (32, 1), (32, 2), (32, 4),
-    (64, 1), (64, 2), (128, 1),
+    (16, 1), (16, 2), (16, 4), (16, 8), (16, 16), (16, 32),
+    (32, 1), (32, 2), (32, 4), (32, 8), (32, 16),
+    (64, 1), (64, 2), (64, 4), (64, 8), (128, 1), (128, 2), (128, 4),
 )
 
 
@@ -1101,7 +1118,8 @@ def paged_lookup_key(*, b: int, kvh: int, g: int, dh: int, page_size: int,
     # facts join the key: each device walks its kv-head slice of the
     # page pool, so the fetch granularity is a per-sharding property.
     tag = "q8" if quantized else ""
-    return (f"paged{tag}-b{b}kvh{kvh}g{g}dh{dh}ps{page_size}"
+    return (f"paged{tag}-{PAGED_SCHEDULE}-b{b}kvh{kvh}g{g}dh{dh}"
+            f"ps{page_size}"
             f"ctx{_paged_ctx_bucket(ctx)}"
             f"-{_dtype_name(dtype)}-{_backend(backend)}"
             + mesh_key_tag(mesh_shape=mesh_shape, mesh_axis=mesh_axis,
@@ -1114,7 +1132,8 @@ def paged_sweep_key(*, b: int, kvh: int, g: int, dh: int, ctx: int, dtype,
                     mesh_shape=None, mesh_axis=None,
                     per_device_heads=None, **_ignored) -> str:
     tag = "q8" if quantized else ""
-    return (f"paged{tag}-sweep-b{b}kvh{kvh}g{g}dh{dh}ctx{ctx}"
+    return (f"paged{tag}-{PAGED_SCHEDULE}-sweep-b{b}kvh{kvh}g{g}dh{dh}"
+            f"ctx{ctx}"
             f"-{_dtype_name(dtype)}-{_backend(backend)}"
             + mesh_key_tag(mesh_shape=mesh_shape, mesh_axis=mesh_axis,
                            per_device_heads=per_device_heads))
@@ -1130,31 +1149,33 @@ def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
 def _paged_kernel_vmem(ps: int, ppb: int, g: int, dh: int, kvh: int,
                        itemsize: int, page_itemsize: int,
                        scales: bool) -> int:
-    """VMEM bytes for one grid step of ``paged_decode._paged_call``.
+    """VMEM bytes of ``paged_decode._paged_call``.
 
-    Double-buffered blocks: the block-diagonal queries [KVH, G, KVH*Dh],
-    ppb k and v page tiles [ps, KVH*Dh] (+ [ps, 1] f32 scale columns for
-    int8 pages), the new token's k/v rows [1, KVH*Dh] and the output
-    [G, KVH*Dh].  Scratch once: m/l [KVH, G, 1] and the f32 accumulator
-    [KVH, G, KVH*Dh].  f32 temporaries: the widened k/v tiles, one widened
-    query row, the [G, ps] score and probability tiles and the P.V
-    product."""
+    Pipelined per row, double-buffered: the block-diagonal queries
+    [KVH*Gp, KVH*Dh] (G padded to a multiple of 8), the new token's k/v
+    rows [1, KVH*Dh] and the output [Gp, KVH*Dh].  The kernel's own DMA
+    buffers, two slots each: ppb k and v page tiles [ps, KVH*Dh] (+ their
+    [ps, 128] f32 scale tiles for int8 pages).  Scratch: m/l [KVH*Gp, 1]
+    and the f32 accumulator [KVH*Gp, KVH*Dh].  f32 temporaries of one
+    block of ppb*ps keys: the widened k and v, the [KVH*Gp, ppb*ps] score
+    and probability tiles, the P.V product and the finish."""
     w = kvh * dh
-    blocks = (kvh * _tile_bytes(g, w, itemsize)
-              + 2 * ppb * _tile_bytes(ps, w, page_itemsize)
-              + (2 * ppb * _tile_bytes(ps, 1, 4) if scales else 0)
-              + 2 * _tile_bytes(1, w, itemsize)
-              + _tile_bytes(g, w, itemsize))
-    scratch = kvh * (2 * _tile_bytes(g, 1, 4) + _tile_bytes(g, w, 4))
-    temps = (2 * _tile_bytes(ps, w, 4) + 2 * _tile_bytes(g, w, 4)
-             + 2 * _tile_bytes(g, ps, 4))
-    return 2 * blocks + scratch + temps
+    r = kvh * -(-g // 8) * 8
+    bk = ppb * ps
+    blocks = (_tile_bytes(r, w, itemsize) + 2 * _tile_bytes(1, w, itemsize)
+              + _tile_bytes(r // kvh, w, itemsize))
+    bufs = 2 * 2 * ppb * (_tile_bytes(ps, w, page_itemsize)
+                          + (_tile_bytes(ps, 128, 4) if scales else 0))
+    scratch = 2 * _tile_bytes(r, 1, 4) + _tile_bytes(r, w, 4)
+    temps = (2 * _tile_bytes(bk, w, 4) + 2 * _tile_bytes(r, bk, 4)
+             + 2 * _tile_bytes(r, w, 4))
+    return 2 * blocks + bufs + scratch + temps
 
 
 def paged_vmem(ps: int, ppb: int, g: int, dh: int, itemsize: int = 4,
                kvh: int = 1) -> int:
-    """VMEM bytes for one grid step of the fp paged decode kernel with
-    ``kvh`` kv heads per device (see :func:`_paged_kernel_vmem`)."""
+    """VMEM bytes of the fp paged decode kernel with ``kvh`` kv heads per
+    device (see :func:`_paged_kernel_vmem`)."""
     return _paged_kernel_vmem(ps, ppb, g, dh, kvh, itemsize, itemsize,
                               scales=False)
 
@@ -1238,7 +1259,8 @@ _PAGED_TUNE = TuneSpace(
     candidates=lambda **f: DEFAULT_PAGED_CANDIDATES,
     vmem=_paged_vmem,
     probe=_paged_probe,
-    default=lambda *, page_size, **f: (page_size, DEFAULT_PAGES_PER_BLOCK),
+    default=lambda *, page_size, **f: (page_size,
+                                         default_pages_per_block(page_size)),
     lookup_key=paged_lookup_key,
     record_keys=_paged_record_keys,
     neighbors=_paged_neighbors,
@@ -1307,7 +1329,8 @@ _PAGED_Q8_TUNE = TuneSpace(
     candidates=lambda **f: DEFAULT_PAGED_CANDIDATES,
     vmem=_paged_q8_vmem,
     probe=_paged_q8_probe,
-    default=lambda *, page_size, **f: (page_size, DEFAULT_PAGES_PER_BLOCK),
+    default=lambda *, page_size, **f: (page_size,
+                                         default_pages_per_block(page_size)),
     lookup_key=_paged_q8_lookup_key,
     record_keys=_paged_q8_record_keys,
     neighbors=_paged_neighbors,

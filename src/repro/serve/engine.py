@@ -468,13 +468,20 @@ class Engine:
         return mesh
 
     def set_page_table(self, state, table) -> Any:
-        """Swap the (host-managed) page table into a decode state."""
+        """Swap the (host-managed) page table into a decode state.  A row
+        whose table starts at the null page (page 0) owns no pages, so it
+        holds no context: its length is set to 0, and the paged kernel
+        spends nothing on it (a freed slot's length would otherwise keep
+        growing with every segment, over null pages)."""
         caches = state["caches"]
         n_layers = caches.length.shape[0]
         with self.span("serve.page_table", width=table.shape[-1]):
-            tbl = jnp.broadcast_to(jnp.asarray(table, jnp.int32)[None],
+            table = jnp.asarray(table, jnp.int32)
+            tbl = jnp.broadcast_to(table[None],
                                    (n_layers,) + tuple(table.shape))
-            return dict(state, caches=caches._replace(page_table=tbl))
+            length = jnp.where(table[:, 0] == 0, 0, caches.length)
+            return dict(state, caches=caches._replace(page_table=tbl,
+                                                      length=length))
 
     def _fetch(self, tree):
         """THE device->host sync point: every transfer is counted here."""

@@ -17,8 +17,9 @@ import pytest
 
 from repro.core.artifact_cache import ArtifactCache
 from repro.core.session import ProfileSession
-from repro.kernels import autotune, dispatch, ref
-from repro.kernels.paged_decode import paged_decode_attention
+from repro.kernels import autotune, dispatch, ref, registry
+from repro.kernels.paged_decode import (paged_decode_attention,
+                                        paged_decode_attention_q8)
 from repro.models.attention import paged_decode_jnp
 from repro.serve.kv_pool import KVPool, pages_for
 
@@ -40,10 +41,54 @@ def _case(rng, b, h, kvh, dh, ps, np_w, lens):
 # kernel parity grid: page_size x ragged lengths x GQA groups
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("ps,np_w,ppb", [(4, 7, 1), (8, 4, 2), (16, 3, 4)])
-@pytest.mark.parametrize("h,kvh", [(4, 2), (8, 2), (4, 4)])
+def _run_flavor(flavor, q, kp, vp, pt, lens, kn, vn, *, ppb,
+                ref_pt=None, ref_kp=None, ref_vp=None, scales=None):
+    """(kernel output, oracle output) of one flavor: ``fp`` pages as given,
+    ``bf16`` queries, pages and new token (the oracle reads the same
+    values in f32), or ``q8``: int8 codes with per-token ``scales``.  The
+    oracle may read other pages and tables than the kernel (``ref_*``)."""
+    ref_pt = pt if ref_pt is None else ref_pt
+    ref_kp = kp if ref_kp is None else ref_kp
+    ref_vp = vp if ref_vp is None else ref_vp
+    if flavor in ("fp", "bf16"):
+        if flavor == "bf16":              # the oracle reads the same values
+            q, kp, vp, kn, vn, ref_kp, ref_vp = (
+                x.astype(jnp.bfloat16)
+                for x in (q, kp, vp, kn, vn, ref_kp, ref_vp))
+        f32 = lambda x: x.astype(jnp.float32)
+        want = ref.paged_decode(f32(q), f32(ref_kp), f32(ref_vp), ref_pt,
+                                lens, f32(kn), f32(vn))
+        got = paged_decode_attention(q, kp, vp, pt, lens, kn, vn,
+                                     pages_per_block=ppb, interpret=True)
+        return f32(got), want
+    ksc, vsc, ref_ksc, ref_vsc = scales
+    want = ref.paged_decode_q8(q, ref_kp, ref_vp, ref_pt, lens, kn, vn,
+                               k_scale=ref_ksc, v_scale=ref_vsc)
+    got = paged_decode_attention_q8(q, kp, vp, pt, lens, kn, vn,
+                                    k_scale=ksc, v_scale=vsc,
+                                    pages_per_block=ppb, interpret=True)
+    return got, want
+
+
+def _tol(flavor):
+    """f32 arithmetic throughout; a bf16 output is rounded once (2^-9)."""
+    return (dict(rtol=2 ** -7, atol=2 ** -7) if flavor == "bf16"
+            else dict(rtol=1e-5, atol=1e-5))
+
+
+def _q8_pages(rng, shape):
+    codes = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+    scale = jnp.asarray(rng.uniform(0.005, 0.05, size=shape[:2]),
+                        jnp.float32)
+    return codes, scale
+
+
+# a block of ppb pages: 3 and 4 do not divide widths 7 and 6
+@pytest.mark.parametrize("ps,np_w,ppb", [(4, 7, 1), (8, 4, 2), (16, 3, 4),
+                                         (4, 7, 3), (8, 6, 4)])
+@pytest.mark.parametrize("h,kvh", [(4, 2), (8, 2), (4, 4), (7, 1)])
 def test_paged_kernel_parity_grid(ps, np_w, ppb, h, kvh):
-    rng = np.random.default_rng(ps * 100 + h * 10 + kvh)
+    rng = np.random.default_rng(ps * 100 + h * 10 + kvh + ppb * 1000)
     b, dh = 3, 16
     lens = [int(rng.integers(0, np_w * ps + 1)) for _ in range(b)]
     args = _case(rng, b, h, kvh, dh, ps, np_w, lens)
@@ -57,26 +102,80 @@ def test_paged_kernel_parity_grid(ps, np_w, ppb, h, kvh):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_paged_kernel_edge_rows():
-    """Empty row (length 0, null-page table), exactly-full pages, and a
-    single-token row — in one batch, with ppb not dividing the width."""
-    rng = np.random.default_rng(7)
-    b, h, kvh, dh, ps, np_w = 3, 4, 2, 16, 8, 3
+@pytest.mark.parametrize("flavor", ["fp", "bf16", "q8"])
+@pytest.mark.parametrize("kvh", [2, 1])
+@pytest.mark.parametrize("ppb", [1, 2, 3])
+def test_paged_kernel_edge_rows(ppb, kvh, flavor):
+    """Empty row (length 0, null-page table), a single token, exactly one
+    block, the full table width and a block and a bit — in one batch,
+    with blocks that do and do not divide the width."""
+    rng = np.random.default_rng(7 + ppb)
+    b, h, dh, ps, np_w = 5, 4, 16, 8, 7
     q, kp, vp, pt, _, kn, vn = _case(rng, b, h, kvh, dh, ps, np_w,
-                                     [0, 0, 0])
+                                     [0] * b)
     pt = pt.at[0].set(0)                      # released slot: null pages
-    lens = jnp.asarray([0, np_w * ps, 1], jnp.int32)
-    want = ref.paged_decode(q, kp, vp, pt, lens, kn, vn)
-    for ppb in (1, 2):
-        got = paged_decode_attention(q, kp, vp, pt, lens, kn, vn,
-                                     pages_per_block=ppb, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
+    lens = jnp.asarray([0, 1, ppb * ps, np_w * ps, ppb * ps + 3],
+                       jnp.int32)
+    scales = None
+    if flavor == "q8":
+        kp, ksc = _q8_pages(rng, kp.shape)
+        vp, vsc = _q8_pages(rng, vp.shape)
+        scales = (ksc, vsc, ksc, vsc)
+    got, want = _run_flavor(flavor, q, kp, vp, pt, lens, kn, vn,
+                            ppb=ppb, scales=scales)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_tol(flavor))
     # the empty row attends only the new token: output is exactly v_new
     got0 = np.asarray(got[0, 0]).reshape(kvh, h // kvh, dh)
     np.testing.assert_allclose(
         got0, np.broadcast_to(np.asarray(vn[0, 0])[:, None], got0.shape),
-        rtol=1e-5)
+        **_tol(flavor))
+
+
+@pytest.mark.parametrize("flavor", ["fp", "bf16", "q8"])
+@pytest.mark.parametrize("ppb", [2, 4])
+def test_paged_kernel_never_reads_dead_pages(ppb, flavor):
+    """Every table entry past a row's live pages points at a page of NaN,
+    and every token past a row's length in its last live page is NaN: the
+    output still equals the oracle run over clean pages, so no dead page
+    and no dead token reaches the softmax or the P.V product."""
+    rng = np.random.default_rng(11 + ppb)
+    b, h, kvh, dh, ps, np_w = 4, 4, 2, 16, 4, 9
+    lens = np.array([0, 5, ppb * ps, np_w * ps - 2])
+    q, kp, vp, pt, lens_j, kn, vn = _case(rng, b, h, kvh, dh, ps, np_w,
+                                          lens)
+    if flavor == "q8":
+        kp, ksc = _q8_pages(rng, kp.shape)
+        vp, vsc = _q8_pages(rng, vp.shape)
+    nan_page = kp.shape[0]                    # one page past the pool
+    live = -(-lens // ps)
+    dead = np.arange(np_w)[None, :] >= live[:, None]
+    pt_nan = jnp.where(jnp.asarray(dead), nan_page, pt)
+    # token rows of live pages past each row's length
+    tail = np.zeros(kp.shape[:2], bool)
+    ptn = np.asarray(pt)
+    for r in range(b):
+        for j in range(live[r]):
+            tail[ptn[r, j], max(lens[r] - j * ps, 0):] = True
+    tail = jnp.asarray(tail)
+    if flavor != "q8":
+        poison = lambda x: jnp.concatenate(
+            [jnp.where(tail[..., None, None], jnp.nan, x),
+             jnp.full_like(x[:1], jnp.nan)])
+        got, want = _run_flavor(flavor, q, poison(kp), poison(vp),
+                                pt_nan, lens_j, kn, vn, ppb=ppb,
+                                ref_pt=pt, ref_kp=kp, ref_vp=vp)
+    else:
+        poison = lambda sc: jnp.concatenate(
+            [jnp.where(tail, jnp.nan, sc), jnp.full_like(sc[:1], jnp.nan)])
+        pad = lambda x: jnp.concatenate([x, x[:1]])
+        got, want = _run_flavor(flavor, q, pad(kp), pad(vp), pt_nan,
+                                lens_j, kn, vn, ppb=ppb, ref_pt=pt,
+                                ref_kp=kp, ref_vp=vp,
+                                scales=(poison(ksc), poison(vsc), ksc, vsc))
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_tol(flavor))
 
 
 def test_paged_matches_dense_decode_token_softmax():
@@ -166,16 +265,16 @@ def test_paged_autotune_feeds_dispatch_table(tmp_path):
     try:
         kw = dict(b=2, kvh=2, g=2, dh=16, page_size=16, dtype=jnp.float32)
         assert autotune.best_paged_block(**kw) \
-            == autotune.DEFAULT_PAGES_PER_BLOCK
+            == registry.default_pages_per_block(16)
         sess = ProfileSession(cache_dir=str(tmp_path / "cache"))
         rec = autotune.autotune_paged_decode(**PAGED_SHAPE, session=sess,
                                              candidates=PAGED_CANDS)
         # the winner per page_size is consulted by dispatch — and the key
-        # is table-width-agnostic, so the scheduler's live-mix buckets
-        # (any width) find the same record
+        # buckets the context, so the scheduler's live-mix widths (here
+        # 48 tokens of table against the sweep's 64) find the same record
         by_ppb = {ppb: s for (ps, ppb), s in rec.scores.items()
                   if ps == 16}
-        got = autotune.best_paged_block(**kw)
+        got = registry.best("paged_decode", ctx=48, **kw)[1]
         assert by_ppb[got] == min(by_ppb.values())
     finally:
         autotune.clear_table()
@@ -311,6 +410,25 @@ def _lm_params():
     lm = LM(cfg, default_features().with_(remat_policy="none"),
             dtype=jnp.float32)
     return lm, lm.init(jax.random.PRNGKey(0))
+
+
+def test_set_page_table_empties_rows_without_pages():
+    """A slot whose table row starts at the null page owns no pages: the
+    table swap sets its length to 0, so the kernel walks nothing for it,
+    while rows with pages keep their lengths."""
+    from repro.serve.engine import Engine, ServeConfig
+    lm, params = _lm_params()
+    eng = Engine(lm, params, ServeConfig(max_seq=32, batch_slots=3,
+                                         page_size=8))
+    state = eng.lm.init_decode_state(3, 32, **eng._state_kwargs())
+    state = eng._with_lengths(state, jnp.asarray([5, 9, 7], jnp.int32))
+    table = np.zeros((3, eng.table_width), np.int32)
+    table[0, :1] = [3]
+    table[2, :2] = [1, 2]
+    got = eng.set_page_table(state, table)["caches"].length
+    assert got.shape == state["caches"].length.shape
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.broadcast_to([5, 0, 7], got.shape))
 
 
 def test_engine_rejects_paged_for_recurrent_families():
