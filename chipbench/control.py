@@ -37,26 +37,25 @@ def readings(lk, workload: str, seeds, seconds: float, *,
              require_tpu=True, fault=None):
     """[(seed, program reading, control reading, program correct,
     control correct), ...]"""
-    from chipbench import reference, traffic, weights
+    from chipbench import traffic
     from chipbench.window import Driver
-    w = lk.workload(workload)
-    cfg, mix, cell = lk.config(w["config"]), lk.mix(w["traffic"]), \
-        lk.cell(workload)
-    if run.check_devices(w["chips"], require_tpu) is None:
+    sy = run.system(lk, workload, require_tpu)
+    if sy is None:
         return None
+    cfg, mix, cell, fam = sy.cfg, sy.mix, sy.cell, sy.fam
     run.enable_cache()
     limit = float(cell["check"]["widest_logit_gap"])
     eng = None
     out = []
     for seed in seeds:
         if eng is None:
-            params, eng, _ = run.setup(cfg, mix, seed)
+            params, eng, _ = run.setup(sy, seed)
             if fault is not None:
                 fault(eng)
         else:
             eng.params = None
             gc.collect()
-            params = weights.make(cfg, seed)
+            params = fam.make_weights(cfg, seed, sy.mesh)
         eng.params = params
         arrivals = traffic.schedule(mix, cell["rate"], seconds, seed,
                                     cfg["vocab_size"])
@@ -65,9 +64,9 @@ def readings(lk, workload: str, seeds, seconds: float, *,
         served = run.sample(drv, seed)
         drv.unwrap()
         width, max_seq = int(mix["output"]["max"]), cfg["serve"]["max_seq"]
-        prog = reference.widest_gap(params, cfg, served, max_seq, width)
-        ctrl = reference.widest_gap(params, cfg, served, max_seq, width,
-                                    control=True)
+        prog = fam.widest_gap(params, cfg, served, max_seq, width)
+        ctrl = fam.widest_gap(params, cfg, served, max_seq, width,
+                              control=True)
         ok_prog, ok_ctrl = run.judge(prog, limit)[0], \
             run.judge(ctrl, limit)[0]
         run.log(f"[control] seed {seed}: {drv.failed()} of "

@@ -1,6 +1,7 @@
 """The flash prefill kernel's share of its roofline in the traced
 stretch: the least time of its calls (plain prefills; a suffix after a
-prefix hit does not run it) over the kernel's device time."""
+prefix hit does not run it) over the kernel's device time, on a mesh
+each chip's share of the work over its own kernel time."""
 
 import sys
 
@@ -16,8 +17,8 @@ def read(rec):
     least, bound = 0.0, {"memory": 0.0, "compute": 0.0}
     for n, p in prefills(rec):
         if p == 0:
-            t, b = work.roofline_seconds(
-                *work.flash_prefill_work(rec.cfg, n), pk)
+            f, by = work.flash_prefill_work(rec.cfg, n)
+            t, b = work.roofline_seconds(f / rec.chips, by / rec.chips, pk)
             least += t
             bound[b] += t
     secs = tr.kernel_seconds(rec.trace, "pallas_flash")

@@ -1,7 +1,9 @@
 """The paged decode kernel's share of its roofline in the traced stretch:
 the least time its calls could take (each call's live K/V, queries and
 outputs over HBM bandwidth, or its FLOPs over the bf16 peak, whichever
-is larger) over the kernel's device time."""
+is larger) over the kernel's device time.  On a mesh each chip runs the
+kernel over its own KV heads: its share of the work over one chip's
+peaks, against its own kernel time."""
 
 import sys
 
@@ -17,8 +19,8 @@ def read(rec):
     least, bound = 0.0, {"memory": 0.0, "compute": 0.0}
     for q, lens in segments(rec):
         for j in range(q):
-            t, b = work.roofline_seconds(
-                *work.paged_decode_work(rec.cfg, lens + j), pk)
+            f, by = work.paged_decode_work(rec.fam, rec.cfg, lens + j)
+            t, b = work.roofline_seconds(f / rec.chips, by / rec.chips, pk)
             least += t
             bound[b] += t
     secs = tr.kernel_seconds(rec.trace, "pallas_paged")

@@ -6,15 +6,18 @@
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration and a
 traffic mix; ``chipbench/cells/<cell>.json`` holds its offered rate, its
-lead-in and the limit of its output check.  The run makes the weights on
-the device from the seed, builds the serving engine, warms every program
-the cell's traffic can reach, builds the arrival schedule from the seed,
-and then offers that schedule open loop (``window.py``): the lead-in's
-``lead_in_s`` seconds, which bring the server to its steady load, then
-the measured window of ``--seconds`` seconds, following every request due
-in the window to its end.
+lead-in and the limit of its output check.  The configuration names the
+family whose code runs it (``chipbench/families/<family>.py``) and, under
+``serve.mesh``, the (data, model) mesh it is served on, whose size is the
+cell's chips.  The run makes the weights on the device (on a mesh, in
+their shardings) from the seed, builds the serving engine, warms every
+program the cell's traffic can reach, builds the arrival schedule from
+the seed, and then offers that schedule open loop (``window.py``): the
+lead-in's ``lead_in_s`` seconds, which bring the server to its steady
+load, then the measured window of ``--seconds`` seconds, following every
+request due in the window to its end.
 It then frees the program's state, checks a sample of the served
-requests against the plain reference (``reference.py``), and prints one
+requests against the family's plain reference, and prints one
 JSON line last on stdout.  With ``--trace 0`` that line holds the cell's
 end-to-end metrics; with ``--trace 1`` a few seconds in the middle of the
 window are traced and the line holds the per-layer metrics instead.
@@ -69,14 +72,25 @@ def parse(argv=None):
 def enable_cache() -> str:
     """JAX's persistent compilation cache at a fixed path in the
     checkout (or ``$JAX_COMPILATION_CACHE_DIR``), keeping every program
-    however quickly it compiled."""
+    however quickly it compiled, and however many there are: a cap on its
+    size (``$JAX_COMPILATION_CACHE_MAX_SIZE``) would evict programs of
+    this very cell, whose warm-up on a mesh writes about a gigabyte."""
     import jax
     path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
             or os.path.join(ROOT, ".jax_cache"))
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_max_size", -1)
     return path
+
+
+def mesh_size(cfg) -> int:
+    """Chips the configuration is served on: its mesh's, or one."""
+    n = 1
+    for k in cfg["serve"].get("mesh") or ():
+        n *= int(k)
+    return n
 
 
 def check_devices(chips: int, require_tpu: bool):
@@ -115,15 +129,55 @@ def sample(drv, seed: int, tokens: int = CHECK_TOKENS,
     return [(list(r.prompt), list(r.generated)) for r in out]
 
 
-def setup(cfg, mix, seed: int):
+class System:
+    """What a cell runs: its entry, configuration, mix and data, the
+    family's code, the devices, and the serving mesh (a ``ServeMesh``
+    over the configuration's ``serve.mesh``, axes (data, model); None on
+    one chip)."""
+
+    def __init__(self, lk, workload: str, devices):
+        self.w = lk.workload(workload)
+        self.cfg, self.mix = lk.config(self.w["config"]), \
+            lk.mix(self.w["traffic"])
+        self.cell = lk.cell(workload)
+        self.fam = lk.family(self.cfg["family"])
+        self.mesh = None
+        self.devices = devices[:1]
+        shape = self.cfg["serve"].get("mesh")
+        if shape:
+            from repro.launch.mesh import make_serve_mesh
+            n = mesh_size(self.cfg)
+            self.mesh = make_serve_mesh(tuple(shape), ("data", "model"),
+                                        devices=devices[:n])
+            self.devices = list(self.mesh.mesh.devices.flat)
+
+    def memory_peak(self) -> int:
+        """The largest peak over the devices served on."""
+        return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                   for d in self.devices)
+
+
+def system(lk, workload: str, require_tpu: bool):
+    """The cell's ``System``, or None without the devices it needs.  A
+    cell whose chips are not its configuration's mesh is an error."""
+    w = lk.workload(workload)
+    n = mesh_size(lk.config(w["config"]))
+    if n != w["chips"]:
+        raise ValueError(f"{workload}: the cell asks for {w['chips']} "
+                         f"chips, its configuration's mesh has {n}")
+    devices = check_devices(w["chips"], require_tpu)
+    return None if devices is None else System(lk, workload, devices)
+
+
+def setup(sy: System, seed: int):
     """Weights from the seed, the serving engine, and the warm-up of every
     program the mix can reach: (params, engine, programs warmed by
     kind)."""
-    from chipbench import model, traffic, warmup, weights
-    params = weights.make(cfg, seed)
-    eng = model.engine(cfg, params)
-    counts = warmup.warm(eng, traffic.prefill_shapes(mix),
-                         traffic.max_context(mix), seed)
+    from chipbench import traffic, warmup
+    params = sy.fam.make_weights(sy.cfg, seed, sy.mesh)
+    eng = sy.fam.engine(sy.cfg, params, sy.mesh)
+    counts = warmup.warm(eng, traffic.prefill_shapes(sy.mix),
+                         traffic.max_context(sy.mix), seed)
     return params, eng, counts
 
 
@@ -144,22 +198,20 @@ class Record:
 def serve(args, lk, *, require_tpu=True, fault=None, out_dir=None):
     """Set up, run the window, check.  Returns (record, result dict) or
     None without the devices the cell needs."""
-    w = lk.workload(args.workload)
-    cfg, mix, cell = lk.config(w["config"]), lk.mix(w["traffic"]), \
-        lk.cell(args.workload)
-    devices = check_devices(w["chips"], require_tpu)
-    if devices is None:
+    sy = system(lk, args.workload, require_tpu)
+    if sy is None:
         return None
-    dev = devices[0]
+    cfg, mix, cell = sy.cfg, sy.mix, sy.cell
+    dev = sy.devices[0]
     cache = enable_cache()
 
-    from chipbench import reference, traffic
+    from chipbench import traffic
     from chipbench.compile_log import CompileLog
     from chipbench.trace import Tracer
     from chipbench.window import Driver
 
     clog = CompileLog()
-    params, eng, counts = setup(cfg, mix, args.seed)
+    params, eng, counts = setup(sy, args.seed)
     shapes = traffic.prefill_shapes(mix)
     log(f"[setup] programs warmed by kind: "
         + ", ".join(f"{k} {v}" for k, v in sorted(counts.items()))
@@ -185,8 +237,7 @@ def serve(args, lk, *, require_tpu=True, fault=None, out_dir=None):
     setup_s = time.perf_counter() - _T0
     drv.run()
     low1, hit1, comp1, secs1 = clog.snapshot()
-    stats = dev.memory_stats() or {}
-    mem_peak = int(stats.get("peak_bytes_in_use", 0))
+    mem_peak = sy.memory_peak()
     log(f"[window] {len(arrivals) - drv.attempted()} lead-in requests "
         f"over {lead_s} s; {drv.attempted()} requests due in "
         f"{args.seconds} s, "
@@ -200,7 +251,8 @@ def serve(args, lk, *, require_tpu=True, fault=None, out_dir=None):
     rec = Record(cfg=cfg, drv=drv, recs=drv.recs, seconds=args.seconds,
                  setup_s=setup_s, lowered_in_window=low1 - low0,
                  sched=sched_metrics, tracer=tracer, trace=None,
-                 device_kind=dev.device_kind)
+                 device_kind=dev.device_kind, fam=sy.fam,
+                 chips=len(sy.devices))
     # the program's state goes before the reference runs
     drv.eng = drv.sched = None
     del eng
@@ -208,8 +260,8 @@ def serve(args, lk, *, require_tpu=True, fault=None, out_dir=None):
 
     limit = float(cell["check"]["widest_logit_gap"])
     width = int(mix["output"]["max"])
-    gap = (reference.widest_gap(params, cfg, served, cfg["serve"]["max_seq"],
-                                width) if served else None)
+    gap = (sy.fam.widest_gap(params, cfg, served, cfg["serve"]["max_seq"],
+                             width) if served else None)
     log(f"[check] {len(served)} requests, "
         f"{sum(len(o) for _, o in served)} served tokens against the plain "
         f"reference")
@@ -217,7 +269,7 @@ def serve(args, lk, *, require_tpu=True, fault=None, out_dir=None):
     result = {"correct": correct, "attempted": drv.attempted(),
               "failed": drv.failed(), "metrics": {},
               "device": {"platform": dev.platform, "kind": dev.device_kind,
-                         "count": len(devices),
+                         "count": len(sy.devices),
                          "memory_peak_bytes": mem_peak}}
     del params
     return rec, result, check

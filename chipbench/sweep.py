@@ -13,7 +13,8 @@ to its steady load first, so a window longer than a request's life shows
 whether the rate is sustained.  The knee is the highest rate whose queue
 did not grow through the window: at least 95 % of the requests due were
 admitted inside it.  ``--write`` puts 0.8 of the knee into the cell's
-data file as its rate, with the sweep beside it.
+data file as its rate, with the knee and each rate's admissions beside
+it.
 """
 
 from __future__ import annotations
@@ -46,14 +47,14 @@ def sweep(lk, workload: str, rates, seconds: float, seed: int,
           drain_s: float = run.DRAIN_S, *, require_tpu=True):
     from chipbench import traffic
     from chipbench.window import Driver
-    w = lk.workload(workload)
-    cfg, mix = lk.config(w["config"]), lk.mix(w["traffic"])
-    lead_s = float(lk.cell(workload).get("lead_in_s", 0.0))
-    if run.check_devices(w["chips"], require_tpu) is None:
+    sy = run.system(lk, workload, require_tpu)
+    if sy is None:
         return None
+    cfg, mix = sy.cfg, sy.mix
+    lead_s = float(sy.cell.get("lead_in_s", 0.0))
     run.enable_cache()
     t = time.perf_counter()
-    _, eng, _ = run.setup(cfg, mix, seed)
+    _, eng, _ = run.setup(sy, seed)
     run.log(f"[sweep] set-up {time.perf_counter() - t:.1f} s")
     rows = []
     for rate in rates:
@@ -106,6 +107,11 @@ def main(argv=None) -> int:
         cell["rate"] = round(0.8 * knee, 3)
         cell["knee"] = {k: out[k] for k in ("knee", "seconds", "seed")}
         cell["knee"]["lead_in_s"] = rows[0]["lead_in_s"]
+        cell["knee"]["drain_s"] = args.drain
+        cell["knee"]["sweep"] = [
+            {k: r[k] for k in ("rate", "requests_due", "admitted_in_window",
+                               "queue_at_close", "failed")}
+            for r in rows]
         with open(path, "w") as f:
             json.dump(cell, f, indent=1)
             f.write("\n")

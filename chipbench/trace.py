@@ -5,11 +5,14 @@
 is in flight at either edge) and marks both edges with host spans.
 
 ``load`` turns the ``.xplane.pb`` into a compact ``Trace``: device
-operations and device program (module) executions, and the host spans
-the benchmark wrote.  On a TPU the device is the ``/device:TPU:<n>``
-planes (lines ``XLA Ops`` and ``XLA Modules``); in a CPU trace, used by
-the tests, the operations are the host events that carry an ``hlo_op``
-stat.  Every reduction below works on the compact form.
+operations and device program (module) executions, each with the index
+of its device, and the host spans the benchmark wrote.  On a TPU each
+``/device:TPU:<n>`` plane is one device (lines ``XLA Ops`` and ``XLA
+Modules``); in a CPU trace, used by the tests, the operations are the
+host events that carry an ``hlo_op`` stat, all on device 0.  Every
+reduction below works on the compact form, one device at a time, and
+averages over the devices: on a mesh the chips run together, so a union
+over all of them would count a chip idle while its neighbours work.
 """
 
 from __future__ import annotations
@@ -17,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 import glob
 import os
+import re
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -39,11 +43,18 @@ PROGRAMS = {"segment": "jit_seg", "prefill": "_paged_slot_prefill_impl",
             "cow_copy": "_copy_pages_impl"}
 
 
+#: the HLO opcodes of collectives, synchronous or as an async pair,
+#: matched on an operation's HLO text (``%all-reduce.8 = bf16[...]
+#: all-reduce(...)``); an operand named after one is not followed by ``(``
+COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|reduce-scatter|"
+                        r"collective-permute|all-to-all)(-start|-done)?\(")
+
+
 @dataclasses.dataclass
 class Trace:
     """Times in seconds on one clock (the profiler's)."""
-    ops: List[Tuple[str, float, float]]        # name, start, end
-    modules: List[Tuple[str, float, float]]
+    ops: List[Tuple[str, float, float, int]]   # name, start, end, device
+    modules: List[Tuple[str, float, float, int]]
     spans: List[Tuple[str, float, float]]
     devices: int
 
@@ -52,6 +63,14 @@ class Trace:
         opened = [s for n, s, _ in self.spans if n == "trace_open"]
         closed = [s for n, s, _ in self.spans if n == "trace_close"]
         return min(opened), max(closed)
+
+    def on(self, device: int) -> "Trace":
+        """One device's operations and modules, as device 0 of a trace of
+        its own, with every span."""
+        def one(items):
+            return [(n, s, e, 0) for n, s, e, d in items if d == device]
+        return Trace(ops=one(self.ops), modules=one(self.modules),
+                     spans=self.spans, devices=1)
 
 
 class Tracer:
@@ -120,16 +139,18 @@ def load(path: str) -> Trace:
     ops, modules, spans = [], [], []
     tpus = [p for p in planes if p.name.startswith("/device:TPU:")]
 
-    def events(line):
+    def events(line, dev):
         return [(e.name, e.start_ns * 1e-9,
-                 (e.start_ns + e.duration_ns) * 1e-9) for e in line.events]
+                 (e.start_ns + e.duration_ns) * 1e-9, dev)
+                for e in line.events]
 
-    for plane in tpus:
+    tpus.sort(key=lambda p: int(p.name.rsplit(":", 1)[1]))
+    for dev, plane in enumerate(tpus):
         for line in plane.lines:
             if line.name == "XLA Ops":
-                ops.extend(events(line))
+                ops.extend(events(line, dev))
             elif line.name == "XLA Modules":
-                modules.extend(events(line))
+                modules.extend(events(line, dev))
     for plane in planes:
         if plane.name != "/host:CPU":
             continue
@@ -142,29 +163,31 @@ def load(path: str) -> Trace:
                     st = _stats(e)
                     if "hlo_op" in st:
                         s, d = e.start_ns * 1e-9, e.duration_ns * 1e-9
-                        ops.append((e.name, s, s + d))
-                        modules.append((str(st["hlo_module"]), s, s + d))
+                        ops.append((e.name, s, s + d, 0))
+                        modules.append((str(st["hlo_module"]), s, s + d, 0))
     return Trace(ops=ops, modules=modules, spans=spans,
                  devices=max(len(tpus), 1))
 
 
 # ------------------------------------------------------------- reductions
 def _clip(items, lo, hi):
-    return [(n, max(s, lo), min(e, hi)) for n, s, e in items
+    return [(n, max(s, lo), min(e, hi)) for n, s, e, *_ in items
             if e > lo and s < hi]
 
 
-def busy(tr: Trace) -> float:
-    """Seconds in which some operation ran on a device, averaged over the
-    devices, inside the window."""
+def _busy(tr: Trace) -> float:
     lo, hi = tr.window()
     return sum(e - s for s, e in merged((s, e) for _, s, e
-                                        in _clip(tr.ops, lo, hi))) \
-        / tr.devices
+                                        in _clip(tr.ops, lo, hi)))
 
 
-def idle_gaps(tr: Trace) -> List[Tuple[float, float]]:
-    """The device's idle intervals inside the window."""
+def busy(tr: Trace) -> float:
+    """Seconds in which some operation ran on a device inside the window,
+    each device's own, averaged over the devices."""
+    return sum(_busy(tr.on(d)) for d in range(tr.devices)) / tr.devices
+
+
+def _idle_gaps(tr: Trace) -> List[Tuple[float, float]]:
     lo, hi = tr.window()
     out, cur = [], lo
     for s, e in merged((s, e) for _, s, e in _clip(tr.ops, lo, hi)):
@@ -176,11 +199,18 @@ def idle_gaps(tr: Trace) -> List[Tuple[float, float]]:
     return out
 
 
+def idle_gaps(tr: Trace) -> List[Tuple[float, float]]:
+    """Each device's idle intervals inside the window, device by
+    device."""
+    return [g for d in range(tr.devices) for g in _idle_gaps(tr.on(d))]
+
+
 def attribute(tr: Trace, gaps: Sequence[Tuple[float, float]]
               ) -> Dict[str, float]:
-    """Idle seconds by what the host was doing: each stretch of a gap goes
-    to the innermost benchmark span around it (``run`` only where no
-    narrower span covers it), else to ``untraced``."""
+    """Idle seconds by what the host was doing, averaged over the devices
+    (``gaps`` holds every device's): each stretch of a gap goes to the
+    innermost benchmark span around it (``run`` only where no narrower
+    span covers it), else to ``untraced``."""
     out: Dict[str, float] = {}
     spans = sorted(tr.spans, key=lambda x: x[1])
     for gs, ge in gaps:
@@ -195,7 +225,7 @@ def attribute(tr: Trace, gaps: Sequence[Tuple[float, float]]
         if rest > 0:
             key = "run" if any(c[0] == "run" for c in cover) else "untraced"
             out[key] = out.get(key, 0.0) + rest
-    return out
+    return {k: v / tr.devices for k, v in out.items()}
 
 
 def op_name(text: str) -> str:
@@ -204,28 +234,45 @@ def op_name(text: str) -> str:
     return text.split(" = ", 1)[0].lstrip("%")
 
 
-def self_seconds(tr: Trace) -> Dict[str, float]:
-    """Device seconds by operation inside the window, over the devices,
-    each counted without the operations nested in it (a ``while`` holds
-    its body's operations)."""
-    lo, hi = tr.window()
-    ops = sorted(_clip(tr.ops, lo, hi), key=lambda o: (o[1], -o[2]))
-    out: Dict[str, float] = {}
+def own_seconds(ops) -> List[Tuple[str, float]]:
+    """(operation, seconds) of one device's ``ops`` (name, start, end), each
+    without the operations nested in it (a ``while`` holds its body's
+    operations): the time in which it was the innermost operation."""
+    out: List[Tuple[str, float]] = []
     stack: List[List] = []        # [name, end, self seconds]
-
-    def pop():
-        n, _, own = stack.pop()
-        out[op_name(n)] = out.get(op_name(n), 0.0) + own / tr.devices
-
-    for n, s, e in ops:
+    for n, s, e in sorted(ops, key=lambda o: (o[1], -o[2])):
         while stack and stack[-1][1] <= s:
-            pop()
+            out.append((stack[-1][0], stack.pop()[2]))
         if stack:
             stack[-1][2] -= min(e, stack[-1][1]) - s
         stack.append([n, e, e - s])
     while stack:
-        pop()
+        out.append((stack[-1][0], stack.pop()[2]))
     return out
+
+
+def self_seconds(tr: Trace) -> Dict[str, float]:
+    """Device seconds by operation inside the window, averaged over the
+    devices, each counted without the operations nested in it."""
+    lo, hi = tr.window()
+    out: Dict[str, float] = {}
+    for d in range(tr.devices):
+        for n, own in own_seconds(_clip(tr.on(d).ops, lo, hi)):
+            out[op_name(n)] = out.get(op_name(n), 0.0) + own / tr.devices
+    return out
+
+
+def collective_seconds(tr: Trace, program: str) -> Tuple[float, float]:
+    """(exposed collective seconds, device seconds) of one serving program
+    inside the window, on a single device's trace (``Trace.on``): the time
+    in which a collective was the innermost operation, with nothing else
+    running on the device, over the program's executions."""
+    exposed = total = 0.0
+    for s, e in program_runs(tr, program):
+        total += e - s
+        exposed += sum(own for n, own in own_seconds(_clip(tr.ops, s, e))
+                       if COLLECTIVE.search(n))
+    return exposed, total
 
 
 def kernel_seconds(tr: Trace, kernel: str) -> float:

@@ -64,17 +64,35 @@ def warm(eng, shapes: Dict[str, List[int]], max_context: int,
     counts["run_s"] = round(time.perf_counter() - t, 3)
     t = time.perf_counter()
 
-    state = eng.lm.init_decode_state(nslots, cfg.max_seq,
-                                     **eng._state_kwargs())
-    logits = jnp.zeros((nslots, vocab), lm.dtype)
+    def fresh():
+        """The state and logits as ``run()`` makes them (on a mesh the
+        pool sharded, the logits replicated)."""
+        return (eng.shard_state(eng.lm.init_decode_state(
+            nslots, cfg.max_seq, **eng._state_kwargs())),
+            eng.replicate(jnp.zeros((nslots, vocab), lm.dtype)))
+
     table = np.zeros((nslots, eng.table_width), np.int32)
     need = pages_for(cfg.max_seq, cfg.page_size)
     table[0, :need] = np.arange(1, need + 1)
+    state, logits = fresh()
     state = eng.set_page_table(state, table)
     for n in shapes["plain"]:
         toks = rng_np.integers(1, vocab, size=n).tolist()
-        state, logits = eng.prefill_slot(state, logits, toks, 0,
-                                         table_row=table[0])
+        if eng.mesh is None:
+            state, logits = eng.prefill_slot(state, logits, toks, 0,
+                                             table_row=table[0])
+        else:
+            # on a mesh a program is built anew for every sharding of its
+            # inputs: a run's first admission meets a fresh state, a later
+            # one what a program gave back (in the shardings the compiler
+            # chose), with the page table just set or not
+            del state, logits
+            state, logits = fresh()
+            for set_table in (True, False, True):
+                if set_table:
+                    state = eng.set_page_table(state, table)
+                state, logits = eng.prefill_slot(state, logits, toks, 0,
+                                                 table_row=table[0])
         counts["prefill"] += 1
     for n in shapes["suffix"]:
         state = eng.copy_pages(state, [(1, need + 1)])
@@ -89,13 +107,14 @@ def warm(eng, shapes: Dict[str, List[int]], max_context: int,
     counts["prefill_s"] = round(time.perf_counter() - t, 3)
     t = time.perf_counter()
 
-    rng = jax.random.key(cfg.seed)
+    rng = eng.replicate(jax.random.key(cfg.seed))
     for width in width_buckets(eng, max_context):
         for steps in steps_set(eng):
-            state = eng.set_page_table(state, table[:, :width])
             # every row back to an empty context, so no write runs past
-            # the narrow tables
-            state = eng._with_lengths(state, jnp.zeros(nslots, jnp.int32))
+            # the narrow tables; then the table, as before every segment
+            state = eng._with_lengths(
+                state, eng.replicate(jnp.zeros(nslots, jnp.int32)))
+            state = eng.set_page_table(state, table[:, :width])
             # dispatched without a wait: the next program traces while
             # this one runs
             toks, logits, state, rng = eng.decode_segment(steps)(

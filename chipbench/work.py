@@ -1,11 +1,15 @@
 """Operations and bytes the algorithm needs, from the configuration's
-shapes, and the chips' peaks.
+shapes and its family's counts, and the chips' peaks.
 
 Model FLOPs count every multiply-add as two operations: the layers'
-matrix products, attention over exactly the causal context, and the head
-where the program computes it (one position per prefill, one per decoded
-token).  Kernel bytes are the least a kernel must move through HBM: what
-it reads once and writes once, in bf16.
+matrix products (the family's ``layer_matmul_params``), attention over
+exactly the causal context, and the head where the program computes it
+(one position per prefill, one per decoded token).  Kernel bytes are the
+least a kernel must move through HBM: what it reads once and writes
+once, in bf16, K and V at the family's ``kv_bytes_per_token``.
+
+Every count is of the whole model.  On a mesh each chip does its share:
+a reader compares the count over the chips with one chip's peak.
 """
 
 from __future__ import annotations
@@ -37,13 +41,6 @@ def dims(cfg: Dict) -> Dict[str, int]:
             "layers": cfg["num_hidden_layers"]}
 
 
-def layer_matmul_params(cfg: Dict) -> int:
-    """Weights one token multiplies through in one layer."""
-    m = dims(cfg)
-    attn = m["d"] * m["dh"] * (2 * m["h"] + 2 * m["kvh"])
-    return attn + 3 * m["d"] * m["f"]
-
-
 def attention_flops(cfg: Dict, contexts: Sequence[int]) -> float:
     """QK^T and PV for one query per entry of ``contexts`` (the keys it
     sees, itself included), over every layer and head."""
@@ -51,36 +48,37 @@ def attention_flops(cfg: Dict, contexts: Sequence[int]) -> float:
     return 4.0 * m["layers"] * m["h"] * m["dh"] * float(sum(contexts))
 
 
-def prefill_flops(cfg: Dict, tokens: int, prefix: int) -> float:
+def prefill_flops(fam, cfg: Dict, tokens: int, prefix: int) -> float:
     """One slot prefill of ``tokens`` new tokens after ``prefix`` resident
     ones: the layers for every new token, causal attention, and the head
     for the last position."""
     m = dims(cfg)
     ctx = prefix * tokens + tokens * (tokens + 1) / 2
-    return (2.0 * m["layers"] * layer_matmul_params(cfg) * tokens
+    return (2.0 * m["layers"] * fam.layer_matmul_params(cfg) * tokens
             + 4.0 * m["layers"] * m["h"] * m["dh"] * ctx
             + 2.0 * m["d"] * m["v"])
 
 
-def decode_flops(cfg: Dict, contexts: Sequence[int]) -> float:
+def decode_flops(fam, cfg: Dict, contexts: Sequence[int]) -> float:
     """One decode step of rows whose new token sees ``contexts`` keys
     each: layers, attention and head per row."""
     m = dims(cfg)
-    per_row = 2.0 * (m["layers"] * layer_matmul_params(cfg) + m["d"] * m["v"])
+    per_row = 2.0 * (m["layers"] * fam.layer_matmul_params(cfg)
+                     + m["d"] * m["v"])
     return per_row * len(contexts) + attention_flops(cfg, contexts)
 
 
-def paged_decode_work(cfg: Dict, contexts: Sequence[int]):
+def paged_decode_work(fam, cfg: Dict, contexts: Sequence[int]):
     """(FLOPs, bytes) of the paged decode kernel over every layer, for one
     step of rows with ``contexts`` resident keys each: it reads each
     row's K and V once, its queries and the new token's K and V, and
     writes its output."""
     m = dims(cfg)
     n, ctx = len(contexts), float(sum(contexts))
-    kv = 2.0 * ctx * m["kvh"] * m["dh"] * BF16
-    qo = 2.0 * n * m["h"] * m["dh"] * BF16 + 2.0 * n * m["kvh"] * m["dh"] * BF16
+    kv = float(fam.kv_bytes_per_token(cfg)) * (ctx + n)
+    qo = 2.0 * n * m["h"] * m["dh"] * BF16
     flops = 4.0 * m["h"] * m["dh"] * (ctx + n)
-    return m["layers"] * flops, m["layers"] * (kv + qo)
+    return m["layers"] * flops, kv + m["layers"] * qo
 
 
 def flash_prefill_work(cfg: Dict, tokens: int):

@@ -1,6 +1,8 @@
 """BENCHMARK.json as the benchmark reads it: every name resolves to a file
-of its own, every configuration file is the program's configuration at
-its published widths, and every name and unit keeps to its alphabet."""
+of its own, every configuration file names a family whose code finds the
+program's configuration at its published widths, every cell's chips are
+its configuration's mesh, and every name and unit keeps to its
+alphabet."""
 
 import json
 import os
@@ -13,7 +15,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
-from chipbench import model, traffic  # noqa: E402
+from chipbench import run, traffic  # noqa: E402
 from chipbench.lookup import Lookup  # noqa: E402
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -55,13 +57,14 @@ def test_every_metric_has_a_reader(lk, m):
 @pytest.mark.parametrize("w", BENCH["workloads"], ids=CELLS)
 def test_every_cell_resolves(lk, w):
     assert NAME.match(w["name"]) and len(w["why"]) <= 200
-    assert w["chips"] == 1
+    assert w["chips"] in (1, 4)
     cfg, mix, cell = lk.config(w["config"]), lk.mix(w["traffic"]), \
         lk.cell(w["name"])
     assert cell["rate"] > 0 and cell["check"]["widest_logit_gap"] > 0
     assert cell.get("lead_in_s", 0.0) >= 0
     assert traffic.max_context(mix) + 8 <= cfg["serve"]["max_seq"]
     assert int(mix["output"]["max"]) <= cfg["serve"]["max_seq"]
+    assert run.mesh_size(cfg) == w["chips"]
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -75,7 +78,8 @@ def test_config_files_are_the_programs_configs(lk, name):
             assert sorted(c["reduced"]) == sorted(cfg["reduced"])
             assert cfg["source"] == c["source"]
     assert {c["name"] for c in BENCH["configs"]} <= set(CONFIGS)
-    lc = model.lm_config(cfg)          # raises on any width that differs
+    # the family's code raises on any width that differs
+    lc = lk.family(cfg["family"]).lm_config(cfg)
     assert lc.n_layers == cfg["num_hidden_layers"]
     for key, published in cfg["reduced"].items():
         assert cfg[key] < published       # a cut, never a widening
@@ -84,4 +88,9 @@ def test_config_files_are_the_programs_configs(lk, name):
 def test_a_width_that_differs_is_refused(lk):
     cfg = dict(lk.config("qwen2-0.5b"), intermediate_size=4096)
     with pytest.raises(ValueError):
-        model.lm_config(cfg)
+        lk.family("qwen2").lm_config(cfg)
+
+
+def test_at_most_half_the_cells_take_four_chips():
+    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(CELLS) // 2)

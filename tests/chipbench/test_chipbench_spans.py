@@ -16,7 +16,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
-from chipbench import model, run, warmup, weights  # noqa: E402
+from chipbench import run, warmup  # noqa: E402
 from chipbench import spans as sp  # noqa: E402
 from chipbench import trace as tr  # noqa: E402
 from chipbench.lookup import HERE as CB, Lookup  # noqa: E402
@@ -26,12 +26,13 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 DATA = os.path.join(HERE, "data")
 BENCH = os.path.join(DATA, "BENCHMARK.json")
 CPU_TRACE = os.path.join(HERE, "cpu_trace.xplane.pb")
+FAM = Lookup([DATA, CB], BENCH).family("qwen2")
 
 
 @pytest.fixture(scope="module")
 def tiny():
     cfg = Lookup([DATA], BENCH).config("tiny")
-    return cfg, weights.make(cfg, 5)
+    return cfg, FAM.make_weights(cfg, 5)
 
 
 class Hook:
@@ -69,7 +70,7 @@ def serve(eng, chaos=None):
 def traced(tiny, tmp_path_factory):
     """One scheduler run and one ``generate()`` under the profiler, on a
     fresh engine: (engine, scheduler, the program's spans and ops)."""
-    eng = model.engine(*tiny)
+    eng = FAM.engine(*tiny)
     out = str(tmp_path_factory.mktemp("trace"))
     jax.profiler.start_trace(out)
     try:
@@ -116,7 +117,7 @@ def test_builds_are_counted_and_spanned(traced):
 
 def test_tokens_are_the_same_without_the_profiler(tiny, traced):
     _eng, sched, _prog = traced
-    again = serve(model.engine(*tiny))
+    again = serve(FAM.engine(*tiny))
     assert again.metrics["programs_built"] == sched.metrics["programs_built"]
     assert {r: q.generated for r, q in again.completed.items()} == \
         {r: q.generated for r, q in sched.completed.items()}
@@ -125,7 +126,7 @@ def test_tokens_are_the_same_without_the_profiler(tiny, traced):
 def test_build_counter_reads_zero_after_warm_up_and_one_after_a_new_length(
         tiny):
     cfg, _params = tiny
-    eng = model.engine(*tiny)
+    eng = FAM.engine(*tiny)
     warmup.warm(eng, {"plain": [32, 48], "suffix": []}, 64, seed=1)
     rng = np.random.default_rng(0)
     sched = BatchScheduler(eng)
@@ -213,9 +214,10 @@ def hand_program():
                  f"{base}/mlp/dot_general")]
     ops.append(("%scatter.2 = scatter(p)", 4.5, 5.0,
                 "jit(_paged_slot_prefill_impl)/layers/attention/kv_cache"))
-    t = tr.Trace(ops=[o[:3] for o in ops],
-                 modules=[("jit_seg(1)", 1.0, 4.0), ("jit_seg(1)", 6.0, 9.0),
-                          ("jit__paged_slot_prefill_impl", 4.5, 5.0)],
+    t = tr.Trace(ops=[o[:3] + (0,) for o in ops],
+                 modules=[("jit_seg(1)", 1.0, 4.0, 0),
+                          ("jit_seg(1)", 6.0, 9.0, 0),
+                          ("jit__paged_slot_prefill_impl", 4.5, 5.0, 0)],
                  spans=[("trace_open", 0.0, 0.0), ("trace_close", 10.0,
                                                     10.0)], devices=1)
     spans = [("serve.run", 0.0, 10.0, {}),
@@ -265,8 +267,8 @@ def test_recorded_cpu_trace_loads_the_same_operations():
     a program without spans or scopes reads nothing."""
     t = tr.load(CPU_TRACE)
     prog = sp.load(CPU_TRACE)
-    assert sorted(o[:3] for o in prog.ops) == sorted(t.ops)
-    again = tr.Trace(ops=[o[:3] for o in prog.ops], modules=t.modules,
+    assert sorted(o[:3] + (0,) for o in prog.ops) == sorted(t.ops)
+    again = tr.Trace(ops=[o[:3] + (0,) for o in prog.ops], modules=t.modules,
                      spans=t.spans, devices=t.devices)
     assert tr.busy(again) == tr.busy(t)
     assert tr.self_seconds(again) == tr.self_seconds(t)

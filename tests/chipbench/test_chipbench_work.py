@@ -17,6 +17,8 @@ from chipbench import stats, work  # noqa: E402
 from chipbench.lookup import Lookup  # noqa: E402
 from chipbench.window import Rec  # noqa: E402
 
+FAM = Lookup().family("qwen2")
+
 
 def cfg(name):
     with open(os.path.join(ROOT, "chipbench", "configs", name + ".json")) as f:
@@ -27,21 +29,26 @@ def cfg(name):
 @pytest.mark.parametrize("name,per_layer,head", [
     ("qwen2-0.5b", 896 * 64 * (28 + 4) + 3 * 896 * 4864, 896 * 151936),
     ("qwen2-vl-7b-14l", 3584 * 128 * (56 + 8) + 3 * 3584 * 18944,
+     3584 * 152064),
+    ("qwen2-vl-7b", 3584 * 128 * (56 + 8) + 3 * 3584 * 18944,
      3584 * 152064)])
 def test_layer_params_and_decode_flops(name, per_layer, head):
     c = cfg(name)
-    assert work.layer_matmul_params(c) == per_layer
+    assert FAM.layer_matmul_params(c) == per_layer
     n = c["num_hidden_layers"]
     h, dh = c["num_attention_heads"], 128 if "vl" in name else 64
     want = 2 * (n * per_layer + head) + 4 * n * h * dh * 1000
-    assert work.decode_flops(c, [1000]) == want
-    assert work.decode_flops(c, [1000, 1000]) == 2 * want
+    assert work.decode_flops(FAM, c, [1000]) == want
+    assert work.decode_flops(FAM, c, [1000, 1000]) == 2 * want
 
 
 def test_published_sizes():
-    assert work.layer_matmul_params(cfg("qwen2-0.5b")) == 14_909_440
+    assert FAM.layer_matmul_params(cfg("qwen2-0.5b")) == 14_909_440
     # 233.05M in a qwen2-vl-7b layer (its biases and norms aside)
-    assert work.layer_matmul_params(cfg("qwen2-vl-7b-14l")) == 233_046_016
+    assert FAM.layer_matmul_params(cfg("qwen2-vl-7b")) == 233_046_016
+    # K and V of a token: 24 layers x 2 KV heads x 64; 28 x 4 x 128
+    assert FAM.kv_bytes_per_token(cfg("qwen2-0.5b")) == 12_288
+    assert FAM.kv_bytes_per_token(cfg("qwen2-vl-7b")) == 4 * 14_336
 
 
 def test_prefill_flops():
@@ -50,12 +57,12 @@ def test_prefill_flops():
     ctx = p * n + n * (n + 1) / 2
     want = (2 * 24 * 14_909_440 * n + 4 * 24 * 14 * 64 * ctx
             + 2 * 896 * 151936)
-    assert work.prefill_flops(c, n, p) == pytest.approx(want)
+    assert work.prefill_flops(FAM, c, n, p) == pytest.approx(want)
 
 
 def test_paged_decode_work():
     c = cfg("qwen2-0.5b")
-    flops, byts = work.paged_decode_work(c, np.array([100, 300]))
+    flops, byts = work.paged_decode_work(FAM, c, np.array([100, 300]))
     # per layer: K and V of 400 tokens, 2 heads of 64, bf16; q and out of
     # 14 heads for 2 rows; the new token's K and V for 2 rows
     kv = 2 * 400 * 2 * 64 * 2
@@ -154,7 +161,8 @@ def test_per_layer_host_readers_on_hand_records(lk):
     assert lk.reader("compiles_in_window")(r) == 0
     for name in ("device_idle_share", "segment_gap_ms", "decode_step_ms",
                  "decode_step_mfu", "prefill_mfu", "prefill_ms_per_ktok",
-                 "paged_decode_roofline", "flash_prefill_roofline"):
+                 "paged_decode_roofline", "flash_prefill_roofline",
+                 "collective_exposed_share"):
         assert lk.reader(name)(r) is None      # nothing traced: no number
 
 
