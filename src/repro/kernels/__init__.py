@@ -12,6 +12,8 @@ paged_decode.py           decode attention over the serve/kv_pool pages
 ssd_scan.py               mLSTM / Mamba2 chunked gated linear attention
 sampling.py               greedy/top-k/top-p token sampling (blockwise
                           argmax reduction + seeded gumbel PRNG contract)
+grouped_matmul.py         MoE expert FFNs over rows sorted by expert
+                          (megablox's Pallas grouped matmul)
 ========================  ===================================================
 
 ops.py holds the jit'd layout adapters; ref.py the pure-jnp oracles every
@@ -21,7 +23,7 @@ tests/test_chip_compile.py compiles them for a described TPU v5e).
 registry.py is the ONE entry point over all of them: every implementation
 is a declarative ``KernelSpec`` registered into a family (``attention``,
 ``paged_decode``, ``stream_triad``, ``jacobi7``, ``ssd_scan``,
-``sampling``) with a
+``sampling``, ``grouped_matmul``) with a
 static capability predicate, layout contract, oracle link and tune
 space; ``registry.select/run`` dispatch through a single per-family
 override ladder (``use_impl`` context > ``REPRO_IMPL`` env > legacy
@@ -33,5 +35,5 @@ docstring); dispatch.py and autotune.py are two-line re-export stubs
 over it.
 """
 
-from repro.kernels import (dispatch, legacy, ops, ref, registry,  # noqa: F401
-                           sampling)
+from repro.kernels import (dispatch, grouped_matmul, legacy,  # noqa: F401
+                           ops, ref, registry, sampling)

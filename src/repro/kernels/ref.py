@@ -6,6 +6,7 @@ Each function is the semantic contract of its kernel twin:
 * :func:`jacobi7_valid`     <- kernels/jacobi7.py (T valid-mode sweeps)
 * :func:`flash_attention`   <- kernels/flash_attention.py (causal GQA)
 * :func:`ssd_scan`          <- kernels/ssd_scan.py (gated linear attention)
+* :func:`grouped_matmul`    <- kernels/grouped_matmul.py (MoE expert FFNs)
 
 All are deliberately naive/obvious implementations — correctness over
 speed; tests sweep shapes/dtypes and assert_allclose kernels against these.
@@ -22,7 +23,8 @@ import numpy as np
 from repro.models.linear_scan import sequential_linear_attention
 
 __all__ = ["stream_triad", "jacobi7_sweep", "jacobi7_valid",
-           "flash_attention", "paged_decode", "paged_decode_q8", "ssd_scan"]
+           "flash_attention", "paged_decode", "paged_decode_q8", "ssd_scan",
+           "grouped_matmul"]
 
 
 def stream_triad(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray,
@@ -152,3 +154,17 @@ def ssd_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return sequential_linear_attention(q, k, v, log_f, log_i,
                                        normalize=normalize,
                                        initial_state=initial_state)
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
+                   group_sizes) -> jnp.ndarray:
+    """Row r of group g (the ``group_sizes[g]`` rows after group g-1) times
+    ``rhs[g]``, in float32; rows past the groups are 0."""
+    sizes = np.asarray(group_sizes)
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32)
+    start = 0
+    for g, n in enumerate(sizes):
+        rows = lhs[start:start + n].astype(jnp.float32)
+        out = out.at[start:start + n].set(rows @ rhs[g].astype(jnp.float32))
+        start += n
+    return out

@@ -41,6 +41,7 @@ Registered families (see :func:`describe` for the live table)::
     stream_triad  pallas_triad | xla_triad             tune: (block_rows,)
     jacobi7       wavefront | naive                    tune: (block_x,)
     ssd_scan      pallas_ssd | jnp_scan                tune: (chunk,)
+    grouped_matmul pallas_gmm | jnp_gmm               (MoE expert FFNs)
 
 ``repro.kernels.legacy`` is the one deprecation shim over this module
 (``dispatch``/``autotune`` re-export it); the migration table lives in
@@ -1749,7 +1750,9 @@ def _run_jnp_ssd(q, k, v, log_f, log_i, *, chunk: Optional[int] = None,
 
 
 # ===========================================================================
-# family: sampling (greedy / top-k / top-p) — registered by its own module
+# families registered by their own modules: sampling (greedy / top-k /
+# top-p) and grouped_matmul (MoE expert FFNs)
 # ===========================================================================
 
 from repro.kernels import sampling  # noqa: E402,F401  (registration side-effect)
+from repro.kernels import grouped_matmul  # noqa: E402,F401

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.layers import (Params, Specs, apply_mrope, apply_rope,
-                                 dense_init, truncated_normal_init)
+                                 dense_init, rms_norm, truncated_normal_init)
 
 __all__ = ["AttnConfig", "init_attn", "attn_specs", "attention",
            "KVCache", "init_kv_cache", "decode_attention",
@@ -40,6 +40,11 @@ class AttnConfig(NamedTuple):
     num_kv_heads: int
     head_dim: int
     qkv_bias: bool = False
+    # Qwen3: an RMSNorm over head_dim, with learned scales, on every query
+    # and key head after the projection and before RoPE (so before K is
+    # cached)
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
     causal: bool = True
     use_rope: bool = True
     rope_theta: float = 10000.0
@@ -72,6 +77,9 @@ def init_attn(key, cfg: AttnConfig, dtype=jnp.float32) -> Params:
         p["bq"] = jnp.zeros((h, dh), dtype)
         p["bk"] = jnp.zeros((kvh, dh), dtype)
         p["bv"] = jnp.zeros((kvh, dh), dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = {"scale": jnp.ones((dh,), jnp.float32)}
+        p["k_norm"] = {"scale": jnp.ones((dh,), jnp.float32)}
     return p
 
 
@@ -86,6 +94,9 @@ def attn_specs(cfg: AttnConfig) -> Specs:
         s["bq"] = ("heads", "head_dim")
         s["bk"] = ("kv_heads", "head_dim")
         s["bv"] = ("kv_heads", "head_dim")
+    if cfg.qk_norm:
+        s["q_norm"] = {"scale": ("head_dim",)}
+        s["k_norm"] = {"scale": ("head_dim",)}
     return s
 
 
@@ -103,6 +114,9 @@ def _project_qkv(p: Params, x: jnp.ndarray, cfg: AttnConfig,
         q = q + p["bq"].astype(x.dtype)
         k = k + p["bk"].astype(x.dtype)
         v = v + p["bv"].astype(x.dtype)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if not cfg.use_rope:
         return q, k, v
     if cfg.mrope_sections is not None and positions3 is not None:

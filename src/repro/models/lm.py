@@ -47,14 +47,21 @@ from repro.models.layers import (DEFAULT_RULES, Params, ShardingRules, Specs,
                                  rms_norm, rmsnorm_init, layer_norm,
                                  layernorm_init, spec_tree_to_pspecs,
                                  truncated_normal_init)
-from repro.models.moe import MoEConfig, count_active_params
+from repro.models.moe import COUNTS, MoEConfig, count_active_params
 from repro.models.ssm import Mamba2Config
 from repro.models.transformer import BlockConfig
 from repro.models.xlstm import XLSTMConfig
 
-__all__ = ["LMConfig", "LM", "Batch"]
+__all__ = ["LMConfig", "LM", "Batch", "MOE_COUNTS", "MOE_KINDS"]
 
 Batch = Dict[str, jnp.ndarray]
+
+#: an MoE model's decode state carries its layers' counts (``moe.COUNTS``,
+#: summed over the layers) under this key, int32 [kind, count]: one row
+#: for :meth:`LM.decode_step`, one for :meth:`LM.prefill`, each growing by
+#: what the call routed (and wrapping past 2**31)
+MOE_COUNTS = "moe_counts"
+MOE_KINDS = ("decode", "prefill")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,6 +76,7 @@ class LMConfig:
     d_ff: int
     head_dim: int = 0            # 0 -> d_model // num_heads
     qkv_bias: bool = False
+    qk_norm: bool = False        # per-head RMSNorm on q and k (Qwen3)
     norm: str = "rmsnorm"
     tie_embeddings: bool = False
     rope_theta: float = 1e6
@@ -78,6 +86,10 @@ class LMConfig:
     moe_top_k: int = 0
     moe_shared_experts: int = 0
     moe_d_ff_shared: int = 0
+    # the routed experts this model holds (one chip's share under expert
+    # parallelism): moe_first_expert .. + moe_held_experts - 1; 0 = all
+    moe_first_expert: int = 0
+    moe_held_experts: int = 0
     # --- vlm ---
     mrope_sections: Tuple[int, int, int] = ()
     n_patches: int = 0           # patch positions at sequence start (stub)
@@ -103,7 +115,8 @@ class LMConfig:
         return AttnConfig(
             d_model=self.d_model, num_heads=self.num_heads,
             num_kv_heads=self.num_kv_heads, head_dim=self.resolved_head_dim,
-            qkv_bias=self.qkv_bias, causal=causal,
+            qkv_bias=self.qkv_bias, qk_norm=self.qk_norm,
+            norm_eps=self.norm_eps, causal=causal,
             rope_theta=self.rope_theta,
             mrope_sections=self.mrope_sections or None,
             chunk_size=self.chunk_size,
@@ -115,7 +128,9 @@ class LMConfig:
             d_model=self.d_model, d_ff_expert=self.d_ff,
             num_experts=self.moe_experts, top_k=self.moe_top_k,
             num_shared_experts=self.moe_shared_experts,
-            d_ff_shared=self.moe_d_ff_shared)
+            d_ff_shared=self.moe_d_ff_shared,
+            first_expert=self.moe_first_expert,
+            held_experts=self.moe_held_experts)
 
     def block_config(self) -> BlockConfig:
         return BlockConfig(
@@ -421,7 +436,11 @@ class LM:
             else:
                 cache = attn_mod.init_kv_cache(batch_size, max_seq, ac,
                                                self.dtype)
-            return {"caches": _stack_tree(cache, cfg.n_layers)}
+            state = {"caches": _stack_tree(cache, cfg.n_layers)}
+            if fam == "moe":
+                state[MOE_COUNTS] = jnp.zeros((len(MOE_KINDS), len(COUNTS)),
+                                              jnp.int32)
+            return state
         if fam == "xlstm":
             xc = cfg.xlstm_config()
             n_pairs = cfg.n_layers // 2
@@ -496,13 +515,14 @@ class LM:
         if fam in ("dense", "moe", "vlm"):
             bc = cfg.block_config()
             pos3 = (self._vlm_positions3(tokens) if fam == "vlm" else None)
-            x, new_caches = tf_mod.apply_stack_decode(
+            x, new_caches, counts = tf_mod.apply_stack_decode(
                 p["blocks"], x, bc, state["caches"], feats,
                 rules=self.rules, mesh=self.mesh, positions3=pos3,
                 block_fn=functools.partial(tf_mod.apply_block_prefill,
                                            lengths=lengths,
                                            prefix_len=prefix_len))
-            new_state = {"caches": new_caches}
+            new_state = _counted(dict(state, caches=new_caches), "prefill",
+                                 counts)
         elif fam == "xlstm":
             xc = cfg.xlstm_config()
 
@@ -555,7 +575,7 @@ class LM:
                                                 feats)
             new_mamba.append(seg_new)
             cache_g = jax.tree.map(lambda a: a[gi], state["attn_caches"])
-            x, new_c = tf_mod.apply_block_prefill(
+            x, new_c, _ = tf_mod.apply_block_prefill(
                 p["shared_attn"], x, bc, KVCache(*cache_g)
                 if not isinstance(cache_g, KVCache) else cache_g,
                 rules=self.rules, mesh=self.mesh)
@@ -618,10 +638,11 @@ class LM:
         fam = cfg.family
         if fam in ("dense", "moe", "vlm"):
             bc = cfg.block_config()
-            x, new_caches = tf_mod.apply_stack_decode(
+            x, new_caches, counts = tf_mod.apply_stack_decode(
                 p["blocks"], x, bc, state["caches"], feats,
                 rules=self.rules, mesh=self.mesh)
-            new_state = {"caches": new_caches}
+            new_state = _counted(dict(state, caches=new_caches), "decode",
+                                 counts)
         elif fam == "xlstm":
             xc = cfg.xlstm_config()
 
@@ -652,7 +673,7 @@ class LM:
                 new_mamba.append(seg_new)
                 cache_g = jax.tree.map(lambda a: a[gi], state["attn_caches"])
                 cache_g = KVCache(*cache_g) if not isinstance(cache_g, KVCache) else cache_g
-                x, new_c = tf_mod.apply_block_decode(
+                x, new_c, _ = tf_mod.apply_block_decode(
                     p["shared_attn"], x, bc, cache_g,
                     rules=self.rules, mesh=self.mesh)
                 new_attn.append(new_c)
@@ -692,7 +713,7 @@ class LM:
         if cfg.family != "moe":
             return n
         mc = cfg.moe_config()
-        per_layer_all = (3 * cfg.d_model * cfg.d_ff * cfg.moe_experts
+        per_layer_all = (3 * cfg.d_model * cfg.d_ff * mc.held
                          + cfg.d_model * cfg.moe_experts)
         n_dense = n - cfg.n_layers * per_layer_all
         return n_dense + cfg.n_layers * count_active_params(mc)
@@ -701,6 +722,15 @@ class LM:
 # ---------------------------------------------------------------------------
 # scan helpers
 # ---------------------------------------------------------------------------
+
+def _counted(state, kind: str, counts):
+    """``state`` with ``counts`` (None for a dense MLP) added to its
+    ``kind`` row of :data:`MOE_COUNTS`, where it has one."""
+    if counts is None or MOE_COUNTS not in state:
+        return state
+    row = MOE_KINDS.index(kind)
+    return dict(state, **{MOE_COUNTS: state[MOE_COUNTS].at[row].add(counts)})
+
 
 def _stack_tree(tree, n: int):
     return jax.tree.map(lambda a: jnp.broadcast_to(a, (n,) + a.shape), tree)

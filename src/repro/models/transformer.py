@@ -26,7 +26,8 @@ from repro.models.layers import (DEFAULT_RULES, Params, ShardingRules, Specs,
                                  constrain, dense_init, layer_norm,
                                  layernorm_init, rms_norm, rmsnorm_init,
                                  swiglu, truncated_normal_init)
-from repro.models.moe import MoEConfig, init_moe, moe_mlp, moe_specs
+from repro.models.moe import (MoEConfig, init_moe, moe_mlp,
+                              moe_mlp_capacity, moe_specs)
 
 __all__ = ["BlockConfig", "init_block", "block_specs", "apply_block",
            "init_stacked", "stacked_specs", "apply_stack",
@@ -103,8 +104,8 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: BlockConfig, *,
     aux = jnp.zeros((), jnp.float32)
     if cfg.mlp == "moe":
         cst = (lambda a, axes: constrain(a, axes, rules, mesh, soft=True))
-        m, aux = moe_mlp(p["moe"], _norm(h, p["ln2"], cfg), cfg.moe,
-                         constrain_fn=cst)
+        m, aux = moe_mlp_capacity(p["moe"], _norm(h, p["ln2"], cfg),
+                                  cfg.moe, constrain_fn=cst)
     else:
         mp = p["mlp"]
         m = swiglu(_norm(h, p["ln2"], cfg), mp["w_gate"].astype(x.dtype),
@@ -115,31 +116,34 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: BlockConfig, *,
 
 
 def _block_mlp(p: Params, h: jnp.ndarray, cfg: BlockConfig,
-               rules, mesh) -> jnp.ndarray:
+               valid=None) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """The post-attention MLP half of a block (aux loss dropped — the
-    decode/prefill paths never train), under the ``mlp`` scope."""
+    decode/prefill paths never train), under the ``mlp`` scope.  Returns
+    (out, the MoE layer's counts — None for a dense MLP).  ``valid``
+    [B,S] marks the tokens an MoE layer routes."""
     with jax.named_scope("mlp"):
         if cfg.mlp == "moe":
-            cst = (lambda a, axes: constrain(a, axes, rules, mesh,
-                                             soft=True))
-            m, _ = moe_mlp(p["moe"], _norm(h, p["ln2"], cfg), cfg.moe,
-                           constrain_fn=cst)
-            return m
+            m, _, counts = moe_mlp(p["moe"], _norm(h, p["ln2"], cfg),
+                                   cfg.moe, valid=valid)
+            return m, counts
         mp = p["mlp"]
         return swiglu(_norm(h, p["ln2"], cfg), mp["w_gate"].astype(h.dtype),
                       mp["w_up"].astype(h.dtype),
-                      mp["w_down"].astype(h.dtype))
+                      mp["w_down"].astype(h.dtype)), None
 
 
 def apply_block_decode(p: Params, x: jnp.ndarray, cfg: BlockConfig,
                        cache: KVCache, *, rules=DEFAULT_RULES, mesh=None,
-                       positions3=None) -> Tuple[jnp.ndarray, KVCache]:
+                       positions3=None):
+    """One-token decode through one block: (y, new cache, MoE counts or
+    None)."""
     with jax.named_scope("attention"):
         a, new_cache = attn_mod.decode_attention(
             p["attn"], _norm(x, p["ln1"], cfg), cfg.attn, cache,
             positions3=positions3)
     h = x + a
-    return h + _block_mlp(p, h, cfg, rules, mesh), new_cache
+    m, counts = _block_mlp(p, h, cfg)
+    return h + m, new_cache, counts
 
 
 def apply_block_prefill(p: Params, x: jnp.ndarray, cfg: BlockConfig,
@@ -147,7 +151,9 @@ def apply_block_prefill(p: Params, x: jnp.ndarray, cfg: BlockConfig,
                         positions3=None, lengths=None, prefix_len=None):
     """Prefill one block; ``cache`` may be dense (:class:`KVCache`) or
     paged (:class:`~repro.models.attention.PagedKVCache`) — the attention
-    compute is identical, only the K/V landing zone differs.
+    compute is identical, only the K/V landing zone differs.  Returns (y,
+    new cache, MoE counts or None); with ``lengths`` an MoE layer routes
+    only each row's real tokens.
 
     ``prefix_len`` [B] (paged only) marks a resident shared prefix: ``x``
     is the divergent suffix, attention spans prefix pages + suffix."""
@@ -166,7 +172,10 @@ def apply_block_prefill(p: Params, x: jnp.ndarray, cfg: BlockConfig,
                 p["attn"], _norm(x, p["ln1"], cfg), cfg.attn, cache,
                 positions3=positions3, lengths=lengths)
     h = x + a
-    return h + _block_mlp(p, h, cfg, rules, mesh), new_cache
+    valid = (None if lengths is None else
+             jnp.arange(x.shape[1])[None, :] < jnp.reshape(lengths, (-1, 1)))
+    m, counts = _block_mlp(p, h, cfg, valid)
+    return h + m, new_cache, counts
 
 
 # ---------------------------------------------------------------------------
@@ -233,9 +242,10 @@ def apply_stack(stacked: Params, x: jnp.ndarray, cfg: BlockConfig,
 def apply_stack_decode(stacked: Params, x: jnp.ndarray, cfg: BlockConfig,
                        caches: KVCache, features: FeatureSet, *,
                        rules=DEFAULT_RULES, mesh=None, positions3=None,
-                       block_fn=apply_block_decode
-                       ) -> Tuple[jnp.ndarray, KVCache]:
+                       block_fn=apply_block_decode):
     """Decode through stacked blocks; caches carry a leading layers axis.
+    Returns (y, new caches, the MoE layers' counts summed over the layers
+    — None for dense MLPs).
 
     The scan path threads the WHOLE stacked cache through the carry and
     writes one token per layer with an in-place dynamic-update-slice (while
@@ -283,39 +293,46 @@ def _apply_stack_decode(stacked, x, cfg, caches, features, *, rules, mesh,
                     layer_p["attn"], _norm(h, layer_p["ln1"], cfg),
                     cfg.attn, k_l, v_l, length, positions3=positions3)
             h2 = h + a
-            y = h2 + _block_mlp(layer_p, h2, cfg, rules, mesh)
+            m, counts = _block_mlp(layer_p, h2, cfg)
             # per-row scatter: row b's token lands at its own length[b]
             with jax.named_scope("kv_cache"):
                 kst = kst.at[i, rows, length].set(
                     k_t[:, 0].astype(kst.dtype))
                 vst = vst.at[i, rows, length].set(
                     v_t[:, 0].astype(vst.dtype))
-            return (y, kst, vst), None
+            return (h2 + m, kst, vst), counts
 
-        (y, kst, vst), _ = jax.lax.scan(
+        (y, kst, vst), counts = jax.lax.scan(
             body, (x, caches.k, caches.v), (jnp.arange(n), stacked))
-        return y, KVCache(k=kst, v=vst, length=caches.length + 1)
+        return (y, KVCache(k=kst, v=vst, length=caches.length + 1),
+                _layer_sum(counts))
 
     def body(h, scanned):
         layer_p, layer_cache = scanned
-        y, new_cache = block_fn(layer_p, h, cfg, layer_cache,
-                                rules=rules, mesh=mesh, positions3=positions3)
-        return y, new_cache
+        y, new_cache, counts = block_fn(layer_p, h, cfg, layer_cache,
+                                        rules=rules, mesh=mesh,
+                                        positions3=positions3)
+        return y, (new_cache, counts)
 
     if features.scan_layers:
-        y, new_caches = jax.lax.scan(body, x, (stacked, caches))
-        return y, new_caches
+        y, (new_caches, counts) = jax.lax.scan(body, x, (stacked, caches))
+        return y, new_caches, _layer_sum(counts)
     n = jax.tree.leaves(stacked)[0].shape[0]
     h = x
     outs = []
     for i in range(n):
         layer_p = jax.tree.map(lambda a: a[i], stacked)
         layer_cache = jax.tree.map(lambda a: a[i], caches)
-        h, nc = block_fn(layer_p, h, cfg, layer_cache, rules=rules,
-                         mesh=mesh, positions3=positions3)
-        outs.append(nc)
-    new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-    return h, new_caches
+        h, out = body(h, (layer_p, layer_cache))
+        outs.append(out)
+    new_caches, counts = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    return h, new_caches, _layer_sum(counts)
+
+
+def _layer_sum(counts):
+    """MoE counts stacked over the layers ([L, 4]) summed; None (dense
+    MLPs) stays None."""
+    return None if counts is None else jnp.sum(counts, axis=0)
 
 
 def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
@@ -346,6 +363,7 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
     with jax.named_scope("kv_cache"):
         page = pt[rows, jnp.minimum(length // ps, np_w - 1)]
         off = length % ps
+    live = (pt[:, :1] != 0)                                  # [B, 1]
     n = jax.tree.leaves(stacked)[0].shape[0]
     quantized = caches.quantized
 
@@ -356,7 +374,8 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
                 k_l, v_l, pt, length, positions3=positions3,
                 k_scale=ksc_l, v_scale=vsc_l)
         h2 = h + a
-        return h2 + _block_mlp(layer_p, h2, cfg, rules, mesh), k_t, v_t
+        m, counts = _block_mlp(layer_p, h2, cfg, live)
+        return h2 + m, k_t, v_t, counts
 
     def layer_slice(pool, i):
         with jax.named_scope("kv_cache"):
@@ -372,15 +391,15 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
         def body(carry, scanned):
             h, kst, vst, ksc, vsc = carry
             i, layer_p = scanned
-            y, k_t, v_t = attend(h, layer_p, layer_slice(kst, i),
-                                 layer_slice(vst, i), layer_slice(ksc, i),
-                                 layer_slice(vsc, i))
+            y, k_t, v_t, counts = attend(
+                h, layer_p, layer_slice(kst, i), layer_slice(vst, i),
+                layer_slice(ksc, i), layer_slice(vsc, i))
             with jax.named_scope("kv_cache"):
                 k_c, k_s = attn_mod.quantize_kv_rows(k_t[:, 0])
                 v_c, v_s = attn_mod.quantize_kv_rows(v_t[:, 0])
             kst, vst = write(kst, i, k_c), write(vst, i, v_c)
             ksc, vsc = write(ksc, i, k_s), write(vsc, i, v_s)
-            return (y, kst, vst, ksc, vsc), None
+            return (y, kst, vst, ksc, vsc), counts
 
         carry0 = (x, caches.k_pages, caches.v_pages,
                   caches.k_scale, caches.v_scale)
@@ -388,25 +407,29 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
         def body(carry, scanned):
             h, kst, vst = carry
             i, layer_p = scanned
-            y, k_t, v_t = attend(h, layer_p, layer_slice(kst, i),
-                                 layer_slice(vst, i))
+            y, k_t, v_t, counts = attend(h, layer_p, layer_slice(kst, i),
+                                         layer_slice(vst, i))
             kst = write(kst, i, k_t[:, 0])
             vst = write(vst, i, v_t[:, 0])
-            return (y, kst, vst), None
+            return (y, kst, vst), counts
 
         carry0 = (x, caches.k_pages, caches.v_pages)
 
     if features.scan_layers:
-        (y, *pools), _ = jax.lax.scan(body, carry0, (jnp.arange(n), stacked))
+        (y, *pools), counts = jax.lax.scan(body, carry0,
+                                           (jnp.arange(n), stacked))
     else:
-        carry = carry0
+        carry, per_layer = carry0, []
         for i in range(n):
             layer_p = jax.tree.map(lambda a: a[i], stacked)
-            carry, _ = body(carry, (jnp.asarray(i), layer_p))
+            carry, c = body(carry, (jnp.asarray(i), layer_p))
+            per_layer.append(c)
         y, *pools = carry
+        counts = jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer)
     kst, vst = pools[0], pools[1]
     ksc, vsc = (pools[2], pools[3]) if quantized else (None, None)
     return y, attn_mod.PagedKVCache(k_pages=kst, v_pages=vst,
                                     page_table=caches.page_table,
                                     length=caches.length + 1,
-                                    k_scale=ksc, v_scale=vsc)
+                                    k_scale=ksc, v_scale=vsc), \
+        _layer_sum(counts)

@@ -33,7 +33,12 @@ Measurement: every boundary of the scheduler and every engine entry
 point opens one host span (:meth:`Engine.span`, names in
 :data:`SERVE_SPANS`) on the profiler's clock, and the decode and prefill
 programs name their parts with ``jax.named_scope`` (``layers``,
-``attention``, ``kv_cache``, ``mlp``, ``head``).  The first dispatch of
+``attention``, ``kv_cache``, ``mlp``, ``head``; an MoE layer's
+``moe_router``, ``moe_experts`` and ``moe_combine`` inside ``mlp``).  An
+MoE model's decode state also counts what its layers routed, on the
+device (``models/lm.py`` ``MOE_COUNTS``); the scheduler fetches the
+counts with each segment's tokens into ``metrics`` (``moe_rows_held.decode``
+and the like).  The first dispatch of
 a program shape the engine has not run before runs under ``serve.build``
 and counts in ``Engine.programs_built`` (``programs_built`` in a
 scheduler's metrics).  ``Engine.instrument`` is LIKWID-style on top:
@@ -66,7 +71,8 @@ from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.models.lm import LM
+from repro.models.lm import LM, MOE_COUNTS, MOE_KINDS
+from repro.models.moe import COUNTS
 
 __all__ = ["ServeConfig", "Engine", "BatchScheduler", "Request",
            "MASKED_FAMILIES", "SERVE_SPANS"]
@@ -753,12 +759,16 @@ class Engine:
 
         Every decode-state leaf is [layers, B, ...]; the row twin is
         [layers, 1, ...] — one dynamic_update_slice along the batch axis
-        per leaf, with the big buffers donated (in-place admission).
+        per leaf, with the big buffers donated (in-place admission).  An
+        MoE model's counts add the row's prefill to the state's.
         """
+        rows = {k: v for k, v in state.items() if k != MOE_COUNTS}
         merged = jax.tree.map(
             lambda big, row: jax.lax.dynamic_update_slice_in_dim(
                 big, row.astype(big.dtype), slot, axis=1),
-            state, row_state)
+            rows, {k: row_state[k] for k in rows})
+        if MOE_COUNTS in state:
+            merged[MOE_COUNTS] = state[MOE_COUNTS] + row_state[MOE_COUNTS]
         logits_buf = jax.lax.dynamic_update_slice_in_dim(
             logits_buf, row_logits.astype(logits_buf.dtype), slot, axis=0)
         return merged, logits_buf
@@ -794,7 +804,7 @@ class Engine:
         if prefix_len is not None:
             batch["prefix_len"] = prefix_len[None]
         row_logits, new_row = self.lm.prefill(params, batch,
-                                              {"caches": row_view})
+                                              dict(state, caches=row_view))
         nc = new_row["caches"]
         new_caches = caches._replace(
             k_pages=nc.k_pages, v_pages=nc.v_pages,
@@ -809,7 +819,7 @@ class Engine:
                 caches.length, nc.length.astype(jnp.int32), slot, axis=1))
         logits_buf = jax.lax.dynamic_update_slice_in_dim(
             logits_buf, row_logits.astype(logits_buf.dtype), slot, axis=0)
-        return dict(state, caches=new_caches), logits_buf
+        return dict(new_row, caches=new_caches), logits_buf
 
     def _copy_pages_impl(self, state, src, dst):
         """Device-side COW page copy: page ``src[i] -> dst[i]`` in every
@@ -1406,6 +1416,14 @@ class BatchScheduler:
             # dispatch at a shape key it had not run before)
             "programs_built": 0,
         }
+        if engine.lm.cfg.family == "moe":
+            # an MoE model's counts (``moe.COUNTS``) over the decode
+            # segments and over the slot prefills, e.g.
+            # ``moe_rows_held.decode``: added up on the device, fetched
+            # with each segment's tokens
+            self.metrics.update({f"{c}.{k}": 0 for c in COUNTS
+                                 for k in MOE_KINDS})
+        self._moe_seen = np.zeros((len(MOE_KINDS), len(COUNTS)), np.int64)
         if engine.spec is not None:
             # speculative decoding telemetry (accept_rate =
             # draft_accepted / draft_proposed over spec rows)
@@ -2041,6 +2059,22 @@ class BatchScheduler:
                 released += 1
         return released
 
+    def _fetch_counted(self, tree, state):
+        """The segment's one sync: ``tree``, and with it an MoE model's
+        counts, of which ``metrics`` gains what they grew by since the
+        last fetch (modulo 2**32: the device's int32 counts wrap)."""
+        counts = state.get(MOE_COUNTS)
+        if counts is None:
+            return self.engine._fetch(tree)
+        got, counts = self.engine._fetch((tree, counts))
+        now = np.asarray(counts, np.int64)
+        grown = (now - self._moe_seen) % (1 << 32)
+        self._moe_seen = now
+        for r, kind in enumerate(MOE_KINDS):
+            for j, name in enumerate(COUNTS):
+                self.metrics[f"{name}.{kind}"] += int(grown[r, j])
+        return got
+
     def run(self, max_segments: Optional[int] = None) -> Dict[int, Request]:
         """Drive the queue to completion (or for ``max_segments`` decode
         segments — in-flight requests then re-queue with their progress
@@ -2085,6 +2119,7 @@ class BatchScheduler:
         # device-side row length (includes segment overshoot the request
         # never sees — the page a token was WRITTEN to must stay covered)
         slot_len = self._slot_len = np.zeros(nslots, np.int64)
+        self._moe_seen[:] = 0           # the fresh state counts from 0
         self._running = True
         seg_run = 0     # segments executed by THIS call (max_segments)
 
@@ -2162,7 +2197,8 @@ class BatchScheduler:
                             logits, rng, spec_mask)
                     with eng.span("serve.fetch", DECODE_REGION, seg=seg):
                         # ONE sync per segment
-                        toks_np, counts_np = eng._fetch((toks, counts))
+                        toks_np, counts_np = self._fetch_counted(
+                            (toks, counts), state)
                     produced = counts_np.astype(np.int64)
                     slot_len[active] += produced[active]
                     self.metrics["segments"] += 1
@@ -2204,7 +2240,8 @@ class BatchScheduler:
                         toks, logits, state, rng = eng.decode_segment(
                             steps)(eng.params, state, logits, rng)
                     with eng.span("serve.fetch", DECODE_REGION, seg=seg):
-                        toks_np = eng._fetch(toks)  # ONE sync per segment
+                        # ONE sync per segment
+                        toks_np = self._fetch_counted(toks, state)
                     produced = np.full(nslots, steps, np.int64)
                     slot_len[active] += steps
                     self.metrics["segments"] += 1

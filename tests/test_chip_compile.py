@@ -13,6 +13,9 @@ import: only one process may load the TPU library at a time, and every
 test worker imports this file.
 """
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -26,6 +29,11 @@ QWEN = get_arch("qwen2-0.5b").config
 QWEN_VL = get_arch("qwen2-vl-7b").config
 ATTN_CFGS = [pytest.param(QWEN, id="qwen2-0.5b"),
              pytest.param(QWEN_VL, id="qwen2-vl-7b")]
+#: one chip's share of a Qwen3-235B-A22B stage: 6 layers, experts 0-31
+QWEN3_MOE = dataclasses.replace(get_arch("qwen3-moe-235b-a22b").config,
+                                n_layers=6, moe_held_experts=32)
+#: the grouped expert matmul's custom call, named after its jit
+GMM = re.compile(r"%grouped_expert_matmul\.\d+ = .*custom-call\(")
 
 
 @pytest.fixture(scope="module")
@@ -234,3 +242,61 @@ def test_decode_segment_pool_copies_carry_kv_cache(one_chip, monkeypatch):
     kernel = re.compile(
         r"%paged_decode_attention_grouped\.\d+ = .*custom-call\(")
     assert len([ln for ln in text.splitlines() if kernel.search(ln)]) == 1
+
+
+@pytest.mark.parametrize("m,k,n", [(1024, 4096, 1536), (1024, 1536, 4096),
+                                   (11264, 4096, 1536), (576, 4096, 1536)],
+                         ids=["decode_gate", "decode_down", "prefill_gate",
+                              "prefill72_gate"])
+def test_pallas_gmm_compiles(one_chip, m, k, n):
+    """The grouped expert matmul at Qwen3-MoE's widths (d_model 4096,
+    expert width 1536, 32 experts held): a decode step's 128 rows x top-8,
+    a 1,408-token prefill's pairs, and a 72-token prefill's 576, which do
+    not fill whole 128-row tiles."""
+    run = registry.get_spec("grouped_matmul", "pallas_gmm").fn
+    txt = _compiled_text(lambda a, b, g: run(a, b, g, interpret=False),
+                         one_chip, ((m, k), BF16), ((32, k, n), BF16),
+                         ((32,), jnp.int32))
+    assert "tpu_custom_call" in txt and GMM.search(txt)
+
+
+def test_moe_programs_compile_at_the_cut_widths(one_chip, monkeypatch):
+    """The Qwen3-MoE stage's decode segment and its longest slot prefill
+    (1,408 tokens), over a 2 GiB pool for 128 slots of 2,048 tokens,
+    compile for one chip and fit its HBM, with the grouped expert matmul
+    three times in the layer scan's body (gate, up, down) and the paged
+    kernel once."""
+    from repro.core.features import default_features
+    from repro.models.lm import LM
+    from repro.serve import Engine, ServeConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lm = LM(QWEN3_MOE, default_features().with_(remat_policy="none"),
+            dtype=BF16)
+    params = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0),
+                                                   dtype=BF16))
+    eng = Engine(lm, params, ServeConfig(page_size=16, batch_slots=128,
+                                         max_seq=2048, pool_pages=10922))
+    state = jax.eval_shape(lambda: lm.init_decode_state(
+        128, 2048, **eng._state_kwargs()))
+    put = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    logits = arg((128, QWEN3_MOE.vocab), BF16)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    decode = eng.decode_segment(8).lower(put(params), put(state), logits,
+                                         put(key)).compile()
+    with eng._impl_ctx():
+        prefill = eng._paged_slot_prefill.lower(
+            put(params), put(state), logits, arg((1, 1408), jnp.int32),
+            arg((), jnp.int32), arg((eng.table_width,), jnp.int32),
+            None).compile()
+    for compiled, paged in ((decode, 1), (prefill, 0)):
+        lines = compiled.as_text().splitlines()
+        assert len([ln for ln in lines if GMM.search(ln)]) == 3
+        assert len([ln for ln in lines if re.search(
+            r"%paged_decode_attention_grouped\.\d+ = .*custom-call\(",
+            ln)]) == paged
+        mem = compiled.memory_analysis()
+        used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        assert used < 15.75 * 2**30, mem

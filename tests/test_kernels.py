@@ -250,3 +250,44 @@ def test_ssd_scan_normalized_mode():
     y, _ = ops.ssd_scan(q, k, v, lf, li, chunk=32, normalize=True)
     y_ref, _ = ref.ssd_scan(q, k, v, lf, li, normalize=True)
     np.testing.assert_allclose(y, y_ref, rtol=2e-3, atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (the MoE layer's expert FFNs)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("impl", ["pallas_gmm", "jnp_gmm"])
+@pytest.mark.parametrize("sizes", [(5, 0, 20, 3), (0, 0, 0, 0), (40, 0, 0, 0),
+                                   (1, 1, 1, 1)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_matmul_sweep(impl, sizes, dtype):
+    """Rows grouped by expert, with empty groups and rows past the groups
+    (undefined for the kernel, so compared only inside them)."""
+    from repro.kernels import registry
+    k1, k2 = jax.random.split(jax.random.PRNGKey(sum(sizes)))
+    lhs, rhs = _rand(k1, (48, 32), dtype), _rand(k2, (4, 32, 24), dtype)
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = registry.run("grouped_matmul", lhs, rhs, gs, impl=impl)
+    n = sum(sizes)
+    assert got.dtype == jnp.float32 and got.shape == (48, 24)
+    np.testing.assert_allclose(np.asarray(got[:n]),
+                               np.asarray(ref.grouped_matmul(lhs, rhs, gs)[:n]),
+                               **TOL[jnp.float32])
+
+
+@pytest.mark.parametrize("impl", ["pallas_gmm", "jnp_gmm"])
+def test_grouped_matmul_rows_not_a_whole_tile(impl):
+    """200 rows (a 128-row tile and part of another), groups crossing the
+    tile boundary and ending short of the last row: a prefill whose pairs
+    do not fill the kernel's last row tile."""
+    from repro.kernels import registry
+    k1, k2 = jax.random.split(jax.random.PRNGKey(7))
+    lhs, rhs = _rand(k1, (200, 32), jnp.bfloat16), \
+        _rand(k2, (4, 32, 24), jnp.bfloat16)
+    gs = jnp.asarray((60, 0, 100, 30), jnp.int32)
+    got = registry.run("grouped_matmul", lhs, rhs, gs, impl=impl)
+    assert got.shape == (200, 24)
+    np.testing.assert_allclose(np.asarray(got[:190]),
+                               np.asarray(ref.grouped_matmul(lhs, rhs,
+                                                             gs)[:190]),
+                               **TOL[jnp.float32])

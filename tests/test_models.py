@@ -122,7 +122,7 @@ def test_moe_output_shape_and_aux_loss():
     cfg = _moe_cfg()
     p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
     x = _rand(jax.random.PRNGKey(1), (2, 8, 32))
-    y, aux = moe_mod.moe_mlp(p, x, cfg)
+    y, aux, _ = moe_mod.moe_mlp(p, x, cfg)
     assert y.shape == x.shape
     assert jnp.isfinite(y).all()
     assert float(aux) >= 0.0
@@ -134,21 +134,22 @@ def test_moe_uniform_router_balanced_aux():
     p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
     x = _rand(jax.random.PRNGKey(2), (1, 64, 32))
     p_uni = dict(p, router=jnp.zeros_like(p["router"]))
-    _, aux_uni = moe_mod.moe_mlp(p_uni, x, cfg)
+    _, aux_uni, _ = moe_mod.moe_mlp(p_uni, x, cfg)
     # skew: every token to expert 0
     skew = jnp.zeros_like(p["router"]).at[:, 0].set(0.0)
     p_skew = dict(p, router=skew + jnp.array([10.0, 0, 0, 0]))
-    _, aux_skew = moe_mod.moe_mlp(p_skew, x, cfg)
+    _, aux_skew, _ = moe_mod.moe_mlp(p_skew, x, cfg)
     assert float(aux_uni) <= float(aux_skew) + 1e-6
 
 
 def test_moe_top1_selects_argmax_expert():
-    # capacity_factor = E/topk makes dispatch lossless (no dropped tokens)
-    cfg = _moe_cfg(e=4, k=1, shared=0)._replace(capacity_factor=4.0)
+    # dispatch is dropless: every token reaches its expert, however many
+    # tokens pick the same one
+    cfg = _moe_cfg(e=4, k=1, shared=0)
     p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
     x = _rand(jax.random.PRNGKey(3), (1, 4, 32))
     logits = jnp.einsum("bsd,de->bse", x, p["router"])
-    y, _ = moe_mod.moe_mlp(p, x, cfg)
+    y, _, _ = moe_mod.moe_mlp(p, x, cfg)
     # manual top-1 dispatch oracle (top-1 routing weight softmaxes to 1)
     e_idx = jnp.argmax(logits, -1)
     outs = []
@@ -158,6 +159,34 @@ def test_moe_top1_selects_argmax_expert():
         u = x[0, t] @ p["w_up"][e]
         outs.append((jax.nn.silu(h) * u) @ p["w_down"][e])
     np.testing.assert_allclose(y[0], jnp.stack(outs), rtol=2e-3, atol=2e-4)
+
+
+def test_moe_capacity_dispatch_lossless_matches_dropless():
+    # capacity_factor = E/topk leaves room for every pair: the training
+    # dispatch then gives the serving one's output and aux loss
+    cfg = _moe_cfg(e=8, k=2, shared=1)._replace(capacity_factor=4.0)
+    p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
+    x = _rand(jax.random.PRNGKey(4), (2, 8, 32))
+    y, aux, _ = moe_mod.moe_mlp(p, x, cfg)
+    y_cap, aux_cap = moe_mod.moe_mlp_capacity(p, x, cfg)
+    np.testing.assert_allclose(y_cap, y, rtol=2e-3, atol=2e-4)
+    np.testing.assert_allclose(aux_cap, aux, rtol=1e-6)
+
+
+def test_moe_capacity_dispatch_drops_past_capacity():
+    # every token to expert 0 at capacity_factor 1: capacity is T*k/E
+    # pairs, so only the first 4 of 16 tokens get an expert output
+    cfg = _moe_cfg(e=4, k=1, shared=0)._replace(capacity_factor=1.0)
+    p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
+    p = dict(p, router=jnp.zeros_like(p["router"]).at[:, 0].set(1.0))
+    x = jnp.abs(_rand(jax.random.PRNGKey(5), (1, 16, 32)))
+    y_cap, _ = moe_mod.moe_mlp_capacity(p, x, cfg)
+    y, _, _ = moe_mod.moe_mlp(p, x, cfg)
+    np.testing.assert_allclose(y_cap[0, :4], y[0, :4], rtol=2e-3, atol=2e-4)
+    assert float(jnp.max(jnp.abs(y_cap[0, 4:]))) == 0.0
+    assert float(jnp.min(jnp.max(jnp.abs(y[0, 4:]), -1))) > 0.0
+    with pytest.raises(ValueError, match="every expert"):
+        moe_mod.moe_mlp_capacity(p, x, cfg._replace(held_experts=2))
 
 
 def test_moe_active_params_counting():
