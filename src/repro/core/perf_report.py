@@ -441,20 +441,21 @@ def suite_inputs(family: str, records: Sequence[Dict[str, Any]] = ()
         q = jax.random.normal(kq, (b, 1, g * kvh, dh), jnp.float32)
         if quantized:
             ksp, vsp = jax.random.split(kp), jax.random.split(vp)
-            k_pages = jax.random.randint(ksp[0], (p_total, ps, kvh, dh),
+            # one layer's pool, stacked as the decode state stacks it
+            k_pages = jax.random.randint(ksp[0], (1, p_total, ps, kvh * dh),
                                          -127, 128, jnp.int8)
-            v_pages = jax.random.randint(vsp[0], (p_total, ps, kvh, dh),
+            v_pages = jax.random.randint(vsp[0], (1, p_total, ps, kvh * dh),
                                          -127, 128, jnp.int8)
             kwargs: Dict[str, Any] = {
-                "k_scale": jax.random.uniform(ksp[1], (p_total, ps),
+                "k_scale": jax.random.uniform(ksp[1], (1, p_total, ps),
                                               jnp.float32, 0.005, 0.05),
-                "v_scale": jax.random.uniform(vsp[1], (p_total, ps),
+                "v_scale": jax.random.uniform(vsp[1], (1, p_total, ps),
                                               jnp.float32, 0.005, 0.05),
             }
         else:
-            k_pages = jax.random.normal(kp, (p_total, ps, kvh, dh),
+            k_pages = jax.random.normal(kp, (1, p_total, ps, kvh * dh),
                                         jnp.float32)
-            v_pages = jax.random.normal(vp, (p_total, ps, kvh, dh),
+            v_pages = jax.random.normal(vp, (1, p_total, ps, kvh * dh),
                                         jnp.float32)
             kwargs = {}
         table = jnp.arange(b * np_w, dtype=jnp.int32).reshape(b, np_w)

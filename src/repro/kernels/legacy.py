@@ -264,11 +264,12 @@ def run_paged_decode(name: str, q, k_pages, v_pages, page_table, length,
                      interpret: Optional[bool] = None):
     """Run paged decode impl ``name`` in model layout.
 
-    q [B,1,H,Dh]; k/v_pages [P,ps,KVH,Dh] (one layer's pool slice);
-    page_table [B,NP] int32; length [B] int32 (past tokens — the new
-    token's K/V ride separately in ``k_new``/``v_new`` [B,1,KVH,Dh] and
-    are folded into the softmax, NOT written; the caller scatters them
-    into their page afterwards).  Returns [B,1,H,Dh].
+    q [B,1,H,Dh]; k/v_pages [L,P,ps,KVH*Dh] (the stacked pool, read at
+    layer 0); page_table [B,NP] int32; length [B] int32 (past tokens —
+    the new token's K/V ride separately in ``k_new``/``v_new``
+    [B,1,KVH,Dh] and are folded into the softmax, NOT written; the
+    caller scatters them into their page afterwards).  Returns
+    [B,1,H,Dh].
     """
     _deprecated("run_paged_decode",
                 'registry.run("paged_decode", ..., impl=name)')

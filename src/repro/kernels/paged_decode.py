@@ -10,10 +10,13 @@ of them, and decode touches only those.
 Structure (grid = one step per batch row; the kv heads of a page are one
 lane-dense ``[ps, KVH*Dh]`` tile):
 
-* the page table ``[B, NP]`` and per-row lengths ``[B]`` are scalar-
-  prefetched into SMEM (``pltpu.PrefetchScalarGridSpec``); the pool slice
-  stays in HBM (``memory_space=pl.ANY``) and the kernel issues its own
-  DMAs;
+* the page table ``[B, NP]``, the per-row lengths ``[B]`` and the layer
+  index are scalar-prefetched into SMEM (``pltpu.PrefetchScalarGridSpec``);
+  the STACKED pool ``[L, P, ps, KVH*Dh]`` stays in HBM
+  (``memory_space=pl.ANY``) and the kernel issues its own DMAs, each
+  reading page ``pt[b, j]`` of the prefetched layer in place — the
+  decode program's layer scan hands the kernel its pool carry as is, so
+  no layer's pool is ever sliced, relaid or copied outside the kernel;
 * a row's pages are walked in blocks of ``pages_per_block`` (ppb) pages:
   one block is ppb page DMAs, each from the physical page ``pt[b, j]``
   into its slot of one ``[ppb, ps, KVH*Dh]`` VMEM buffer (the gather
@@ -33,6 +36,11 @@ lane-dense ``[ps, KVH*Dh]`` tile):
   while block k is scored; which of the two buffers holds the block in
   flight crosses grid steps in SMEM, so the grid runs in order
   ("arbitrary");
+* int8 pages: the wrapper gathers the scales of the pages each row's
+  table lists (``[B, NP*ps]`` f32, the table's size, never the pool's)
+  and hands them in as one lane-dense row per block; the kernel scales
+  the block's scores by the K scales and its probabilities by the V
+  scales, so the int8 codes go straight into the matmuls;
 * online softmax state (running max / denominator / accumulator) lives in
   VMEM scratch across the blocks; after the last one the NEW token's K/V
   (one [KVH, Dh] row, passed separately so the caller can scatter it into
@@ -43,9 +51,12 @@ lane-dense ``[ps, KVH*Dh]`` tile):
   derives it from the page size, and ``kernels/autotune.py`` sweeps it
   with ``page_size``.
 
-Layout contract: q grouped [B, KVH, G, Dh]; pages [P, page_size, KVH, Dh]
-(the pool layout, one layer's slice).  ``paged_decode_attention`` adapts
-from the model's [B, 1, H, Dh].  Oracle: kernels/ref.py::paged_decode.
+Layout contract: q grouped [B, KVH, G, Dh]; pages [L, P, page_size,
+KVH*Dh], the pool as ``PagedKVCache`` stores it (head h in lanes
+``[h*Dh, (h+1)*Dh)``), read at ``layer`` (a single-layer pool is
+``L = 1``, layer 0); int8 scales [L, P, page_size].
+``paged_decode_attention`` adapts from the model's [B, 1, H, Dh].
+Oracle: kernels/ref.py::paged_decode.
 """
 
 from __future__ import annotations
@@ -64,13 +75,13 @@ __all__ = ["paged_decode_attention", "paged_decode_attention_grouped",
 NEG_INF = -2.0e38
 
 
-def _paged_kernel(lens_ref, pt_ref, q_ref, kn_ref, vn_ref, *refs,
+def _paged_kernel(lens_ref, pt_ref, layer_ref, q_ref, kn_ref, vn_ref, *refs,
                   scale: float, ps: int, ppb: int, kvh: int, dh: int,
                   np_w: int, quantized: bool):
-    """refs: the HBM pages k, v [and scales k, v when quantized], then o,
-    then scratch: the VMEM block buffers (one per HBM operand, two slots
-    each), the DMA semaphores [2, operands], the SMEM slot of the block in
-    flight, and m, l, acc.
+    """refs: [the per-block scale rows k, v when quantized], the stacked
+    HBM pages k, v, then o, then scratch: the VMEM block buffers (one per
+    HBM operand, two slots each), the DMA semaphores [2, 2], the SMEM slot
+    of the block in flight, and m, l, acc.
 
     Each kv head's queries sit block-diagonally in rows of a
     ``[KVH*Gp, KVH*Dh]`` tile (zeros outside the head's lanes, G padded to
@@ -79,13 +90,15 @@ def _paged_kernel(lens_ref, pt_ref, q_ref, kn_ref, vn_ref, *refs,
     keeps each head's own lanes.  No lane slice at a sub-128 offset is
     ever taken.
     """
-    n_hbm = 4 if quantized else 2
-    hbm = refs[:n_hbm]
-    o_ref = refs[n_hbm]
-    bufs = refs[n_hbm + 1:2 * n_hbm + 1]
-    sems, slot_ref, m_ref, l_ref, acc_ref = refs[2 * n_hbm + 1:]
+    n_sc = 2 if quantized else 0
+    scales = refs[:n_sc]
+    hbm = refs[n_sc:n_sc + 2]
+    o_ref = refs[n_sc + 2]
+    bufs = refs[n_sc + 3:n_sc + 5]
+    sems, slot_ref, m_ref, l_ref, acc_ref = refs[n_sc + 5:]
     b = pl.program_id(0)
     nb = pl.num_programs(0)
+    layer = layer_ref[0]
     bk = ppb * ps
     r, w = acc_ref.shape
 
@@ -95,13 +108,15 @@ def _paged_kernel(lens_ref, pt_ref, q_ref, kn_ref, vn_ref, *refs,
 
     def dma(row, blk, slot, op):
         """Issue (op="start") or retire (op="wait") block ``blk`` of
-        ``row`` in ``slot``: one copy per live page and HBM operand."""
+        ``row`` in ``slot``: one copy per live page and HBM operand, read
+        in place from the layer's pages of the stacked pool."""
         live = jnp.minimum(ppb, (row_len(row) + ps - 1) // ps - blk * ppb)
 
         def page(i, carry):
             p = pt_ref[row, blk * ppb + i]
             for j, (src, buf) in enumerate(zip(hbm, bufs)):
-                getattr(pltpu.make_async_copy(src.at[p], buf.at[slot, i],
+                getattr(pltpu.make_async_copy(src.at[layer, p],
+                                              buf.at[slot, i],
                                               sems.at[slot, j]), op)()
             return carry
 
@@ -158,23 +173,23 @@ def _paged_kernel(lens_ref, pt_ref, q_ref, kn_ref, vn_ref, *refs,
             dma(nxt, 0, 1 - slot, "start")
 
         dma(b, i, slot, "wait")
-        k = bufs[0][slot].astype(jnp.float32)             # [ppb, ps, W]
-        v = bufs[1][slot].astype(jnp.float32)
-        if quantized:
-            # dequant in VMEM: int8 codes x f32 per-token scales (every
-            # lane of a scale tile holds the same value)
-            k = k * jnp.max(bufs[2][slot], axis=-1, keepdims=True)
-            v = v * jnp.max(bufs[3][slot], axis=-1, keepdims=True)
-        k = k.reshape(bk, w)
+        k = bufs[0][slot].astype(jnp.float32).reshape(bk, w)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
         ok = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < length
+        if quantized:
+            # int8 codes: a key's dequant factor scales its score column
+            s = s * scales[0][pl.ds(i, 1), :]
         s = jnp.where(ok, s, NEG_INF)
         m_prev = m_ref[...]                                # [R, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         pr = jnp.where(ok, jnp.exp(s - m_new), 0.0)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=1, keepdims=True)
-        v = v.reshape(bk, w)
+        if quantized:
+            # ... and a value's its probability column (a dead key's
+            # scale, whatever it holds, is masked out)
+            pr = pr * jnp.where(ok, scales[1][pl.ds(i, 1), :], 0.0)
+        v = bufs[1][slot].astype(jnp.float32).reshape(bk, w)
         live = (i * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
                 < length)
         v = jnp.where(live, v, 0.0)       # the last block's dead rows
@@ -206,33 +221,44 @@ def _paged_kernel(lens_ref, pt_ref, q_ref, kn_ref, vn_ref, *refs,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
+def _block_scales(scales, page_table, layer, ppb: int, n_blk: int):
+    """The scales of the pages each row's table lists, one lane-dense row
+    of ``ppb * ps`` keys per block: ``[B, n_blk, ppb * ps]`` f32, gathered
+    straight from the stacked ``[L, P, ps]`` scale pool at ``layer`` (the
+    table's width past NP reads the null page, never a live key)."""
+    b, np_w = page_table.shape
+    pt = jnp.pad(page_table, ((0, 0), (0, n_blk * ppb - np_w)))
+    return tuple(sc[layer, pt].astype(jnp.float32).reshape(b, n_blk, -1)
+                 for sc in scales)
+
+
 def _paged_call(q4, k_pages, v_pages, scales, page_table, lengths, k_new,
-                v_new, *, pages_per_block: int, interpret,
+                v_new, layer=0, *, pages_per_block: int, interpret,
                 compiler_params=None):
     """Shared pallas_call for the fp and int8 page flavors.
 
-    ``scales`` is None (fp pages) or ``(k_scale, v_scale)`` ``[P, ps]``,
-    fetched beside their pages as lane-dense ``[ps, 128]`` tiles.  The pool
-    ``[P, ps, KVH, Dh]`` is viewed as ``[P, ps, KVH*Dh]`` (a reshape,
-    though not a free one on a v5e): a page is then one lane-dense tile,
-    which the TPU tiling accepts for any KVH and Dh — a per-head tile
-    would put KVH in the sublane dim, refused unless KVH is a multiple of
-    8.  ``compiler_params`` (``pltpu.CompilerParams``) passes through to
-    the ``pallas_call``, e.g. to hold the kernel to a VMEM limit.
+    ``k_pages``/``v_pages`` are the stacked pool ``[L, P, ps, KVH*Dh]``
+    and ``layer`` the (traced) layer the kernel reads: a page is one
+    lane-dense tile, which the TPU tiling accepts for any KVH and Dh (a
+    per-head tile would put KVH in the sublane dim, refused unless KVH is
+    a multiple of 8), and the kernel's DMAs read it in place.  ``scales``
+    is None (fp pages) or ``(k_scale, v_scale)`` ``[L, P, ps]``.
+    ``compiler_params`` (``pltpu.CompilerParams``) passes through to the
+    ``pallas_call``, e.g. to hold the kernel to a VMEM limit.
     """
     from repro.kernels import registry
     if interpret is None:
         interpret = registry.default_interpret()
     b, kvh, g, dh = q4.shape
-    p_total, ps, kvh_p, _ = k_pages.shape
-    assert kvh_p == kvh, (kvh_p, kvh)
-    w = kvh * dh
+    _, _, ps, w = k_pages.shape
+    assert w == kvh * dh, (k_pages.shape, q4.shape)
     np_w = page_table.shape[1]
     ppb = max(1, min(pages_per_block or registry.default_pages_per_block(ps),
                      np_w))
     gp = -(-g // 8) * 8
     lengths = jnp.asarray(lengths, jnp.int32)
     page_table = jnp.asarray(page_table, jnp.int32)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     # block-diagonal queries: head h's [G, Dh] in lanes [h*Dh, (h+1)*Dh)
     # of rows [h*Gp, h*Gp + G)
     eye = jnp.eye(kvh, dtype=q4.dtype)
@@ -242,36 +268,24 @@ def _paged_call(q4, k_pages, v_pages, scales, page_table, lengths, k_new,
         b, kvh * gp, w)
     kn = k_new.reshape(b, 1, w)
     vn = v_new.reshape(b, 1, w)
-    # on a v5e the [ps, KVH, Dh] and [ps, W] tilings differ, so XLA
-    # copies the whole pool to make this view: pool movement outside the
-    # kernel, named as such
-    with jax.named_scope("kv_cache"):
-        k_rows = k_pages.reshape(p_total, ps, w)
-        v_rows = v_pages.reshape(p_total, ps, w)
-    hbm = [k_rows, v_rows]
-    bufs = [pltpu.VMEM((2, ppb, ps, w), k_rows.dtype),
-            pltpu.VMEM((2, ppb, ps, w), v_rows.dtype)]
+    row = lambda b_, lens, pt, layer: (b_, 0, 0)
+    blocked = [qw, kn, vn]
+    in_specs = [pl.BlockSpec((None, kvh * gp, w), row),
+                pl.BlockSpec((None, 1, w), row),
+                pl.BlockSpec((None, 1, w), row)]
     if scales is not None:
-        # [P, ps] -> [P, ps, 128]: a page's scales as one lane-dense tile
-        # (a [ps, 1] column is stored lane-padded to the same bytes, but
-        # its DMA window is refused by the TPU tiling)
-        hbm += [jnp.broadcast_to(sc.astype(jnp.float32)[..., None],
-                                 (p_total, ps, 128)) for sc in scales]
-        bufs += [pltpu.VMEM((2, ppb, ps, 128), jnp.float32)] * 2
-    row = lambda b_, lens, pt: (b_, 0, 0)
+        n_blk = -(-np_w // ppb)
+        blocked += _block_scales(scales, page_table, layer[0], ppb, n_blk)
+        in_specs += [pl.BlockSpec((None, n_blk, ppb * ps), row)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,            # lengths, page_table
+        num_scalar_prefetch=3,            # lengths, page_table, layer
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((None, kvh * gp, w), row),
-            pl.BlockSpec((None, 1, w), row),
-            pl.BlockSpec((None, 1, w), row),
-            *[pl.BlockSpec(memory_space=pl.ANY)] * len(hbm),
-        ],
+        in_specs=[*in_specs, *[pl.BlockSpec(memory_space=pl.ANY)] * 2],
         out_specs=pl.BlockSpec((None, gp, w), row),
         scratch_shapes=[
-            *bufs,
-            pltpu.SemaphoreType.DMA((2, len(hbm))),
+            pltpu.VMEM((2, ppb, ps, w), k_pages.dtype),
+            pltpu.VMEM((2, ppb, ps, w), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),              # slot in flight
             pltpu.VMEM((kvh * gp, 1), jnp.float32),   # running max
             pltpu.VMEM((kvh * gp, 1), jnp.float32),   # denominator
@@ -291,7 +305,7 @@ def _paged_call(q4, k_pages, v_pages, scales, page_table, lengths, k_new,
         out_shape=jax.ShapeDtypeStruct((b, gp, w), q4.dtype),
         compiler_params=params,
         interpret=interpret,
-    )(lengths, page_table, qw, kn, vn, *hbm)
+    )(lengths, page_table, layer, *blocked, k_pages, v_pages)
     # [B, Gp, KVH*Dh] -> [B, KVH, G, Dh]
     return out[:, :g].reshape(b, g, kvh, dh).transpose(0, 2, 1, 3)
 
@@ -301,13 +315,15 @@ def paged_decode_attention_grouped(q4: jnp.ndarray, k_pages: jnp.ndarray,
                                    v_pages: jnp.ndarray,
                                    page_table: jnp.ndarray,
                                    lengths: jnp.ndarray,
-                                   k_new: jnp.ndarray, v_new: jnp.ndarray, *,
+                                   k_new: jnp.ndarray, v_new: jnp.ndarray,
+                                   layer=0, *,
                                    pages_per_block: int | None = None,
                                    interpret: bool | None = None
                                    ) -> jnp.ndarray:
-    """q4: [B,KVH,G,Dh]; k/v_pages: [P,ps,KVH,Dh]; page_table: [B,NP] int32;
-    lengths: [B] int32 (past tokens; the new token is NOT in the pages yet);
-    k_new/v_new: [B,KVH,Dh].  Returns [B,KVH,G,Dh].
+    """q4: [B,KVH,G,Dh]; k/v_pages: [L,P,ps,KVH*Dh] read at ``layer``;
+    page_table: [B,NP] int32; lengths: [B] int32 (past tokens; the new
+    token is NOT in the pages yet); k_new/v_new: [B,KVH,Dh].  Returns
+    [B,KVH,G,Dh].
 
     ``page_table[b, j]`` is the physical page holding row b's tokens
     ``[j*ps, (j+1)*ps)``; entries past ``ceil(lengths[b]/ps)`` are never
@@ -316,7 +332,7 @@ def paged_decode_attention_grouped(q4: jnp.ndarray, k_pages: jnp.ndarray,
     ``registry.default_pages_per_block(ps)``.
     """
     return _paged_call(q4, k_pages, v_pages, None, page_table, lengths,
-                       k_new, v_new, pages_per_block=pages_per_block,
+                       k_new, v_new, layer, pages_per_block=pages_per_block,
                        interpret=interpret)
 
 
@@ -328,21 +344,21 @@ def paged_decode_attention_q8_grouped(q4: jnp.ndarray, k_pages: jnp.ndarray,
                                       page_table: jnp.ndarray,
                                       lengths: jnp.ndarray,
                                       k_new: jnp.ndarray,
-                                      v_new: jnp.ndarray, *,
+                                      v_new: jnp.ndarray, layer=0, *,
                                       pages_per_block: int | None = None,
                                       interpret: bool | None = None
                                       ) -> jnp.ndarray:
     """:func:`paged_decode_attention_grouped` over int8 pages.
 
-    k/v_pages hold int8 codes; k/v_scale ``[P, ps]`` f32 hold one dequant
-    factor per resident token row.  The scales ride the SAME block DMAs
-    as their pages (one extra [ps, 128] f32 tile per page), and
-    dequantization happens in VMEM between the DMA and the QK^T matmul:
-    HBM sees only int8.
+    k/v_pages hold int8 codes; k/v_scale ``[L, P, ps]`` f32 hold one
+    dequant factor per resident token row.  Only the scales of the pages
+    the table lists are gathered (one lane-dense row per block of each
+    row's walk), and they apply to the block's scores and probabilities
+    in VMEM: HBM sees only int8 pages.
     """
     assert k_pages.dtype == jnp.int8, k_pages.dtype
     return _paged_call(q4, k_pages, v_pages, (k_scale, v_scale), page_table,
-                       lengths, k_new, v_new,
+                       lengths, k_new, v_new, layer,
                        pages_per_block=pages_per_block, interpret=interpret)
 
 
@@ -351,17 +367,16 @@ def paged_decode_attention_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
                               lengths: jnp.ndarray, k_new: jnp.ndarray,
                               v_new: jnp.ndarray, *,
                               k_scale: jnp.ndarray, v_scale: jnp.ndarray,
-                              pages_per_block: int | None = None,
+                              layer=0, pages_per_block: int | None = None,
                               interpret: bool | None = None) -> jnp.ndarray:
     """Model layout int8 entry: q [B,1,H,Dh], k/v_new [B,1,KVH,Dh],
-    int8 pages + [P, ps] scales -> [B,1,H,Dh]."""
+    int8 pages [L,P,ps,KVH*Dh] + [L,P,ps] scales -> [B,1,H,Dh]."""
     b, _, h, dh = q.shape
-    kvh = k_pages.shape[2]
-    g = h // kvh
-    q4 = q.reshape(b, kvh, g, dh)
+    kvh = k_new.shape[2]
+    q4 = q.reshape(b, kvh, h // kvh, dh)
     out = paged_decode_attention_q8_grouped(
         q4, k_pages, v_pages, k_scale, v_scale, page_table, lengths,
-        k_new.reshape(b, kvh, dh), v_new.reshape(b, kvh, dh),
+        k_new.reshape(b, kvh, dh), v_new.reshape(b, kvh, dh), layer,
         pages_per_block=pages_per_block, interpret=interpret)
     return out.reshape(b, 1, h, dh)
 
@@ -369,16 +384,16 @@ def paged_decode_attention_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
 def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, page_table: jnp.ndarray,
                            lengths: jnp.ndarray, k_new: jnp.ndarray,
-                           v_new: jnp.ndarray, *,
+                           v_new: jnp.ndarray, *, layer=0,
                            pages_per_block: int | None = None,
                            interpret: bool | None = None) -> jnp.ndarray:
-    """Model layout: q [B,1,H,Dh], k_new/v_new [B,1,KVH,Dh] -> [B,1,H,Dh]."""
+    """Model layout: q [B,1,H,Dh], pages [L,P,ps,KVH*Dh] read at
+    ``layer``, k_new/v_new [B,1,KVH,Dh] -> [B,1,H,Dh]."""
     b, _, h, dh = q.shape
-    kvh = k_pages.shape[2]
-    g = h // kvh
-    q4 = q.reshape(b, kvh, g, dh)
+    kvh = k_new.shape[2]
+    q4 = q.reshape(b, kvh, h // kvh, dh)
     out = paged_decode_attention_grouped(
         q4, k_pages, v_pages, page_table, lengths,
-        k_new.reshape(b, kvh, dh), v_new.reshape(b, kvh, dh),
+        k_new.reshape(b, kvh, dh), v_new.reshape(b, kvh, dh), layer,
         pages_per_block=pages_per_block, interpret=interpret)
     return out.reshape(b, 1, h, dh)

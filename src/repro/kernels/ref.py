@@ -87,20 +87,24 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                  page_table: jnp.ndarray, lengths: jnp.ndarray,
-                 k_new: jnp.ndarray, v_new: jnp.ndarray) -> jnp.ndarray:
+                 k_new: jnp.ndarray, v_new: jnp.ndarray,
+                 layer: int = 0) -> jnp.ndarray:
     """Paged decode-attention oracle <- kernels/paged_decode.py.
 
-    q: [B,1,H,Dh]; k/v_pages: [P,ps,KVH,Dh]; page_table: [B,NP] int32;
+    q: [B,1,H,Dh]; k/v_pages: [L,P,ps,KVH*Dh] (head h in lanes
+    [h*Dh, (h+1)*Dh)), read at ``layer``; page_table: [B,NP] int32;
     lengths: [B] int32 (past tokens, new token excluded); k_new/v_new:
-    [B,1,KVH,Dh].  Deliberately obvious: gather every listed page into a
-    dense context, append the new token, run one full masked softmax.
+    [B,1,KVH,Dh].  Deliberately obvious: take the layer's pool, gather
+    every listed page into a dense context, append the new token, run
+    one full masked softmax.
     """
     b, _, h, dh = q.shape
-    ps, kvh = k_pages.shape[1], k_pages.shape[2]
+    kvh = k_new.shape[2]
+    ps = k_pages.shape[2]
     np_w = page_table.shape[1]
     lengths = jnp.asarray(lengths, jnp.int32)
-    k_ctx = k_pages[page_table].reshape(b, np_w * ps, kvh, dh)
-    v_ctx = v_pages[page_table].reshape(b, np_w * ps, kvh, dh)
+    k_ctx = k_pages[layer][page_table].reshape(b, np_w * ps, kvh, dh)
+    v_ctx = v_pages[layer][page_table].reshape(b, np_w * ps, kvh, dh)
     k_full = jnp.concatenate([k_ctx, k_new.astype(k_ctx.dtype)], axis=1)
     v_full = jnp.concatenate([v_ctx, v_new.astype(v_ctx.dtype)], axis=1)
     sk = np_w * ps + 1
@@ -124,21 +128,22 @@ def paged_decode_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
                     v_pages: jnp.ndarray, page_table: jnp.ndarray,
                     lengths: jnp.ndarray, k_new: jnp.ndarray,
                     v_new: jnp.ndarray, *, k_scale: jnp.ndarray,
-                    v_scale: jnp.ndarray) -> jnp.ndarray:
+                    v_scale: jnp.ndarray, layer: int = 0) -> jnp.ndarray:
     """Quantized paged decode oracle <- kernels/paged_decode.py (q8).
 
     Same contract as :func:`paged_decode` over int8 pages: dequantize
-    every gathered page row with its [P, ps] per-token f32 scale, then
-    run the identical dense masked softmax.  The kernel must match this
+    every page row with its [L, P, ps] per-token f32 scale, then run the
+    identical dense masked softmax.  The kernel must match this
     EXACTLY (the quantization error lives in the codes, not the kernel —
     dequant-then-attend is deterministic).
     """
     dq = q.dtype if q.dtype == jnp.float32 else jnp.float32
     k_deq = (k_pages.astype(jnp.float32)
-             * k_scale.astype(jnp.float32)[..., None, None]).astype(dq)
+             * k_scale.astype(jnp.float32)[..., None]).astype(dq)
     v_deq = (v_pages.astype(jnp.float32)
-             * v_scale.astype(jnp.float32)[..., None, None]).astype(dq)
-    return paged_decode(q, k_deq, v_deq, page_table, lengths, k_new, v_new)
+             * v_scale.astype(jnp.float32)[..., None]).astype(dq)
+    return paged_decode(q, k_deq, v_deq, page_table, lengths, k_new, v_new,
+                        layer)
 
 
 def ssd_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

@@ -332,10 +332,12 @@ def mesh_facts() -> Dict[str, Any]:
 
 
 #: kernel operand layouts under a mesh: ``_HEADS`` splits dim -2 of
-#: [B,S,H,Dh] / [P,ps,KVH,Dh] over the ambient ``mesh_axis`` — the layout
-#: ``Engine(mesh=)`` shards weights and KV pages in — and ``_REPL``
-#: replicates
+#: [B,S,H,Dh] over the ambient ``mesh_axis`` — the layout ``Engine(mesh=)``
+#: shards weights in — ``_LANES`` splits the last dim of the lane-dense
+#: [L,P,ps,KVH*Dh] page pool (heads are contiguous lane groups, so each
+#: device holds whole heads), and ``_REPL`` replicates
 _HEADS = "heads"
+_LANES = "lanes"
 _REPL = jax.sharding.PartitionSpec()
 
 
@@ -348,9 +350,10 @@ def on_mesh(fn: Callable, args: Sequence, in_specs: Sequence,
     mesh = facts.get("mesh")
     if mesh is None:
         return fn(*args)
-    heads = jax.sharding.PartitionSpec(None, None,
-                                       facts.get("mesh_axis", "model"), None)
-    spec = lambda s: heads if s == _HEADS else s
+    axis = facts.get("mesh_axis", "model")
+    named = {_HEADS: jax.sharding.PartitionSpec(None, None, axis, None),
+             _LANES: jax.sharding.PartitionSpec(None, None, None, axis)}
+    spec = lambda s: named.get(s, s)
     return jax.shard_map(fn, mesh=mesh,
                          in_specs=tuple(spec(s) for s in in_specs),
                          out_specs=spec(out_specs), check_vma=False)(*args)
@@ -1149,26 +1152,31 @@ def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
 
 def _paged_kernel_vmem(ps: int, ppb: int, g: int, dh: int, kvh: int,
                        itemsize: int, page_itemsize: int,
-                       scales: bool) -> int:
+                       scales: bool, ctx: int = 0) -> int:
     """VMEM bytes of ``paged_decode._paged_call``.
 
     Pipelined per row, double-buffered: the block-diagonal queries
     [KVH*Gp, KVH*Dh] (G padded to a multiple of 8), the new token's k/v
-    rows [1, KVH*Dh] and the output [Gp, KVH*Dh].  The kernel's own DMA
-    buffers, two slots each: ppb k and v page tiles [ps, KVH*Dh] (+ their
-    [ps, 128] f32 scale tiles for int8 pages).  Scratch: m/l [KVH*Gp, 1]
+    rows [1, KVH*Dh] and the output [Gp, KVH*Dh] (+ for int8 pages the
+    k and v scale rows of the row's table, [NP/ppb, ppb*ps] f32 for a
+    table of ``ctx`` keys).  The kernel's own DMA buffers, two slots
+    each: ppb k and v page tiles [ps, KVH*Dh].  Scratch: m/l [KVH*Gp, 1]
     and the f32 accumulator [KVH*Gp, KVH*Dh].  f32 temporaries of one
     block of ppb*ps keys: the widened k and v, the [KVH*Gp, ppb*ps] score
-    and probability tiles, the P.V product and the finish."""
+    and probability tiles (one more for the scaled probabilities), the
+    P.V product and the finish."""
     w = kvh * dh
     r = kvh * -(-g // 8) * 8
     bk = ppb * ps
     blocks = (_tile_bytes(r, w, itemsize) + 2 * _tile_bytes(1, w, itemsize)
               + _tile_bytes(r // kvh, w, itemsize))
-    bufs = 2 * 2 * ppb * (_tile_bytes(ps, w, page_itemsize)
-                          + (_tile_bytes(ps, 128, 4) if scales else 0))
+    if scales:
+        n_blk = -(-max(-(-int(ctx) // ps), 1) // ppb)
+        blocks += 2 * _tile_bytes(n_blk, bk, 4)
+    bufs = 2 * 2 * ppb * _tile_bytes(ps, w, page_itemsize)
     scratch = 2 * _tile_bytes(r, 1, 4) + _tile_bytes(r, w, 4)
-    temps = (2 * _tile_bytes(bk, w, 4) + 2 * _tile_bytes(r, bk, 4)
+    temps = (2 * _tile_bytes(bk, w, 4)
+             + (3 if scales else 2) * _tile_bytes(r, bk, 4)
              + 2 * _tile_bytes(r, w, 4))
     return 2 * blocks + bufs + scratch + temps
 
@@ -1203,7 +1211,8 @@ def _paged_probe(cand, interpret, *, b, kvh, g, dh, ctx, dtype, **facts):
     np_w = max(-(-ctx // ps), 1)
     p_total = b * np_w + 1
     fn = functools.partial(_paged_probe_fn, ppb=ppb, interpret=interpret)
-    kp_s = jax.ShapeDtypeStruct((p_total, ps, kvh, dh), dtype)
+    # one layer's pool, stacked as the decode state stacks it
+    kp_s = jax.ShapeDtypeStruct((1, p_total, ps, kvh * dh), dtype)
     kn_s = jax.ShapeDtypeStruct((b, kvh, dh), dtype)
     args = (jax.ShapeDtypeStruct((b, kvh, g, dh), dtype), kp_s, kp_s,
             jax.ShapeDtypeStruct((b, np_w), jnp.int32),
@@ -1267,12 +1276,13 @@ _PAGED_TUNE = TuneSpace(
     neighbors=_paged_neighbors,
 )
 
-_PAGED_LAYOUT = ("q [B,1,H,Dh]; k/v_pages [P,ps,KVH,Dh]; page_table "
-                 "[B,NP] i32; length [B] i32; k/v_new [B,1,KVH,Dh] "
-                 "-> [B,1,H,Dh]")
+_PAGED_LAYOUT = ("q [B,1,H,Dh]; k/v_pages [L,P,ps,KVH*Dh] read at layer "
+                 "(i32 scalar, default 0); page_table [B,NP] i32; length "
+                 "[B] i32; k/v_new [B,1,KVH,Dh] -> [B,1,H,Dh]")
 
 _PAGED_Q8_LAYOUT = (_PAGED_LAYOUT +
-                    "; int8 pages + k/v_scale [P,ps] f32 per-token scales")
+                    "; int8 pages + k/v_scale [L,P,ps] f32 per-token "
+                    "scales")
 
 
 # --- int8 tune space: same candidate grid, its own keys (the winning
@@ -1296,10 +1306,10 @@ def _paged_q8_record_keys(scores, **facts) -> Dict[str, Tuple[Tuple, float]]:
 
 
 def _paged_q8_vmem(cand, itemsize, *, g, dh, kvh=1, per_device_heads=None,
-                   **facts) -> int:
+                   ctx=0, **facts) -> int:
     ps, ppb = cand
     return _paged_kernel_vmem(ps, ppb, g, dh, per_device_heads or kvh,
-                              itemsize, 1, scales=True)
+                              itemsize, 1, scales=True, ctx=ctx)
 
 
 def _paged_q8_probe_fn(q4, kp, vp, ksc, vsc, pt, lens, kn, vn, *, ppb: int,
@@ -1315,8 +1325,8 @@ def _paged_q8_probe(cand, interpret, *, b, kvh, g, dh, ctx, dtype, **facts):
     np_w = max(-(-ctx // ps), 1)
     p_total = b * np_w + 1
     fn = functools.partial(_paged_q8_probe_fn, ppb=ppb, interpret=interpret)
-    kp_s = jax.ShapeDtypeStruct((p_total, ps, kvh, dh), jnp.int8)
-    sc_s = jax.ShapeDtypeStruct((p_total, ps), jnp.float32)
+    kp_s = jax.ShapeDtypeStruct((1, p_total, ps, kvh * dh), jnp.int8)
+    sc_s = jax.ShapeDtypeStruct((1, p_total, ps), jnp.float32)
     kn_s = jax.ShapeDtypeStruct((b, kvh, dh), dtype)
     args = (jax.ShapeDtypeStruct((b, kvh, g, dh), dtype), kp_s, kp_s,
             sc_s, sc_s,
@@ -1350,10 +1360,14 @@ register_family("paged_decode", heuristic=_paged_heuristic,
                 layout=_PAGED_LAYOUT)
 
 
-def _paged_ctx_fact(page_table, k_pages) -> int:
-    """Static context capacity of a dispatch site: table width x page
-    size (the live length is traced; capacity is the trace-time bound)."""
-    return page_table.shape[1] * k_pages.shape[1]
+def _paged_facts(q, k_pages, page_table, k_new) -> Dict[str, Any]:
+    """Tune facts of a dispatch site, read from its operand shapes: the
+    context capacity is table width x page size (the live length is
+    traced; capacity is the trace-time bound)."""
+    kvh = k_new.shape[2]
+    return dict(b=q.shape[0], kvh=kvh, g=q.shape[2] // kvh, dh=q.shape[-1],
+                page_size=k_pages.shape[-2],
+                ctx=page_table.shape[1] * k_pages.shape[-2], dtype=q.dtype)
 
 
 @register_impl("paged_decode", "pallas_paged", tune=_PAGED_TUNE,
@@ -1364,36 +1378,37 @@ def _paged_ctx_fact(page_table, k_pages) -> int:
                    not quantized and (per_device_heads is None
                                       or per_device_heads >= 1))
 def _run_pallas_paged(q, k_pages, v_pages, page_table, length, k_new, v_new,
-                      *, pages_per_block: Optional[int] = None,
+                      *, layer=0, pages_per_block: Optional[int] = None,
                       interpret: Optional[bool] = None):
-    """Pallas paged decode kernel — bytes/token O(length), table-walked."""
+    """Pallas paged decode kernel — bytes/token O(length), table-walked,
+    reading ``layer``'s pages in place from the stacked pool."""
     from repro.kernels.paged_decode import paged_decode_attention
     ppb = pages_per_block or best(
-        "paged_decode", b=q.shape[0], kvh=k_pages.shape[2],
-        g=q.shape[2] // k_pages.shape[2], dh=q.shape[-1],
-        page_size=k_pages.shape[1], ctx=_paged_ctx_fact(page_table, k_pages),
-        dtype=q.dtype)[1]
+        "paged_decode", **_paged_facts(q, k_pages, page_table, k_new))[1]
 
-    def kernel(q, k_pages, v_pages, page_table, length, k_new, v_new):
+    def kernel(q, k_pages, v_pages, page_table, length, k_new, v_new,
+               layer):
         return paged_decode_attention(q, k_pages, v_pages, page_table,
-                                      length, k_new, v_new,
+                                      length, k_new, v_new, layer=layer,
                                       pages_per_block=ppb,
                                       interpret=interpret)
 
     return on_mesh(kernel,
-                   (q, k_pages, v_pages, page_table, length, k_new, v_new),
-                   (_HEADS, _HEADS, _HEADS, _REPL, _REPL, _HEADS, _HEADS))
+                   (q, k_pages, v_pages, page_table, length, k_new, v_new,
+                    jnp.asarray(layer, jnp.int32)),
+                   (_HEADS, _LANES, _LANES, _REPL, _REPL, _HEADS, _HEADS,
+                    _REPL))
 
 
 @register_impl("paged_decode", "jnp_paged", layout=_PAGED_LAYOUT,
                oracle="repro.kernels.ref.paged_decode",
                supports=lambda quantized=False, **f: not quantized)
 def _run_jnp_paged(q, k_pages, v_pages, page_table, length, k_new, v_new,
-                   *, pages_per_block=None, interpret=None):
+                   *, layer=0, pages_per_block=None, interpret=None):
     """gather-based masked-dense reference (oracle/fallback)."""
     from repro.models.attention import paged_decode_jnp
     return paged_decode_jnp(q, k_pages, v_pages, page_table, length,
-                            k_new, v_new)
+                            k_new, v_new, layer=layer)
 
 
 @register_impl("paged_decode", "pallas_paged_q8", tune=_PAGED_Q8_TUNE,
@@ -1403,43 +1418,41 @@ def _run_jnp_paged(q, k_pages, v_pages, page_table, length, k_new, v_new,
                    quantized and (per_device_heads is None
                                   or per_device_heads >= 1))
 def _run_pallas_paged_q8(q, k_pages, v_pages, page_table, length, k_new,
-                         v_new, *, k_scale, v_scale,
+                         v_new, *, k_scale, v_scale, layer=0,
                          pages_per_block: Optional[int] = None,
                          interpret: Optional[bool] = None):
-    """Pallas paged decode over int8 pages — dequant in VMEM post-DMA."""
+    """Pallas paged decode over int8 pages — scales applied in VMEM."""
     from repro.kernels.paged_decode import paged_decode_attention_q8
     ppb = pages_per_block or best(
         "paged_decode", impl="pallas_paged_q8",
-        b=q.shape[0], kvh=k_pages.shape[2],
-        g=q.shape[2] // k_pages.shape[2], dh=q.shape[-1],
-        page_size=k_pages.shape[1], ctx=_paged_ctx_fact(page_table, k_pages),
-        dtype=q.dtype)[1]
+        **_paged_facts(q, k_pages, page_table, k_new))[1]
 
     def kernel(q, k_pages, v_pages, page_table, length, k_new, v_new,
-               k_scale, v_scale):
+               k_scale, v_scale, layer):
         return paged_decode_attention_q8(q, k_pages, v_pages, page_table,
                                          length, k_new, v_new,
                                          k_scale=k_scale, v_scale=v_scale,
-                                         pages_per_block=ppb,
+                                         layer=layer, pages_per_block=ppb,
                                          interpret=interpret)
 
     return on_mesh(kernel,
                    (q, k_pages, v_pages, page_table, length, k_new, v_new,
-                    k_scale, v_scale),
-                   (_HEADS, _HEADS, _HEADS, _REPL, _REPL, _HEADS, _HEADS,
-                    _REPL, _REPL))
+                    k_scale, v_scale, jnp.asarray(layer, jnp.int32)),
+                   (_HEADS, _LANES, _LANES, _REPL, _REPL, _HEADS, _HEADS,
+                    _REPL, _REPL, _REPL))
 
 
 @register_impl("paged_decode", "jnp_paged_q8", layout=_PAGED_Q8_LAYOUT,
                oracle="repro.kernels.ref.paged_decode_q8",
                supports=lambda quantized=False, **f: quantized)
 def _run_jnp_paged_q8(q, k_pages, v_pages, page_table, length, k_new, v_new,
-                      *, k_scale, v_scale, pages_per_block=None,
+                      *, k_scale, v_scale, layer=0, pages_per_block=None,
                       interpret=None):
     """gather + dequantize masked-dense reference for the int8 pages."""
     from repro.models.attention import paged_decode_jnp
     return paged_decode_jnp(q, k_pages, v_pages, page_table, length,
-                            k_new, v_new, k_scale=k_scale, v_scale=v_scale)
+                            k_new, v_new, k_scale=k_scale, v_scale=v_scale,
+                            layer=layer)
 
 
 # ===========================================================================

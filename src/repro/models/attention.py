@@ -614,17 +614,24 @@ class PagedKVCache(NamedTuple):
     table entries point at it, and writes routed there are trash by
     convention (never read — attention masks by ``length``).
 
+    Layout: a page is one lane-dense ``[page_size, KVH*Dh]`` tile, head h
+    in lanes ``[h*Dh, (h+1)*Dh)`` (a row-major reshape of a token's
+    ``[KVH, Dh]``).  The decode state stacks every field over the layers
+    (pools ``[L, P, page_size, KVH*Dh]``), and the paged decode kernel
+    reads layer i's pages straight out of that stack; under a mesh the
+    last dim splits over ``model``, so each device holds whole heads.
+
     int8 storage: when ``k_scale``/``v_scale`` are present the pages hold
     int8 codes and the scales hold one f32 dequant factor per TOKEN ROW
     (``[P, page_size]``, amax over that token's [KVH, Dh] block / 127).
     Per-row scales mean appends never requantize resident tokens, and the
-    paged-decode kernel dequantizes right after the page DMA — HBM
+    paged-decode kernel applies them in VMEM after the page DMA — HBM
     traffic and pool bytes drop ~4x vs fp32 (2x vs bf16) for the same
     token capacity.
     """
 
-    k_pages: jnp.ndarray     # [P, page_size, KVH, Dh] (fp, or int8 codes)
-    v_pages: jnp.ndarray     # [P, page_size, KVH, Dh]
+    k_pages: jnp.ndarray     # [P, page_size, KVH*Dh] (fp, or int8 codes)
+    v_pages: jnp.ndarray     # [P, page_size, KVH*Dh]
     page_table: jnp.ndarray  # [B, NP] int32 physical page ids
     length: jnp.ndarray      # [B] int32 — tokens filled so far, per row
     k_scale: Optional[jnp.ndarray] = None   # [P, page_size] f32 (int8 only)
@@ -632,7 +639,7 @@ class PagedKVCache(NamedTuple):
 
     @property
     def page_size(self) -> int:
-        return self.k_pages.shape[-3]
+        return self.k_pages.shape[-2]
 
     @property
     def quantized(self) -> bool:
@@ -654,10 +661,10 @@ def quantize_kv_rows(seq: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 def dequantize_gathered(gathered: jnp.ndarray, scale: jnp.ndarray,
                         dtype=jnp.float32) -> jnp.ndarray:
-    """Dequantize gathered int8 pages: gathered [..., ps, KVH, Dh] codes,
+    """Dequantize gathered int8 pages: gathered [..., ps, KVH*Dh] codes,
     scale [..., ps] -> fp values in ``dtype``."""
     return (gathered.astype(jnp.float32)
-            * scale.astype(jnp.float32)[..., None, None]).astype(dtype)
+            * scale.astype(jnp.float32)[..., None]).astype(dtype)
 
 
 def init_paged_kv_cache(batch: int, num_pages: int, table_width: int,
@@ -667,7 +674,7 @@ def init_paged_kv_cache(batch: int, num_pages: int, table_width: int,
     """``kv_dtype`` overrides the page storage dtype; ``jnp.int8`` turns
     on quantized storage (per-token-row f32 scales ride along)."""
     kv_dtype = dtype if kv_dtype is None else kv_dtype
-    shape = (num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
+    shape = (num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
     quantized = jnp.dtype(kv_dtype) == jnp.dtype(jnp.int8)
     scale = (jnp.zeros((num_pages, page_size), jnp.float32)
              if quantized else None)
@@ -681,24 +688,24 @@ def init_paged_kv_cache(batch: int, num_pages: int, table_width: int,
 
 def _scatter_pages(pages: jnp.ndarray, page_table: jnp.ndarray,
                    seq: jnp.ndarray) -> jnp.ndarray:
-    """Write [B,S,KVH,Dh] token rows into their pages.
+    """Write [B,S,KVH,Dh] token rows into their [P,ps,KVH*Dh] pages.
 
     Position t of row b lands in physical page ``page_table[b, t//ps]`` at
     offset ``t%ps``.  S is padded up to a page multiple; positions whose
     table entry is unallocated (0) land in the null page — harmless, and
     rows never share live pages so the scatter has no real collisions.
     """
-    b, s, kvh, dh = seq.shape
-    ps = pages.shape[1]
+    b, s = seq.shape[:2]
+    ps, w = pages.shape[1:]
+    seq = seq.reshape(b, s, w)
     pad = (-s) % ps
     if pad:
-        seq = jnp.pad(seq, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        seq = jnp.pad(seq, ((0, 0), (0, pad), (0, 0)))
     npp = seq.shape[1] // ps
     npp_eff = min(npp, page_table.shape[1])
-    tiles = seq[:, :npp_eff * ps].reshape(b, npp_eff, ps, kvh, dh)
+    tiles = seq[:, :npp_eff * ps].reshape(b * npp_eff, ps, w)
     ids = page_table[:, :npp_eff].reshape(-1)
-    return pages.at[ids].set(
-        tiles.reshape(b * npp_eff, ps, kvh, dh).astype(pages.dtype))
+    return pages.at[ids].set(tiles.astype(pages.dtype))
 
 
 def _scatter_scales(scales: jnp.ndarray, page_table: jnp.ndarray,
@@ -725,15 +732,15 @@ def _scatter_pages_at(pages: jnp.ndarray, page_table: jnp.ndarray,
     the shared prefix's pages are already populated and MUST NOT be
     rewritten).  Tokens with ``t >= count[b]`` (padding) are routed to the
     null page."""
-    b, s, kvh, dh = seq.shape
-    ps = pages.shape[1]
+    b, s = seq.shape[:2]
+    ps, w = pages.shape[1:]
     np_w = page_table.shape[1]
     pos = start[:, None] + jnp.arange(s)[None, :]              # [B,S]
     logical = jnp.minimum(pos // ps, np_w - 1)
     ids = jnp.take_along_axis(page_table, logical, axis=1)     # [B,S]
     ids = jnp.where(jnp.arange(s)[None, :] < count[:, None], ids, 0)
     offs = pos % ps
-    return pages.at[ids, offs].set(seq.astype(pages.dtype))
+    return pages.at[ids, offs].set(seq.reshape(b, s, w).astype(pages.dtype))
 
 
 def _scatter_scales_at(scales: jnp.ndarray, page_table: jnp.ndarray,
@@ -750,14 +757,15 @@ def _scatter_scales_at(scales: jnp.ndarray, page_table: jnp.ndarray,
     return scales.at[ids, pos % ps].set(rows.astype(scales.dtype))
 
 
-def _gather_ctx(cache: PagedKVCache, dtype) -> Tuple[jnp.ndarray,
-                                                     jnp.ndarray]:
+def _gather_ctx(cache: PagedKVCache, dtype, kvh: int
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dense [B, NP*ps, KVH, Dh] view of every page each row's table
     lists, dequantized when the cache stores int8 codes."""
     b = cache.page_table.shape[0]
-    ps, kvh, dh = cache.k_pages.shape[1:]
+    ps, w = cache.k_pages.shape[1:]
+    dh = w // kvh
     np_w = cache.page_table.shape[1]
-    k_g = cache.k_pages[cache.page_table]       # [B, NP, ps, KVH, Dh]
+    k_g = cache.k_pages[cache.page_table]       # [B, NP, ps, KVH*Dh]
     v_g = cache.v_pages[cache.page_table]
     if cache.quantized:
         k_g = dequantize_gathered(k_g, cache.k_scale[cache.page_table],
@@ -784,7 +792,7 @@ def _suffix_prefill_attend(p: Params, x: jnp.ndarray, cfg: AttnConfig,
     positions = prefix_len[:, None] + jnp.arange(s)[None, :]
     q, k, v = _project_qkv(p, x, cfg, positions, positions3)
     with jax.named_scope("kv_cache"):
-        k_ctx, v_ctx = _gather_ctx(cache, q.dtype)
+        k_ctx, v_ctx = _gather_ctx(cache, q.dtype, k.shape[2])
     ctx_w = k_ctx.shape[1]
     # joint mask over [ctx | suffix] keys: ctx key j real iff j < prefix;
     # suffix key t visible iff t <= i (causal) and t < suffix length
@@ -871,24 +879,29 @@ def paged_decode_jnp(q: jnp.ndarray, k_pages: jnp.ndarray,
                      length: jnp.ndarray, k_new: jnp.ndarray,
                      v_new: jnp.ndarray,
                      k_scale: Optional[jnp.ndarray] = None,
-                     v_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                     v_scale: Optional[jnp.ndarray] = None,
+                     layer=0) -> jnp.ndarray:
     """The gather-based paged decode reference (dispatch ``jnp_paged``;
     with scales, ``jnp_paged_q8``).
 
-    Gathers each row's listed pages into a dense [B, NP*ps, KVH, Dh]
-    context view (dequantizing int8 codes with the per-token scales) and
+    Gathers each row's listed pages of ``layer`` out of the stacked
+    [L, P, ps, KVH*Dh] pool into a dense [B, NP*ps, KVH, Dh] context view
+    (dequantizing int8 codes with the per-token [L, P, ps] scales) and
     runs the SAME two-part softmax as the dense decode path
     (:func:`_decode_token_attend`) — the masked-dense oracle the Pallas
     kernels are checked against, and the interpret-mode fallback.
     """
     b = q.shape[0]
-    ps, kvh, dh = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]
+    ps = k_pages.shape[-2]
+    kvh, dh = k_new.shape[-2:]
     np_w = page_table.shape[1]
-    k_ctx = k_pages[page_table]
-    v_ctx = v_pages[page_table]
+    k_ctx = k_pages[layer, page_table]          # [B, NP, ps, KVH*Dh]
+    v_ctx = v_pages[layer, page_table]
     if k_scale is not None:
-        k_ctx = dequantize_gathered(k_ctx, k_scale[page_table], q.dtype)
-        v_ctx = dequantize_gathered(v_ctx, v_scale[page_table], q.dtype)
+        k_ctx = dequantize_gathered(k_ctx, k_scale[layer, page_table],
+                                    q.dtype)
+        v_ctx = dequantize_gathered(v_ctx, v_scale[layer, page_table],
+                                    q.dtype)
     k_ctx = k_ctx.reshape(b, np_w * ps, kvh, dh)
     v_ctx = v_ctx.reshape(b, np_w * ps, kvh, dh)
     valid = jnp.arange(np_w * ps)[None, :] < length[:, None]
@@ -901,16 +914,18 @@ def paged_decode_attention_token(p: Params, x: jnp.ndarray, cfg: AttnConfig,
                                  length: jnp.ndarray,
                                  positions3: Optional[jnp.ndarray] = None,
                                  k_scale: Optional[jnp.ndarray] = None,
-                                 v_scale: Optional[jnp.ndarray] = None
-                                 ) -> Tuple[jnp.ndarray, jnp.ndarray,
-                                            jnp.ndarray]:
+                                 v_scale: Optional[jnp.ndarray] = None,
+                                 layer=0) -> Tuple[jnp.ndarray, jnp.ndarray,
+                                                   jnp.ndarray]:
     """One-token decode against READ-ONLY pages: the paged twin of
     :func:`decode_attention_token`.
 
-    Attention touches only the pages each row's table lists — bytes/token
-    is O(length), not O(max_seq).  Which implementation runs (the Pallas
-    paged kernel or the gather reference, in their fp or int8-dequant
-    variants) is a registry decision (``registry.select("paged_decode",
+    ``k_pages``/``v_pages`` are the stacked [L, P, ps, KVH*Dh] pool (and
+    ``k_scale``/``v_scale`` the stacked [L, P, ps] scales), read at
+    ``layer``.  Attention touches only the pages each row's table lists
+    — bytes/token is O(length), not O(max_seq).  Which implementation
+    runs (the Pallas paged kernel or the gather reference, in their fp or
+    int8-dequant variants) is a registry decision (``registry.select("paged_decode",
     quantized=...)``); the new token's K/V are returned UNQUANTIZED for
     the caller to scatter into its page (quantizing on the way if the
     cache is int8).
@@ -924,6 +939,6 @@ def paged_decode_attention_token(p: Params, x: jnp.ndarray, cfg: AttnConfig,
     impl = registry.select("paged_decode", quantized=quantized)
     kw = dict(k_scale=k_scale, v_scale=v_scale) if quantized else {}
     out = registry.run("paged_decode", q, k_pages, v_pages, page_table,
-                       length, k, v, impl=impl, **kw)
+                       length, k, v, impl=impl, layer=layer, **kw)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, k, v

@@ -346,18 +346,20 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
     Pages are carried in place (scan carry, while-loop aliasing, exactly
     like the dense in-place path); the page table and per-row lengths are
     shared across layers (every layer's slice holds the same values, so
-    layer 0's are read once).  The token write touches ONE page per layer:
-    row b's token lands in physical page ``pt[b, length[b] // ps]`` at
-    offset ``length[b] % ps`` — the pool guarantees that page is
-    allocated before the segment runs.  The layer's pool slices and the
-    token write run under the ``kv_cache`` scope.
+    layer 0's are read once).  Layer i's attention hands the kernel the
+    whole stacked pool carry and ``i``: the kernel reads the layer's
+    pages in place, so the program holds no per-layer copy of the pool.
+    The token write touches ONE page per layer: row b's token lands in
+    physical page ``pt[b, length[b] // ps]`` at offset ``length[b] % ps``
+    — the pool guarantees that page is allocated before the segment runs
+    — and runs under the ``kv_cache`` scope.
     """
     b = x.shape[0]
     length = attn_mod._row_lengths(
         caches.length[0] if caches.length.ndim > 1 else caches.length, b)
     pt = (caches.page_table[0] if caches.page_table.ndim > 2
           else caches.page_table)
-    ps = caches.k_pages.shape[-3]
+    ps = caches.k_pages.shape[-2]
     np_w = pt.shape[-1]
     rows = jnp.arange(b)
     with jax.named_scope("kv_cache"):
@@ -367,22 +369,20 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
     n = jax.tree.leaves(stacked)[0].shape[0]
     quantized = caches.quantized
 
-    def attend(h, layer_p, k_l, v_l, ksc_l=None, vsc_l=None):
+    def attend(h, layer_p, i, kst, vst, ksc=None, vsc=None):
         with jax.named_scope("attention"):
             a, k_t, v_t = attn_mod.paged_decode_attention_token(
                 layer_p["attn"], _norm(h, layer_p["ln1"], cfg), cfg.attn,
-                k_l, v_l, pt, length, positions3=positions3,
-                k_scale=ksc_l, v_scale=vsc_l)
+                kst, vst, pt, length, positions3=positions3,
+                k_scale=ksc, v_scale=vsc, layer=i)
         h2 = h + a
         m, counts = _block_mlp(layer_p, h2, cfg, live)
         return h2 + m, k_t, v_t, counts
 
-    def layer_slice(pool, i):
-        with jax.named_scope("kv_cache"):
-            return jax.lax.dynamic_index_in_dim(pool, i, 0, keepdims=False)
-
     def write(pool, i, rows_kv):
         with jax.named_scope("kv_cache"):
+            # a token's [KVH, Dh] is one lane-dense row of its page
+            rows_kv = rows_kv.reshape((b,) + pool.shape[3:])
             return pool.at[i, page, off].set(rows_kv.astype(pool.dtype))
 
     if quantized:
@@ -391,9 +391,7 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
         def body(carry, scanned):
             h, kst, vst, ksc, vsc = carry
             i, layer_p = scanned
-            y, k_t, v_t, counts = attend(
-                h, layer_p, layer_slice(kst, i), layer_slice(vst, i),
-                layer_slice(ksc, i), layer_slice(vsc, i))
+            y, k_t, v_t, counts = attend(h, layer_p, i, kst, vst, ksc, vsc)
             with jax.named_scope("kv_cache"):
                 k_c, k_s = attn_mod.quantize_kv_rows(k_t[:, 0])
                 v_c, v_s = attn_mod.quantize_kv_rows(v_t[:, 0])
@@ -407,8 +405,7 @@ def _apply_stack_decode_paged(stacked: Params, x: jnp.ndarray,
         def body(carry, scanned):
             h, kst, vst = carry
             i, layer_p = scanned
-            y, k_t, v_t, counts = attend(h, layer_p, layer_slice(kst, i),
-                                         layer_slice(vst, i))
+            y, k_t, v_t, counts = attend(h, layer_p, i, kst, vst)
             kst = write(kst, i, k_t[:, 0])
             vst = write(vst, i, v_t[:, 0])
             return (y, kst, vst), counts

@@ -406,13 +406,18 @@ class Engine:
                                  self.lm.rules, self.mesh)
 
     def _state_spec(self, leaf) -> P:
-        """PartitionSpec for one decode-state leaf: KV storage — dense
-        caches [L,B,S,KVH,Dh] and paged pools [L,P,ps,KVH,Dh] alike —
-        shards its kv-head dim (-2) over ``model``; page tables, lengths
+        """PartitionSpec for one decode-state leaf: KV storage shards its
+        kv heads over ``model`` — dense caches [L,B,S,KVH,Dh] on dim -2,
+        and a paged engine's lane-dense pools [L,P,ps,KVH*Dh] (its only
+        rank-4 leaves: recurrent states, also rank 4, never have pages)
+        on their last dim, where heads are contiguous lane groups, so each
+        device holds whole heads; recurrent states, page tables, lengths
         and quant scales replicate (the tables are host-planned and
-        global — every device walks the same pages, reading its own head
-        slice)."""
+        global — every device walks the same pages, reading its own
+        heads)."""
         msize = int(self.mesh.shape["model"])
+        if self.paged and leaf.ndim == 4 and leaf.shape[-1] % msize == 0:
+            return P(None, None, None, "model")
         if leaf.ndim == 5 and leaf.shape[-2] % msize == 0:
             return P(None, None, None, "model", None)
         return P()

@@ -1,9 +1,9 @@
 """Host-side page allocator for the paged KV cache (the pool manager).
 
 The device state (:class:`repro.models.attention.PagedKVCache`) is dumb
-storage: a pool of ``[num_pages, page_size, KVH, Dh]`` pages per layer and
-per-slot page tables.  THIS class owns the policy: a global free list of
-physical pages, per-slot ownership, and the ``[slots, max_pages]`` int32
+storage: a pool of lane-dense ``[num_pages, page_size, KVH*Dh]`` pages per
+layer and per-slot page tables.  THIS class owns the policy: a global free
+list of physical pages, per-slot ownership, and the ``[slots, max_pages]`` int32
 table mirror the scheduler uploads before every decode segment.
 
 Since the prefix-cache PR the pool is **content-addressed**: pages are

@@ -14,6 +14,7 @@ test worker imports this file.
 """
 
 import dataclasses
+import math
 import re
 
 import jax
@@ -76,33 +77,47 @@ def test_pallas_flash_compiles(one_chip, cfg, seq):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("cfg", ATTN_CFGS)
+#: the paged kernel's operand widths in each cell, one chip's share:
+#: (query heads, kv heads, head dim, layers of the stacked pool)
+PAGED_WIDTHS = [
+    pytest.param((14, 2, 64, 24), id="qwen2-0.5b"),
+    pytest.param((28, 4, 128, 28), id="qwen2-vl-7b"),
+    # tensor parallel over four chips: one kv head a chip
+    pytest.param((7, 1, 128, 28), id="qwen2-vl-7b.tp4"),
+    pytest.param((64, 4, 128, 6), id="qwen3-moe-235b-a22b"),
+]
+
+
+@pytest.mark.parametrize("widths", PAGED_WIDTHS)
 @pytest.mark.parametrize("impl", ["pallas_paged", "pallas_paged_q8"])
 @pytest.mark.parametrize("b,width,page_size,ppb", [
     (8, 64, 16, 1), (8, 64, 32, 2),
     # the chat cell's decode: 128 slots, the widest table bucket, the
     # untuned block
     (128, 128, 16, None)])
-def test_pallas_paged_compiles(one_chip, cfg, impl, b, width, page_size,
+def test_pallas_paged_compiles(one_chip, widths, impl, b, width, page_size,
                                ppb):
+    """The kernel over the stacked [L, P, ps, KVH*Dh] pool, reading a
+    traced layer's pages in place."""
     run = registry.get_spec("paged_decode", impl).fn
     pool = b * width + 1
-    h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    h, kvh, dh, layers = widths
     page_dt = jnp.int8 if impl.endswith("_q8") else BF16
-    shapes = [((b, 1, h, dh), BF16), ((pool, page_size, kvh, dh), page_dt),
-              ((pool, page_size, kvh, dh), page_dt),
+    pages = ((layers, pool, page_size, kvh * dh), page_dt)
+    shapes = [((b, 1, h, dh), BF16), pages, pages,
               ((b, width), jnp.int32), ((b,), jnp.int32),
-              ((b, 1, kvh, dh), BF16), ((b, 1, kvh, dh), BF16)]
+              ((b, 1, kvh, dh), BF16), ((b, 1, kvh, dh), BF16),
+              ((), jnp.int32)]
     if impl.endswith("_q8"):
-        shapes += [((pool, page_size), jnp.float32)] * 2
+        shapes += [((layers, pool, page_size), jnp.float32)] * 2
 
-        def fn(q, kp, vp, pt, ln, kn, vn, ks, vs):
+        def fn(q, kp, vp, pt, ln, kn, vn, layer, ks, vs):
             return run(q, kp, vp, pt, ln, kn, vn, k_scale=ks, v_scale=vs,
-                       pages_per_block=ppb, interpret=False)
+                       layer=layer, pages_per_block=ppb, interpret=False)
     else:
-        def fn(q, kp, vp, pt, ln, kn, vn):
-            return run(q, kp, vp, pt, ln, kn, vn, pages_per_block=ppb,
-                       interpret=False)
+        def fn(q, kp, vp, pt, ln, kn, vn, layer):
+            return run(q, kp, vp, pt, ln, kn, vn, layer=layer,
+                       pages_per_block=ppb, interpret=False)
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
 
 
@@ -130,13 +145,13 @@ def test_paged_vmem_estimate_bounds_compiled_kernel(one_chip, impl):
     pool = b * width + 1
     q8 = impl.endswith("_q8")
     page_dt = jnp.int8 if q8 else BF16
-    shapes = [((b, kvh, g, dh), BF16), ((pool, ps, kvh, dh), page_dt),
-              ((pool, ps, kvh, dh), page_dt), ((b, width), jnp.int32),
+    shapes = [((b, kvh, g, dh), BF16), ((1, pool, ps, kvh * dh), page_dt),
+              ((1, pool, ps, kvh * dh), page_dt), ((b, width), jnp.int32),
               ((b,), jnp.int32), ((b, kvh, dh), BF16), ((b, kvh, dh), BF16)]
     if q8:
-        shapes += [((pool, ps), jnp.float32)] * 2
+        shapes += [((1, pool, ps), jnp.float32)] * 2
     est = registry.get_spec("paged_decode", impl).tune.vmem(
-        (ps, ppb), 2, g=g, dh=dh, kvh=kvh)
+        (ps, ppb), 2, g=g, dh=dh, kvh=kvh, ctx=width * ps)
 
     def compiled(limit):
         params = pltpu.CompilerParams(vmem_limit_bytes=int(limit))
@@ -206,42 +221,105 @@ def test_decode_segment_compiles_at_full_width(one_chip, monkeypatch):
     assert used < 16 * 2**30, mem
 
 
-def test_decode_segment_pool_copies_carry_kv_cache(one_chip, monkeypatch):
-    """On the v5e the decode segment copies each layer's K/V pool slice,
-    and the paged kernel's lane-dense view of it: every such copy of a
-    whole layer's pool carries the ``kv_cache`` scope, and the kernel is
-    one custom call per layer that keeps its name."""
-    import re
+#: one chip's decode segment over each cell's pool: qwen2-0.5b with the
+#: engine's own pool (128 slots of 2,048 tokens, 16,513 pages), also in
+#: int8 with its scale pools, and the Qwen3-MoE stage with its 2 GiB pool
+#: (10,922 pages)
+SEGMENT_POOLS = [
+    pytest.param(QWEN, None, None, id="qwen2-0.5b"),
+    pytest.param(QWEN, None, "int8", id="qwen2-0.5b-int8"),
+    pytest.param(QWEN3_MOE, 10922, None, id="qwen3-moe-235b-a22b"),
+]
 
+#: one HLO instruction: name, element type, dims, layout, opcode
+HLO_OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\](\{[^}]*\})?"
+                    r"\S* (\S+)\(")
+
+
+def _computations(text):
+    """{computation name: its instruction lines} of an HLO module."""
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        if ln and not ln[0].isspace() and ln.rstrip().endswith("{"):
+            cur = ln.split()[1 if ln.startswith("ENTRY") else 0].lstrip("%")
+            comps[cur] = []
+        elif cur is not None and ln.startswith("  "):
+            comps[cur].append(ln)
+    return comps
+
+
+@pytest.mark.parametrize("cfg,pool_pages,kv_dtype", SEGMENT_POOLS)
+def test_decode_segment_pool_copies_carry_kv_cache(one_chip, monkeypatch,
+                                                   cfg, pool_pages,
+                                                   kv_dtype):
+    """On the v5e the decode segment moves no K/V pool: the paged kernel
+    reads each layer's pages in place from the stacked pool the layer
+    scan carries, so the only operation that yields an array of a
+    layer's pool or of the whole stack (of pages, or of int8 scales) is
+    the one-token write in place (under the ``kv_cache`` scope); the
+    pool enters and leaves the program in one layout, so nothing
+    relayouts it at the edges; and the kernel is one custom call in the
+    layer scan's body that keeps the name the benchmark's trace
+    reduction keys."""
     from repro.core.features import default_features
     from repro.models.lm import LM
     from repro.serve import Engine, ServeConfig
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    lm = LM(QWEN, default_features().with_(remat_policy="none"), dtype=BF16)
+    lm = LM(cfg, default_features().with_(remat_policy="none"), dtype=BF16)
     params = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0),
                                                    dtype=BF16))
-    eng = Engine(lm, params, ServeConfig(page_size=16, batch_slots=8,
-                                         max_seq=512))
+    eng = Engine(lm, params, ServeConfig(page_size=16, batch_slots=128,
+                                         max_seq=2048,
+                                         pool_pages=pool_pages,
+                                         kv_dtype=kv_dtype))
     state = jax.eval_shape(lambda: lm.init_decode_state(
-        8, 512, page_size=16, num_pages=eng.pool_pages, table_width=8))
+        128, 2048, **eng._state_kwargs()))
     put = lambda t: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
     key = jax.eval_shape(lambda: jax.random.key(0))
-    text = eng.decode_segment(2).lower(
+    text = eng.decode_segment(8).lower(
         put(params), put(state),
-        jax.ShapeDtypeStruct((8, QWEN.vocab), BF16, sharding=one_chip),
+        jax.ShapeDtypeStruct((128, cfg.vocab), BF16, sharding=one_chip),
         put(key)).compile().as_text()
-    p = eng.pool_pages
-    layer_pool = re.compile(
-        rf"%(copy|constant_dynamic-slice_fusion)[.\d]* = bf16\[(1,)?{p},16,")
-    moved = [ln for ln in text.splitlines() if layer_pool.search(ln)]
-    assert len(moved) >= 4
-    assert all("/kv_cache/" in ln for ln in moved), moved
-    # one kernel call in the layer scan's body, under the name the
-    # benchmark's trace reduction keys
-    kernel = re.compile(
-        r"%paged_decode_attention_grouped\.\d+ = .*custom-call\(")
-    assert len([ln for ln in text.splitlines() if kernel.search(ln)]) == 1
+    stack = state["caches"].k_pages.shape
+    assert stack == (cfg.n_layers, eng.pool_pages, 16,
+                     cfg.num_kv_heads * cfg.resolved_head_dim)
+    layer_n = stack[1] * stack[2] * stack[3]
+    sizes = {layer_n, stack[0] * layer_n}
+    if kv_dtype:
+        # the scale pools [L, P, ps]
+        sizes |= {stack[1] * stack[2], stack[0] * stack[1] * stack[2]}
+    moved, writes = [], 0
+    for ln in text.splitlines():
+        m = HLO_OP.match(ln)
+        if not m or m.group(5) in ("parameter", "get-tuple-element",
+                                   "bitcast"):
+            continue
+        dims = [int(d) for d in m.group(3).split(",") if d]
+        if dims and math.prod(dims) in sizes:
+            if "/kv_cache/scatter" in ln:
+                writes += 1
+            else:
+                moved.append(ln[:200])
+    assert not moved, moved
+    assert writes >= (4 if kv_dtype else 2)   # K and V (and scales)
+    # the pool's layout where it enters the program and where it leaves
+    entry = re.search(r"entry_computation_layout=\{\((.*)\)->\((.*)\)\}",
+                      text.splitlines()[0])
+    pool_ty = re.escape(("s8[" if kv_dtype else "bf16[")
+                        + ",".join(map(str, stack)) + "]")
+    ins, outs = (re.findall(pool_ty + r"(\{[^}]*\})", half)
+                 for half in entry.groups())
+    assert len(ins) == len(outs) == 2 and set(ins) == set(outs), (ins, outs)
+    # one kernel call, in the body of the layer scan's loop
+    comps = _computations(text)
+    name = ("paged_decode_attention_q8_grouped" if kv_dtype
+            else "paged_decode_attention_grouped")
+    kernel = re.compile(rf"%{name}\.\d+ = .*custom-call\(")
+    homes = [c for c, lines in comps.items()
+             for ln in lines if kernel.search(ln)]
+    bodies = set(re.findall(r"\bbody=%([\w.\-]+)", text))
+    assert len(homes) == 1 and homes[0] in bodies, (homes, bodies)
 
 
 @pytest.mark.parametrize("m,k,n", [(1024, 4096, 1536), (1024, 1536, 4096),

@@ -228,8 +228,10 @@ def test_paged_decode_bytes_track_table_width_not_pool():
     def compile_bytes(p_total, np_w):
         B, H, KVH, Dh, ps = 4, 4, 2, 32, 16
         args = (jax.ShapeDtypeStruct((B, 1, H, Dh), jnp.float32),
-                jax.ShapeDtypeStruct((p_total, ps, KVH, Dh), jnp.float32),
-                jax.ShapeDtypeStruct((p_total, ps, KVH, Dh), jnp.float32),
+                jax.ShapeDtypeStruct((1, p_total, ps, KVH * Dh),
+                                     jnp.float32),
+                jax.ShapeDtypeStruct((1, p_total, ps, KVH * Dh),
+                                     jnp.float32),
                 jax.ShapeDtypeStruct((B, np_w), jnp.int32),
                 jax.ShapeDtypeStruct((B,), jnp.int32),
                 jax.ShapeDtypeStruct((B, 1, KVH, Dh), jnp.float32),

@@ -118,6 +118,34 @@ def test_engine_rejects_indivisible_kv_heads(tiny_lm, tiny_params):
         Engine(tiny_lm, tiny_params, ServeConfig(**SCFG), mesh=FakeMesh())
 
 
+def test_state_spec_shards_pool_lanes_not_recurrent_states():
+    """Under a model axis of 2 a paged engine's lane-dense pools
+    [L,P,ps,KVH*Dh] split their last dim (whole heads a device), and its
+    int8 scale pools, page tables and lengths replicate; in an engine
+    without pages a rank-4 recurrent state replicates and a dense cache
+    [L,B,S,KVH,Dh] splits its dim -2."""
+    from types import SimpleNamespace
+
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from repro.models.attention import PagedKVCache
+    mesh = SimpleNamespace(shape={"model": 2})
+    spec = lambda paged, x: Engine._state_spec(
+        SimpleNamespace(mesh=mesh, paged=paged), x)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    pool = PagedKVCache(k_pages=sds(2, 9, 16, 128), v_pages=sds(2, 9, 16, 128),
+                        page_table=sds(2, 4, 8), length=sds(2, 4),
+                        k_scale=sds(2, 9, 16), v_scale=sds(2, 9, 16))
+    got = jax.tree.map(lambda x: spec(True, x), pool)
+    lanes = P(None, None, None, "model")
+    assert (got.k_pages, got.v_pages) == (lanes, lanes)
+    assert got.page_table == got.length == got.k_scale == got.v_scale == P()
+    assert spec(False, sds(2, 4, 8, 16)) == P()
+    assert spec(False, sds(2, 4, 32, 2, 64)) == P(None, None, None, "model",
+                                                  None)
+
+
 def test_trivial_mesh_engine_matches_unsharded(tiny_lm, tiny_params):
     prompts = [[1, 2, 3, 4], [7, 5, 3]]
     ref = Engine(tiny_lm, tiny_params, ServeConfig(**SCFG)).generate(

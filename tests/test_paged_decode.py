@@ -24,12 +24,14 @@ from repro.models.attention import paged_decode_jnp
 from repro.serve.kv_pool import KVPool, pages_for
 
 
-def _case(rng, b, h, kvh, dh, ps, np_w, lens):
-    """Random pool + shuffled per-row page tables + a new token."""
+def _case(rng, b, h, kvh, dh, ps, np_w, lens, layers=1):
+    """Random stacked pool [layers, P, ps, KVH*Dh] + shuffled per-row page
+    tables + a new token."""
     p_total = b * np_w + 1
+    pool = (layers, p_total, ps, kvh * dh)
     q = jnp.asarray(rng.normal(size=(b, 1, h, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(p_total, ps, kvh, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(p_total, ps, kvh, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.float32)
     kn = jnp.asarray(rng.normal(size=(b, 1, kvh, dh)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(b, 1, kvh, dh)), jnp.float32)
     ids = rng.permutation(np.arange(1, p_total))[:b * np_w].reshape(b, np_w)
@@ -77,8 +79,9 @@ def _tol(flavor):
 
 
 def _q8_pages(rng, shape):
+    """int8 codes of a [L, P, ps, W] pool and its [L, P, ps] scales."""
     codes = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
-    scale = jnp.asarray(rng.uniform(0.005, 0.05, size=shape[:2]),
+    scale = jnp.asarray(rng.uniform(0.005, 0.05, size=shape[:3]),
                         jnp.float32)
     return codes, scale
 
@@ -147,28 +150,29 @@ def test_paged_kernel_never_reads_dead_pages(ppb, flavor):
     if flavor == "q8":
         kp, ksc = _q8_pages(rng, kp.shape)
         vp, vsc = _q8_pages(rng, vp.shape)
-    nan_page = kp.shape[0]                    # one page past the pool
+    nan_page = kp.shape[1]                    # one page past the pool
     live = -(-lens // ps)
     dead = np.arange(np_w)[None, :] >= live[:, None]
     pt_nan = jnp.where(jnp.asarray(dead), nan_page, pt)
     # token rows of live pages past each row's length
-    tail = np.zeros(kp.shape[:2], bool)
+    tail = np.zeros(kp.shape[:3], bool)
     ptn = np.asarray(pt)
     for r in range(b):
         for j in range(live[r]):
-            tail[ptn[r, j], max(lens[r] - j * ps, 0):] = True
+            tail[:, ptn[r, j], max(lens[r] - j * ps, 0):] = True
     tail = jnp.asarray(tail)
     if flavor != "q8":
         poison = lambda x: jnp.concatenate(
-            [jnp.where(tail[..., None, None], jnp.nan, x),
-             jnp.full_like(x[:1], jnp.nan)])
+            [jnp.where(tail[..., None], jnp.nan, x),
+             jnp.full_like(x[:, :1], jnp.nan)], axis=1)
         got, want = _run_flavor(flavor, q, poison(kp), poison(vp),
                                 pt_nan, lens_j, kn, vn, ppb=ppb,
                                 ref_pt=pt, ref_kp=kp, ref_vp=vp)
     else:
         poison = lambda sc: jnp.concatenate(
-            [jnp.where(tail, jnp.nan, sc), jnp.full_like(sc[:1], jnp.nan)])
-        pad = lambda x: jnp.concatenate([x, x[:1]])
+            [jnp.where(tail, jnp.nan, sc), jnp.full_like(sc[:, :1], jnp.nan)],
+            axis=1)
+        pad = lambda x: jnp.concatenate([x, x[:, :1]], axis=1)
         got, want = _run_flavor(flavor, q, pad(kp), pad(vp), pt_nan,
                                 lens_j, kn, vn, ppb=ppb, ref_pt=pt,
                                 ref_kp=kp, ref_vp=vp,
@@ -188,13 +192,58 @@ def test_paged_matches_dense_decode_token_softmax():
     q, kp, vp, pt, lens_j, kn, vn = _case(rng, b, h, kvh, dh, ps, np_w, lens)
     got = paged_decode_jnp(q, kp, vp, pt, lens_j, kn, vn)
     # densify: gather each row's pages into a contiguous cache
-    k_ctx = np.asarray(kp)[np.asarray(pt)].reshape(b, np_w * ps, kvh, dh)
-    v_ctx = np.asarray(vp)[np.asarray(pt)].reshape(b, np_w * ps, kvh, dh)
+    k_ctx = np.asarray(kp)[0][np.asarray(pt)].reshape(b, np_w * ps, kvh, dh)
+    v_ctx = np.asarray(vp)[0][np.asarray(pt)].reshape(b, np_w * ps, kvh, dh)
     valid = jnp.arange(np_w * ps)[None, :] < lens_j[:, None]
     want = _decode_token_attend(q, jnp.asarray(k_ctx), jnp.asarray(v_ctx),
                                 valid, kn, vn)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("flavor", ["fp", "q8"])
+@pytest.mark.parametrize("h,kvh,dh", [(14, 2, 64), (8, 4, 128), (7, 1, 128)],
+                         ids=["kvh2-dh64", "kvh4-dh128", "kvh1-dh128"])
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+def test_stacked_pool_kernel_reads_only_its_layer(layer, h, kvh, dh, flavor):
+    """The kernel reads layer ``layer``'s pages in place from a stacked
+    [L, P, ps, KVH*Dh] pool whose every other layer is NaN (for int8
+    pages: NaN scales), and matches the oracle run over that one layer
+    alone; so does the gather reference.  The widths are the cells':
+    qwen2-0.5b's two heads of 64, Qwen3-MoE's four of 128, and one chip's
+    single head of 128 on the tensor-parallel mesh."""
+    rng = np.random.default_rng(layer * 7 + kvh * 31 + dh)
+    b, ps, np_w, n_layers = 3, 8, 5, 5
+    lens = [0, 13, np_w * ps - 3]
+    q, kp, vp, pt, lens_j, kn, vn = _case(rng, b, h, kvh, dh, ps, np_w,
+                                          lens, layers=n_layers)
+    other = (jnp.arange(n_layers) != layer)[:, None, None]
+    one = lambda x: x[layer:layer + 1]
+    if flavor == "fp":
+        nan = lambda x: jnp.where(other[..., None], jnp.nan, x)
+        want = ref.paged_decode(q, one(kp), one(vp), pt, lens_j, kn, vn)
+        got = paged_decode_attention(q, nan(kp), nan(vp), pt, lens_j, kn,
+                                     vn, layer=layer, pages_per_block=2,
+                                     interpret=True)
+        got_j = paged_decode_jnp(q, nan(kp), nan(vp), pt, lens_j, kn, vn,
+                                 layer=layer)
+    else:
+        kp, ksc = _q8_pages(rng, kp.shape)
+        vp, vsc = _q8_pages(rng, vp.shape)
+        nan = lambda sc: jnp.where(other, jnp.nan, sc)
+        want = ref.paged_decode_q8(q, one(kp), one(vp), pt, lens_j, kn, vn,
+                                   k_scale=one(ksc), v_scale=one(vsc))
+        got = paged_decode_attention_q8(q, kp, vp, pt, lens_j, kn, vn,
+                                        k_scale=nan(ksc), v_scale=nan(vsc),
+                                        layer=layer, pages_per_block=2,
+                                        interpret=True)
+        got_j = paged_decode_jnp(q, kp, vp, pt, lens_j, kn, vn,
+                                 k_scale=nan(ksc), v_scale=nan(vsc),
+                                 layer=layer)
+    for out in (got, got_j):
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -202,13 +202,14 @@ def test_pool_prefix_churn_invariants():
 def _q8_case(rng, b, h, kvh, dh, ps, np_w, lens):
     p_total = b * np_w + 1
     q = jnp.asarray(rng.normal(size=(b, 1, h, dh)), jnp.float32)
-    kp = jnp.asarray(rng.integers(-127, 128, size=(p_total, ps, kvh, dh)),
+    # one layer's pool, stacked as the decode state stacks it
+    kp = jnp.asarray(rng.integers(-127, 128, size=(1, p_total, ps, kvh * dh)),
                      jnp.int8)
-    vp = jnp.asarray(rng.integers(-127, 128, size=(p_total, ps, kvh, dh)),
+    vp = jnp.asarray(rng.integers(-127, 128, size=(1, p_total, ps, kvh * dh)),
                      jnp.int8)
-    ksc = jnp.asarray(rng.uniform(0.005, 0.05, size=(p_total, ps)),
+    ksc = jnp.asarray(rng.uniform(0.005, 0.05, size=(1, p_total, ps)),
                       jnp.float32)
-    vsc = jnp.asarray(rng.uniform(0.005, 0.05, size=(p_total, ps)),
+    vsc = jnp.asarray(rng.uniform(0.005, 0.05, size=(1, p_total, ps)),
                       jnp.float32)
     kn = jnp.asarray(rng.normal(size=(b, 1, kvh, dh)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(b, 1, kvh, dh)), jnp.float32)
